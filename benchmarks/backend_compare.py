@@ -60,7 +60,7 @@ from repro.core.tuning import pick_dcut
 from repro.engine import ExecSpec, as_plan
 from repro.kernels.backend import get_backend
 
-from .util import CSV
+from .util import CSV, use_compile_cache
 
 PRIMITIVES = ("range_count", "denser_nn", "prefix_nn", "rho_delta_two_pass",
               "rho_delta_fused", "range_count_halo", "denser_nn_halo",
@@ -252,7 +252,7 @@ warnings.filterwarnings("ignore")
 os.environ["REPRO_ANALYSIS"] = "suspend"   # bench plans, not production fits
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core.grid import build_grid
 from repro.core.tuning import pick_dcut
 from repro.distributed import dpc as ddpc
@@ -283,10 +283,10 @@ def phases(layout):
     delta_fn = ddpc._make_delta_dense("data", 256, be, layout=layout)
     sm_rho = jax.jit(shard_map(
         rho_fn, mesh=mesh, in_specs=(P("data"), P("data")),
-        out_specs=P("data"), check_rep=False))
+        out_specs=P("data"), check_vma=False))
     sm_delta = jax.jit(shard_map(
         delta_fn, mesh=mesh, in_specs=(P("data"),) * 4,
-        out_specs=(P("data"),) * 3, check_rep=False))
+        out_specs=(P("data"),) * 3, check_vma=False))
     def run():
         out = (sm_rho(pts_s, pts_s), sm_delta(pts_s, rk_q, pts_s, rk_tab))
         return jax.block_until_ready(out)
@@ -301,6 +301,7 @@ for _ in range(repeats):
     t0 = time.perf_counter(); dense_run(); dts.append(time.perf_counter() - t0)
     t0 = time.perf_counter(); bs_run(); bts.append(time.perf_counter() - t0)
 print("RESULT" + json.dumps({
+    "platform": jax.devices()[0].platform,
     "n": n, "d": d, "d_cut": d_cut, "devices": S, "backend": "jnp",
     "layout_probe": lay,
     "dense_seconds": float(np.min(dts)), "bs_seconds": float(np.min(bts)),
@@ -312,8 +313,10 @@ print("RESULT" + json.dumps({
 
 def measure_distributed(n: int, d: int, repeats: int = 3,
                         devices: int = DIST_DEVICES) -> dict:
-    """The ISSUE 8 distributed row: dense vs block-sparse shard phases on
-    a ``devices``-device mesh (subprocess; see ``_DIST_SCRIPT``)."""
+    """The distributed row: dense vs block-sparse shard phases on a
+    ``devices``-device virtual CPU mesh (subprocess pinned to the CPU, so
+    it never contends for an accelerator the parent holds; see
+    ``_DIST_SCRIPT``).  The record says ``platform: cpu``."""
     import subprocess
 
     env = dict(os.environ)
@@ -330,7 +333,7 @@ def measure_distributed(n: int, d: int, repeats: int = 3,
                            + proc.stderr[-3000:])
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT")][0]
     rec = json.loads(line[len("RESULT"):])
-    print(f"[backend_compare] distributed n={rec['n']} "
+    print(f"[backend_compare] distributed ({rec['platform']}) n={rec['n']} "
           f"S={rec['devices']}: dense shard phases "
           f"{rec['dense_seconds']:.2f}s, block-sparse "
           f"{rec['bs_seconds']:.2f}s -> {rec['speedup']:.2f}x "
@@ -468,6 +471,7 @@ def main(n: int = 4096, d: int = 3, repeats: int = 3,
          out: str = "experiments/backends", smoke: bool = False,
          baseline: str = "BENCH_core.json",
          refresh_baseline: bool = False, obs_snapshot: str | None = None):
+    use_compile_cache()
     if smoke:
         # gated jnp pass at the committed shape + a small kernel exercise
         committed = json.load(open(baseline))

@@ -17,6 +17,8 @@ def main():
     ap.add_argument("--only", default=None,
                     help="comma-separated section names")
     args = ap.parse_args()
+    from .util import use_compile_cache
+    use_compile_cache()
     big = args.full
 
     from . import (accuracy, backend_compare, decomposed, dpc_kv_bench,

@@ -44,6 +44,7 @@ m = -(-len(work) // shards) * shards
 work = np.pad(work, (0, m - len(work)))
 per = work.reshape(shards, -1).sum(axis=1)
 print("RESULT" + json.dumps({
+    "platform": jax.devices()[0].platform,
     "wall_s": t1 - t0,
     "work_max": float(per.max()), "work_mean": float(per.mean()),
     "imbalance": float(per.max() / max(per.mean(), 1.0)),
@@ -57,6 +58,9 @@ def main(n=16_000, dataset="household", shard_counts=(1, 2, 4, 8)):
     for s in shard_counts:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={s}"
+        # virtual host devices: the children run on the CPU, never on an
+        # accelerator the parent process may hold
+        env["JAX_PLATFORMS"] = "cpu"
         env.setdefault("PYTHONPATH", "src")
         code = (_WORKER.replace("@N@", str(n))
                 .replace("@SHARDS@", str(s))
@@ -69,7 +73,8 @@ def main(n=16_000, dataset="household", shard_counts=(1, 2, 4, 8)):
         line = [l for l in proc.stdout.splitlines()
                 if l.startswith("RESULT")][0]
         r = json.loads(line[len("RESULT"):])
-        csv.add(shards=s, wall_s=r["wall_s"], work_per_shard_max=r["work_max"],
+        csv.add(shards=s, platform=r["platform"], wall_s=r["wall_s"],
+                work_per_shard_max=r["work_max"],
                 imbalance=r["imbalance"])
     return csv
 

@@ -9,10 +9,28 @@ comparable and are not the deliverable (the roofline/dry-run is).
 """
 from __future__ import annotations
 
+import os
 import time
 
 import numpy as np
 import jax
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at one fixed directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is left as it is (JAX reads it
+    itself).  Otherwise the cache goes to ``<checkout>/.jax_cache``: a fixed
+    path, because a cache directory that moves between runs never hits.
+    Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def timeit_stats(fn, *args, repeats: int = 3, warmup: int = 1, **kw) -> dict:

@@ -12,7 +12,7 @@ R1-spmd-gather         sort-tainted dynamic indices feeding gather /
                        dynamic_slice inside multi-partition shard_map — the
                        miscompile class itself; also the re-enablement gate
                        for distributed block-sparse (``spmd_gather_safe``)
-R2-check-rep-audit     every ``check_rep=False`` shard_map body carries an
+R2-check-rep-audit     every ``check_vma=False`` shard_map body carries an
                        ``@audit_check_rep`` replication-safety annotation
 R3-precision-flow      bf16 dot_general accumulations reach the f32
                        direct-diff refinement epilogue

@@ -1,6 +1,6 @@
 """Abstract interpretation over pallas grids and jaxpr buffers (R6/R9 core).
 
-Three analyses, each grounded in how the pinned jax 0.4.37 actually lowers
+Three analyses, each grounded in how the installed jax (0.9) actually lowers
 this repo's kernels (probed, not guessed):
 
 * an **affine domain over symbolic grid indices** —
@@ -169,7 +169,7 @@ def eval_index_map(index_map_jaxpr: Any, n_grid: int) -> list[AbsVal]:
     """Per-output-dim abstract block coordinates of a BlockSpec index map.
 
     ``index_map_jaxpr`` invars are the grid indices followed by the
-    scalar-prefetch refs (jax 0.4.37 ``BlockMapping.index_map_jaxpr``
+    scalar-prefetch refs (jax 0.9 ``BlockMapping.index_map_jaxpr``
     layout).  Returns one :data:`AbsVal` per output dimension.
     """
     jaxpr = unwrap(index_map_jaxpr)
@@ -299,7 +299,7 @@ def classify_kernel_writes(body: Any, n_prefetch: int, n_inputs: int,
 
     ``body`` is the kernel jaxpr whose invars are, in order, the
     scalar-prefetch refs, the input refs, the output refs and the scratch
-    refs (jax 0.4.37 ``pallas_call`` eqn ``jaxpr`` param layout).  Returns
+    refs (jax 0.9 ``pallas_call`` eqn ``jaxpr`` param layout).  Returns
     ``(writes, reads)`` where ``reads`` is the set of ref slots whose value
     is read anywhere (``("input", i)`` / ``("output", k)`` / ...).
     """
@@ -568,6 +568,15 @@ def _is_smem(aval: Any) -> bool:
     return "smem" in str(aval).lower()
 
 
+def block_dims(bm: Any) -> tuple[int, ...]:
+    """A block mapping's block shape as ints.  jax wraps each dimension
+    in a ``Blocked``/``Element``/``Squeezed`` object carrying
+    ``block_size``; a squeezed (``None``) dimension counts 1."""
+    sizes = (getattr(b, "block_size", b)
+             for b in tuple(getattr(bm, "block_shape", ()) or ()))
+    return tuple(s if isinstance(s, int) else 1 for s in sizes)
+
+
 def pallas_memory(eqn: Any) -> dict:
     """Peak VMEM/SMEM bytes one ``pallas_call`` launch needs, from its
     ``grid_mapping``: non-SMEM block mappings double-buffered, scalar
@@ -584,9 +593,8 @@ def pallas_memory(eqn: Any) -> dict:
                    for g in tuple(getattr(gm, "grid", ()) or ())]
     vmem = smem = 0
     for bm in tuple(getattr(gm, "block_mappings", ()) or ()):
-        block = tuple(1 if b is None else int(b)
-                      for b in tuple(getattr(bm, "block_shape", ()) or ()))
-        dtype = getattr(getattr(bm, "array_shape_dtype", None), "dtype", None)
+        block = block_dims(bm)
+        dtype = getattr(getattr(bm, "array_aval", None), "dtype", None)
         nb = _nbytes(block, dtype)
         if _is_smem(getattr(bm, "block_aval", "")):
             smem += nb

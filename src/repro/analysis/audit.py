@@ -1,16 +1,16 @@
-"""Audit annotations: ``check_rep=False`` bodies and determinism blessings.
+"""Audit annotations: ``check_vma=False`` bodies and determinism blessings.
 
 Two structured "the author thought about this" records, both attached to
 functions with zero-wrapper decorators (one attribute set; decorated code
 traces exactly as before):
 
-* :func:`audit_check_rep` — ``shard_map(..., check_rep=False)`` switches
+* :func:`audit_check_rep` — ``shard_map(..., check_vma=False)`` switches
   off JAX's replication checking, the mechanism that would catch a body
   producing different values on different mesh members.  Every such body
   in this tree exists because a primitive inside it (``pallas_call``) has
   no replication rule, not because the body is replication-unsafe; the
   decorator records *why* it is safe and *which collectives* make it so.
-  Rule R2 fails any unannotated ``check_rep=False`` body.
+  Rule R2 fails any unannotated ``check_vma=False`` body.
 * :func:`audit_determinism` — a float ``psum`` whose operand order depends
   on the device count, or a float scatter-add with possibly-duplicate
   indices, is a non-associative reduction whose bit pattern can move when
@@ -33,7 +33,7 @@ _F = TypeVar("_F", bound=Callable[..., Any])
 
 @dataclass(frozen=True)
 class CheckRepAudit:
-    """One audited ``check_rep=False`` body: the replication-safety argument."""
+    """One audited ``check_vma=False`` body: the replication-safety argument."""
 
     qualname: str
     module: str
@@ -53,7 +53,7 @@ _AUDIT_ATTR = "__check_rep_audit__"
 def audit_check_rep(reason: str, *,
                     collectives: tuple[str, ...] | list[str] = ()
                     ) -> Callable[[_F], _F]:
-    """Annotate a shard_map body as audited for ``check_rep=False``.
+    """Annotate a shard_map body as audited for ``check_vma=False``.
 
     ``reason`` states why the body is replication-safe; ``collectives``
     names the collective primitives (``all_gather``, ``psum``, ``ppermute``,

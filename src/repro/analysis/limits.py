@@ -62,6 +62,11 @@ _TABLE: dict[str, KernelLimits] = {
 }
 
 
+# backend spellings a pallas_call or a plan may carry -> table row
+_ALIASES = {"cpu": "tpu", "interpret": "tpu", "mosaic": "tpu",
+            "mosaic_tpu": "tpu", "triton": "gpu", "mosaic_gpu": "gpu"}
+
+
 def _env_int(name: str) -> int | None:
     raw = os.environ.get(name, "").strip()
     if not raw:
@@ -73,13 +78,16 @@ def _env_int(name: str) -> int | None:
 
 
 def limits_for_platform(platform: str | None) -> KernelLimits:
-    """Budget row for a resolved platform (unknown/None -> the TPU row:
-    every pallas kernel in this tree is written against ``pltpu``)."""
+    """Budget row for a resolved platform.  ``None`` is a ``pallas_call``
+    that pins no backend: every kernel in this tree is written against
+    ``pltpu``, so it takes the TPU row (interpret mode models the TPU memory
+    hierarchy too).  A platform the table does not know is an error, not a
+    default."""
     key = (platform or "tpu").lower()
-    if key in ("cpu", "interpret", "mosaic", "mosaic_tpu", "tpu"):
-        key = "tpu"
-    elif key not in _TABLE:
-        key = "tpu"
+    key = _ALIASES.get(key, key)
+    if key not in _TABLE:
+        raise ValueError(f"no kernel memory limits for platform {platform!r}"
+                         f"; known: {sorted(set(_TABLE) | set(_ALIASES))}")
     base = _TABLE[key]
     vmem = _env_int("REPRO_LIMIT_VMEM_BYTES")
     smem = _env_int("REPRO_LIMIT_SMEM_BYTES")

@@ -6,7 +6,7 @@ pattern: PR 4's distributed block-sparse path sorted tile lower bounds
 inside each shard (``jnp.argsort`` in the ring-worklist build) and then
 walked the order with ``ord_i[p]`` — on multi-device meshes the compiled
 module silently degraded the order-gather to the loop counter, skipping
-kept tiles with *identical wrong answers on every device* (no check_rep,
+kept tiles with *identical wrong answers on every device* (no check_vma,
 no test failure).  PR 5 found it by accident and degraded the distributed
 block-sparse phases to dense tiles behind a blunt ``S_data == 1`` guard.
 

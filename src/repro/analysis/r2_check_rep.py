@@ -1,18 +1,18 @@
-"""R2 check-rep-audit: every ``check_rep=False`` shard_map body carries an
+"""R2 check-rep-audit: every ``check_vma=False`` shard_map body carries an
 explicit :func:`repro.analysis.audit.audit_check_rep` annotation.
 
-``check_rep=False`` switches off the one JAX mechanism that would notice a
+``check_vma=False`` switches off the one JAX mechanism that would notice a
 shard body producing non-replicated values where replication is claimed —
 and this tree runs *every* kernel-backed shard body that way, because
 ``pallas_call`` has no replication rule.  The audit decorator records the
 human argument for why that is safe (which collectives make the body's
 outputs well-defined per member); R2 makes the annotation mandatory, so a
-new ``check_rep=False`` site cannot ship with the argument still in the
+new ``check_vma=False`` site cannot ship with the argument still in the
 author's head.
 
 The check is a source scan (the jaxpr has no trace of where a body
 function was defined): for each ``shard_map(...)`` call whose
-``check_rep`` keyword is anything but a literal ``True`` (absent =
+``check_vma`` keyword is anything but a literal ``True`` (absent =
 default True = fine), resolve the body argument to its ``def`` —
 
 * a function defined in an enclosing scope, or
@@ -48,9 +48,9 @@ def _is_shard_map(call: ast.Call) -> bool:
 
 
 def _check_rep_maybe_false(call: ast.Call) -> bool:
-    """True when the call's check_rep could evaluate to False at runtime."""
+    """True when the call's check_vma could evaluate to False at runtime."""
     for kw in call.keywords:
-        if kw.arg == "check_rep":
+        if kw.arg == "check_vma":
             v = kw.value
             return not (isinstance(v, ast.Constant) and v.value is True)
     return False               # absent -> default True
@@ -164,7 +164,7 @@ def scan_module(path: str, rel: str) -> list[Finding]:
                 if fn is None:
                     findings.append(Finding(
                         rule=RULE_NAME, severity="error", target=rel,
-                        message=("shard_map with check_rep that may be "
+                        message=("shard_map with check_vma that may be "
                                  "False has a body this scanner cannot "
                                  "resolve to a def — restructure so the "
                                  "body is a named local function (or a "
@@ -175,7 +175,7 @@ def scan_module(path: str, rel: str) -> list[Finding]:
                     findings.append(Finding(
                         rule=RULE_NAME, severity="error", target=rel,
                         message=(f"shard_map body `{fn.name}` runs with "
-                                 f"check_rep=False but carries no "
+                                 f"check_vma=False but carries no "
                                  f"@audit_check_rep annotation — record "
                                  f"why the body is replication-safe "
                                  f"(see repro.analysis.audit)"),
@@ -189,7 +189,7 @@ def scan_module(path: str, rel: str) -> list[Finding]:
 @dataclass(frozen=True)
 class CheckRepAuditRule(Rule):
     name: str = RULE_NAME
-    description: str = ("every check_rep=False shard_map body must carry an "
+    description: str = ("every check_vma=False shard_map body must carry an "
                         "explicit @audit_check_rep replication-safety "
                         "annotation")
     kind: str = "project"

@@ -1,9 +1,9 @@
 """R4 pallas-legality: static checks on every ``pallas_call`` instantiation.
 
 Three invariants every kernel launch in this tree is supposed to hold, all
-checkable from the ``grid_mapping`` the eqn params carry (jax 0.4.37:
+checkable from the ``grid_mapping`` the eqn params carry (jax 0.9:
 ``GridMapping`` with ``grid``, ``block_mappings`` — each a ``BlockMapping``
-with ``block_shape`` / ``array_shape_dtype`` / SMEM-typed
+with ``block_shape`` / ``array_aval`` / SMEM-typed
 ``index_map_avals`` — ``num_index_operands``, ``num_dynamic_grid_bounds``):
 
 * **grid/block divisibility** — callers pad arrays to block multiples
@@ -27,6 +27,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any
 
+from . import absint
 from .rules import Finding, Rule, register_rule
 
 RULE_NAME = "R4-pallas-legality"
@@ -62,9 +63,9 @@ def _check_pallas_eqn(target: str, site: Any) -> list[Finding]:
             break
 
     for bm in tuple(getattr(gm, "block_mappings", ()) or ()):
-        shape = tuple(getattr(getattr(bm, "array_shape_dtype", None),
+        shape = tuple(getattr(getattr(bm, "array_aval", None),
                               "shape", ()) or ())
-        block = tuple(getattr(bm, "block_shape", ()) or ())
+        block = absint.block_dims(bm)
         origin = getattr(bm, "origin", "?")
         if len(shape) != len(block):
             continue                    # mapped/squeezed dims: skip

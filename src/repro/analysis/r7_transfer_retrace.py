@@ -15,7 +15,7 @@ Two halves share the rule name:
   a strong one, so an un-normalized scalar argument silently doubles the
   trace cache (one entry per spelling the caller happens to use — retrace
   churn, measured in whole-kernel recompiles).  The probe traces the
-  plan's density primitive under each spelling and compares every ``pjit``
+  plan's density primitive under each spelling and compares every ``jit``
   boundary's ``(dtype, shape, weak_type)`` signature.
 
 The fix the probe enforces: ``DPCPlan.rho_delta`` and the ``tile_sweep``
@@ -37,7 +37,7 @@ _TRANSFER_PRIMS = ("pure_callback", "io_callback", "debug_callback",
 
 _DESCRIPTION = ("hot traced paths carry no host callbacks/transfers; "
                 "equivalent d_cut spellings hit one jit trace (stable "
-                "weak-type/dtype avals at every pjit boundary)")
+                "weak-type/dtype avals at every jit boundary)")
 
 
 @dataclass(frozen=True)
@@ -67,13 +67,13 @@ class TransferRule(Rule):
 
 # ----------------------------------------------------- retrace-churn probe
 def _jit_signature(closed: Any) -> tuple:
-    """Every ``pjit`` boundary's aval signature, outermost to innermost."""
+    """Every ``jit`` boundary's aval signature, outermost to innermost."""
     from .walker import iter_sites
 
     sig: list[Any] = []
     for site in iter_sites(closed):
         eqn = site.eqn
-        if eqn.primitive.name != "pjit":
+        if eqn.primitive.name != "jit":
             continue
         avals = tuple(
             (str(v.aval.dtype), tuple(getattr(v.aval, "shape", ())),
@@ -163,10 +163,10 @@ class RetraceChurnRule(Rule):
         for label, sig in sigs.items():
             if sig == base:
                 continue
-            boundary = "<pjit count differs>"
+            boundary = "<jit count differs>"
             for a, b in zip(base, sig):
                 if a != b:
-                    boundary = f"{a[0]}/pjit:{a[1] or '<anon>'}"
+                    boundary = f"{a[0]}/jit:{a[1] or '<anon>'}"
                     break
             out.append(Finding(
                 rule=RULE_NAME, severity="error", target=target,

@@ -16,7 +16,7 @@ under any order, and some accumulations tolerate last-bit wobble by
 design.  What *is* a bug is shipping one silently.  R8 therefore flags
 every such site that is not lexically inside an
 :func:`repro.analysis.audit.audit_determinism`-decorated function (matched
-through the traced eqn's source frames, same mechanism as R2's check_rep
+through the traced eqn's source frames, same mechanism as R2's check_vma
 audits): **error** when the reduction's value flows to the trace's
 outputs (user-visible labels / centers / densities), **warn** when it
 stays internal.
@@ -40,11 +40,15 @@ def _blessed(eqn: Any, index: dict) -> object | None:
     from jax._src import source_info_util
 
     try:
-        frames = list(source_info_util.user_frames(eqn.source_info))
+        frames = list(source_info_util.user_frames(
+            eqn.source_info.traceback))
     except Exception:       # noqa: BLE001 — source info is best-effort
         return None
     for fr in frames:
-        rec = index.get((fr.file_name, fr.function_name))
+        # frames name the function by its qualified name
+        # ("make_x.<locals>.f"); audits are keyed by the bare name
+        name = fr.function_name.rsplit(".", 1)[-1]
+        rec = index.get((fr.file_name, name))
         if rec is not None:
             return rec
     return None
@@ -91,7 +95,7 @@ def _check(target: str, closed: Any) -> list[Finding]:
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
             hit = None
-            # jax 0.4.37 traces lax.psum as "psum2" inside shard_map
+            # older jax traced lax.psum as "psum2" inside shard_map
             # bodies and "psum" under pmap — match both spellings
             if (name in ("psum", "psum2") and shard is not None
                     and shard.multi_partition

@@ -9,7 +9,7 @@ plus the project rules, folded into one JSON-able report.
      "targets": ["<spec>:<target>", ...],   # every trace analyzed
      "skipped": ["<reason>", ...],          # impossible combos, with why
      "rules":   {name: {kind, description}, ...},
-     "audits":  {key: {...}, ...},          # registered check_rep audits
+     "audits":  {key: {...}, ...},          # registered check_vma audits
      "determinism_audits": {key: {...}, ...}}
 
 Plan-time analysis is suspended for the duration

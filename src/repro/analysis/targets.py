@@ -160,7 +160,7 @@ def distributed_targets(pl: Any) -> tuple[list, list]:
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     devs = jax.devices()
@@ -190,9 +190,9 @@ def distributed_targets(pl: Any) -> tuple[list, list]:
     targets: list[tuple[str, Any]] = []
 
     def add(name: str, fn: Any, in_specs: Any, out_specs: Any,
-            args: Any, check_rep: bool = True) -> None:
+            args: Any, check_vma: bool = True) -> None:
         sm = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=check_rep)
+                       out_specs=out_specs, check_vma=check_vma)
         targets.append((name, lambda sm=sm, args=args:
                         jax.make_jaxpr(sm)(*args)))
 
@@ -200,25 +200,25 @@ def distributed_targets(pl: Any) -> tuple[list, list]:
     rho_halo = ddpc._make_rho_halo(axis, D_CUT, block, span_w, S,
                                    2 * rows, 1, 1, be)
     add("halo:rho", rho_halo, (P(axis),) * 5, P(axis),
-        (pts, starts, ends, pts, lo_arr), check_rep=not be.mxu_dense)
+        (pts, starts, ends, pts, lo_arr), check_vma=not be.mxu_dense)
     delta_halo = ddpc._make_delta_halo(axis, D_CUT, block, span_w, S,
                                        2 * rows, 1, 1, be)
     add("halo:delta", delta_halo, (P(axis),) * 7,
         (P(axis), P(axis), P(axis)),
         (pts, rk, starts, ends, pts, rk, lo_arr),
-        check_rep=not be.mxu_dense)
+        check_vma=not be.mxu_dense)
 
     # gather strategy: dense engine tiles or the grid stencil, per dispatch
     if dense:
         rho_fn = ddpc._make_rho_dense(axis, D_CUT, block, be,
                                       layout=shard_layout)
         add("dense:rho", rho_fn, (P(axis), P(axis)), P(axis),
-            (pts, pts), check_rep=False)
+            (pts, pts), check_vma=False)
         delta_fn = ddpc._make_delta_dense(axis, block, be,
                                           layout=shard_layout)
         add("dense:delta", delta_fn, (P(axis),) * 4,
             (P(axis), P(axis), P(axis)), (pts, rk, pts, rk),
-            check_rep=False)
+            check_vma=False)
     else:
         rho_fn = ddpc._make_rho(axis, D_CUT, block, span_w)
         add("stencil:rho", rho_fn, (P(axis),) * 4, P(axis),
@@ -229,7 +229,7 @@ def distributed_targets(pl: Any) -> tuple[list, list]:
         fb_fn = ddpc._make_fallback(axis, max(block, 1024), be,
                                     layout=shard_layout)
         add("stencil:fallback", fb_fn, (P(axis),) * 4, (P(axis), P(axis)),
-            (pts, rk, pts, rk), check_rep=not be.mxu_dense)
+            (pts, rk, pts, rk), check_vma=not be.mxu_dense)
     return targets, []
 
 

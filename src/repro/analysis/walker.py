@@ -1,11 +1,11 @@
 """Jaxpr walking + sort-taint propagation (the analyzer's engine).
 
-Everything here is grounded in how jax 0.4.37 actually lowers the
+Everything here is grounded in how jax 0.9 actually lowers the
 repo's code (probed, not guessed):
 
-* ``jnp.argsort`` lowers to a nested ``pjit`` eqn whose body holds ``iota``
+* ``jnp.argsort`` lowers to a nested ``jit`` eqn whose body holds ``iota``
   + ``sort`` — so taint sources hide one call level down and the engine
-  must recurse through ``pjit`` bodies.
+  must recurse through ``jit`` bodies.
 * scalar indexing ``order[p]`` inside a ``while_loop`` lowers to
   ``dynamic_slice`` (NOT ``gather``) with a traced start index; array
   indexing (``take_along_axis``, ``tbl[idx_array]``) lowers to ``gather``.
@@ -15,7 +15,7 @@ repo's code (probed, not guessed):
   fixpoint (monotone, so it terminates in <= len(carry) rounds).
 * ``shard_map`` eqn params carry the raw body ``Jaxpr`` under ``jaxpr``,
   the ``mesh``, per-operand ``in_names``/``out_names`` dicts and
-  ``check_rep``; body invars map 1:1 onto eqn invars.
+  ``check_vma``; body invars map 1:1 onto eqn invars.
 
 The taint engine answers R1's question: *does any ``gather`` /
 ``dynamic_slice`` read use an index derived from a ``sort`` computed in
@@ -59,7 +59,7 @@ class ShardCtx:
     """The shard_map context an eqn sits inside."""
 
     axis_sizes: tuple[tuple[str, int], ...]   # mapped mesh axes and sizes
-    check_rep: bool
+    check_vma: bool
 
     @property
     def multi_partition(self) -> bool:
@@ -83,7 +83,7 @@ def shard_ctx_of(eqn: Any) -> ShardCtx:
             if not names or ax in names:
                 sizes.append((str(ax), int(sz)))
     return ShardCtx(axis_sizes=tuple(sizes),
-                    check_rep=bool(eqn.params.get("check_rep", True)))
+                    check_vma=bool(eqn.params.get("check_vma", True)))
 
 
 @dataclass(frozen=True)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from .dpc_types import DPCResult, with_jitter
 from .grid import sq_dists
 from .scan import dependent_scan
+from repro.kernels.ref import sq_sum
 
 
 @partial(jax.jit, static_argnames=("k", "iters"))
@@ -62,7 +63,7 @@ def run_cfsfdp_a(points, d_cut: float, *, k: int = 32, block: int = 256,
                                             num_segments=k)
     cap = int(jnp.max(count_per_cluster))
     # cluster radii for the triangle-inequality filter
-    dist_to_own = jnp.sqrt(jnp.sum((points - cents[assign]) ** 2, -1))
+    dist_to_own = jnp.sqrt(sq_sum(points - cents[assign]))
     radius = jax.ops.segment_max(dist_to_own, assign, num_segments=k)
 
     rho = _density(points, pts_s, cents, radius, start_per_pt,
@@ -90,7 +91,7 @@ def _density(points, pts_s, cents, radius, start, count, d_cut, cap: int, block:
             idx = start[c] + jnp.arange(cap)
             valid = jnp.arange(cap) < count[c]
             cand = pts_s[jnp.minimum(idx, n - 1)]
-            d2 = jnp.sum((rows[:, None, :] - cand[None, :, :]) ** 2, -1)
+            d2 = sq_sum(rows[:, None, :] - cand[None, :, :])
             cnt = jnp.sum((d2 < d2cut) & valid[None, :], axis=1).astype(jnp.int32)
             return acc + jnp.where(keep[:, c], cnt, 0)
 

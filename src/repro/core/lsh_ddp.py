@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from .dpc_types import DPCResult, with_jitter
 from .exdpc import _pow2_pad
 from .stencil import masked_nn_rows
+from repro.kernels.ref import sq_sum
 
 
 @partial(jax.jit, static_argnames=("L", "cap", "block"))
@@ -55,7 +56,7 @@ def _bucket_round(points, key, d_cut, L: int, cap: int, block: int = 64):
         valid = (idx < n) & (bs[jnp.minimum(idx, n - 1)] ==
                              bs[jnp.minimum(i0 + jnp.arange(block), n - 1)][:, None])
         cand = pts_s[jnp.minimum(idx, n - 1)]
-        d2 = jnp.sum((rows[:, None, :] - cand) ** 2, -1)
+        d2 = sq_sum(rows[:, None, :] - cand)
         cnt = jnp.sum((d2 < d2cut) & valid, axis=1)
         return cnt, idx, valid, d2
 
@@ -144,7 +145,7 @@ def _bucket_dependent(pts_s, rk_s, bs, st_p, cap: int, block: int):
                             bs[jnp.minimum(rowi, n - 1)][:, None])
         cand = pts_s[jnp.minimum(idx, n - 1)]
         crk = rk_s[jnp.minimum(idx, n - 1)]
-        d2 = jnp.sum((rows[:, None, :] - cand) ** 2, -1)
+        d2 = sq_sum(rows[:, None, :] - cand)
         d2 = jnp.where(same & (crk > rks[:, None]), d2, jnp.inf)
         j = jnp.argmin(d2, axis=1)
         best = d2[jnp.arange(block), j]

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from .grid import Grid, cell_span_bounds, point_span_bounds
+from repro.kernels.ref import sq_sum
 
 
 def _pad_to(x: jnp.ndarray, m: int, axis: int = 0, value=0):
@@ -48,7 +49,7 @@ def density_per_point(grid: Grid, block: int = 256) -> jnp.ndarray:
         idx = st[..., None] + jnp.arange(W, dtype=st.dtype)           # (B, S, W)
         valid = idx < en[..., None]
         cand = grid.points[jnp.minimum(idx, n - 1)]                   # (B, S, W, d)
-        d2 = jnp.sum((rows[:, None, None, :] - cand) ** 2, axis=-1)
+        d2 = sq_sum(rows[:, None, None, :] - cand)
         return jnp.sum((d2 < d2cut) & valid, axis=(1, 2))
 
     cnt = jax.lax.map(chunk, jnp.arange(nb) * block).reshape(-1)[:n]
@@ -89,7 +90,7 @@ def density_per_cell(grid: Grid, block: int = 32) -> jnp.ndarray:
         cand = grid.points[jnp.minimum(cidx, n - 1)]                  # (B, S, W, d)
         cand = cand.reshape(block, S * W, d)
         cvalid = cvalid.reshape(block, S * W)
-        d2 = jnp.sum((members[:, :, None, :] - cand[:, None, :, :]) ** 2, -1)
+        d2 = sq_sum(members[:, :, None, :] - cand[:, None, :, :])
         cnt = jnp.sum((d2 < d2cut) & cvalid[:, None, :], axis=-1)     # (B, M)
         return cnt, midx, mvalid
 
@@ -130,7 +131,7 @@ def dependent_stencil(grid: Grid, rho_key_sorted: jnp.ndarray, block: int = 256)
         idx_c = jnp.minimum(idx, n - 1)
         cand = grid.points[idx_c]                                     # (B,S,W,d)
         cand_rk = rho_key_sorted[idx_c]
-        d2 = jnp.sum((rows[:, None, None, :] - cand) ** 2, axis=-1)
+        d2 = sq_sum(rows[:, None, None, :] - cand)
         mask = valid & (cand_rk > rk[:, None, None]) & (d2 < d2cut)
         d2m = jnp.where(mask, d2, jnp.inf).reshape(block, S * W)
         j = jnp.argmin(d2m, axis=1)
@@ -169,7 +170,7 @@ def density_for_slots(grid: Grid, slots: jnp.ndarray, block: int = 256) -> jnp.n
         idx = st[..., None] + jnp.arange(W, dtype=st.dtype)
         valid = idx < en[..., None]
         cand = grid.points[jnp.minimum(idx, n - 1)]
-        d2 = jnp.sum((rows[:, None, None, :] - cand) ** 2, axis=-1)
+        d2 = sq_sum(rows[:, None, None, :] - cand)
         cnt = jnp.sum((d2 < d2cut) & valid, axis=(1, 2))
         return jnp.where(alive, cnt, 0)
 
@@ -207,7 +208,7 @@ def dependent_stencil_slots(grid: Grid, rho_key_sorted: jnp.ndarray,
         idx_c = jnp.minimum(idx, n - 1)
         cand = grid.points[idx_c]
         cand_rk = rho_key_sorted[idx_c]
-        d2 = jnp.sum((rows[:, None, None, :] - cand) ** 2, axis=-1)
+        d2 = sq_sum(rows[:, None, None, :] - cand)
         mask = valid & (cand_rk > rk[:, None, None]) & (d2 < d2cut)
         d2m = jnp.where(mask, d2, jnp.inf).reshape(block, S * W)
         j = jnp.argmin(d2m, axis=1)
@@ -237,7 +238,7 @@ def masked_nn_rows(query_pts, query_rk, all_pts, all_rk, block: int = 4096):
     def col_block(j0):
         cols = jax.lax.dynamic_slice_in_dim(pts_p, j0, block, 0)
         crk = jax.lax.dynamic_slice_in_dim(rk_p, j0, block, 0)
-        d2 = jnp.sum((query_pts[:, None, :] - cols[None, :, :]) ** 2, -1)
+        d2 = sq_sum(query_pts[:, None, :] - cols[None, :, :])
         d2 = jnp.where(crk[None, :] > query_rk[:, None], d2, jnp.inf)
         j = jnp.argmin(d2, axis=1)
         return d2[jnp.arange(m), j], (j0 + j).astype(jnp.int32)

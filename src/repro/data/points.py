@@ -11,6 +11,8 @@
 """
 from __future__ import annotations
 
+import zlib
+
 import numpy as np
 
 DOMAIN = 1e5
@@ -103,7 +105,9 @@ _REAL_PROXIES = {
 def real_proxy(name: str, n: int, seed: int = 0, domain: float = DOMAIN):
     """Skewed-density mixture matched to the real dataset's dim/cardinality."""
     d, skew, k = _REAL_PROXIES[name]
-    rng = np.random.default_rng(seed + hash(name) % 2**16)
+    # a per-name offset that is the same in every process (str hash() is
+    # salted per process, so it would draw a different dataset each run)
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()) % 2**16)
     centers = rng.uniform(0.1 * domain, 0.9 * domain, size=(k, d))
     # power-law cluster sizes -> skewed densities (what defeats k-means pivots)
     weights = rng.pareto(skew, k) + 0.05

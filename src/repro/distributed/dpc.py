@@ -36,7 +36,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro import obs
 from repro.analysis.audit import audit_check_rep
@@ -45,6 +45,7 @@ from repro.core.grid import build_grid, point_span_bounds
 from repro.engine.planner import as_plan
 from repro.engine.spec import ExecSpec, merge_legacy
 from repro.launch.mesh import flatten_mesh
+from repro.kernels.ref import sq_sum
 
 _STRATEGIES = ("gather", "halo")
 
@@ -219,7 +220,7 @@ def _make_rho(axis, d_cut, block, span_w):
             idx = st[..., None] + jnp.arange(span_w, dtype=st.dtype)
             valid = idx < en[..., None]
             cand = tbl[jnp.minimum(idx, n - 1)]
-            d2 = jnp.sum((rows[:, None, None, :] - cand) ** 2, axis=-1)
+            d2 = sq_sum(rows[:, None, None, :] - cand)
             return jnp.sum((d2 < d2cut) & valid, axis=(1, 2))
 
         cnt = jax.lax.map(chunk, jnp.arange(nb) * block).reshape(-1)[:m]
@@ -253,7 +254,7 @@ def _make_delta(axis, d_cut, block, span_w):
             idx_c = jnp.minimum(idx, n - 1)
             cand = tbl[idx_c]
             cand_rk = rk_all[idx_c]
-            d2 = jnp.sum((rows[:, None, None, :] - cand) ** 2, axis=-1)
+            d2 = sq_sum(rows[:, None, None, :] - cand)
             mask = valid & (cand_rk > rk[:, None, None]) & (d2 < d2cut)
             d2m = jnp.where(mask, d2, jnp.inf).reshape(block, -1)
             j = jnp.argmin(d2m, axis=1)
@@ -340,10 +341,10 @@ def _bs_shards_safe(flat_mesh, axis: str, be) -> bool:
     delta_fn = _make_delta_dense(axis, 256, be, layout="block-sparse")
     sm_rho = shard_map(rho_fn, mesh=flat_mesh,
                        in_specs=(P(axis), P(axis)), out_specs=P(axis),
-                       check_rep=False)
+                       check_vma=False)
     sm_delta = shard_map(delta_fn, mesh=flat_mesh, in_specs=(P(axis),) * 4,
                          out_specs=(P(axis), P(axis), P(axis)),
-                         check_rep=False)
+                         check_vma=False)
     pts = jnp.zeros((S * 8, 2), jnp.float32)
     rk = jnp.zeros((S * 8,), jnp.float32)
     ok = bool(spmd_gather_safe(sm_rho, pts, pts)
@@ -495,7 +496,7 @@ def distributed_dpc(points, cfg: DistDPCConfig | None = None,
                                 S_data, W, hf, hb, be)
         sm_rho = shard_map(rho_fn, mesh=flat_mesh,
                            in_specs=(P(axis),) * 5, out_specs=P(axis),
-                           check_rep=not be.mxu_dense)  # pallas: no rep rule
+                           check_vma=not be.mxu_dense)  # pallas: no rep rule
         with obs.span("dist.rho", n=n, shards=S_data,
                       strategy=cfg.strategy) as sp:
             rho_sorted = sp.sync(jax.jit(sm_rho)(
@@ -505,7 +506,7 @@ def distributed_dpc(points, cfg: DistDPCConfig | None = None,
                                  layout=shard_layout)
         sm_rho = shard_map(rho_fn, mesh=flat_mesh,
                            in_specs=(P(axis), P(axis)), out_specs=P(axis),
-                           check_rep=False)   # pallas_call lacks a rep rule
+                           check_vma=False)   # pallas_call lacks a rep rule
         with obs.span("dist.rho", n=n, shards=S_data,
                       strategy=cfg.strategy) as sp:
             rho_sorted = sp.sync(jax.jit(sm_rho)(pts_s, pts_s)[:n])
@@ -530,7 +531,7 @@ def distributed_dpc(points, cfg: DistDPCConfig | None = None,
         sm_delta = shard_map(delta_fn, mesh=flat_mesh,
                              in_specs=(P(axis),) * 7,
                              out_specs=(P(axis), P(axis), P(axis)),
-                             check_rep=not be.mxu_dense)  # pallas: no rep rule
+                             check_vma=not be.mxu_dense)  # pallas: no rep rule
         with obs.span("dist.delta", n=n, shards=S_data) as sp:
             dlt_s, par_s, ok_s = sp.sync(jax.jit(sm_delta)(
                 pts_s, rk_query, starts_p, ends_p, pts_s, rk_sorted_full,
@@ -541,7 +542,7 @@ def distributed_dpc(points, cfg: DistDPCConfig | None = None,
         sm_delta = shard_map(delta_fn, mesh=flat_mesh,
                              in_specs=(P(axis),) * 4,
                              out_specs=(P(axis), P(axis), P(axis)),
-                             check_rep=False)  # pallas_call lacks a rep rule
+                             check_vma=False)  # pallas_call lacks a rep rule
         with obs.span("dist.delta", n=n, shards=S_data) as sp:
             dlt_s, par_s, ok_s = sp.sync(jax.jit(sm_delta)(
                 pts_s, rk_query, pts_s, rk_sorted_full))
@@ -574,7 +575,7 @@ def distributed_dpc(points, cfg: DistDPCConfig | None = None,
         sm_fb = shard_map(fb_fn, mesh=flat_mesh,
                           in_specs=(P(axis), P(axis), P(axis), P(axis)),
                           out_specs=(P(axis), P(axis)),
-                          check_rep=not fb_be.mxu_dense)
+                          check_vma=not fb_be.mxu_dense)
         with obs.span("dist.fallback", unresolved=int(unresolved.size),
                       shards=S_data) as sp:
             fd, fp = sp.sync(jax.jit(sm_fb)(q_pts, q_rk, pts_s,

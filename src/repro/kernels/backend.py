@@ -60,11 +60,13 @@ exactly-representable data (tested), and up to bf16 rounding elsewhere
 input order; only the pruning rate does.
 
 ``get_backend(None)`` auto-detects: ``pallas`` on TPU, ``jnp`` elsewhere.
-Numerical contract: the pallas backends compute squared distances in the MXU
-expanded form |x|^2+|y|^2-2xy (then re-rank the top-k candidates direct-diff,
-see sweep.refine_topk_d2), so pairs within f32 rounding of a threshold
-can be classified differently from ``jnp``.  Equality tests draw data away
-from thresholds; production consumers treat the backends as interchangeable.
+Numerical contract: every d_cut test (range counts, the halo NN's within-
+d_cut gate) compares direct-difference distances rounded exactly as the jnp
+reference rounds them (``ref.sq_sum_parts``), so counts agree bit for bit
+across backends.  The pallas NN accumulators rank candidates in the MXU
+expanded form |x|^2+|y|^2-2xy and re-rank the top-k direct-diff (see
+sweep.refine_topk_d2), so only a near-tie whose true winner falls outside
+the expanded top-k can pick a different neighbour than ``jnp``.
 The pallas backends additionally accept ``precision="bf16"`` on ``rho_delta``
 (bf16 inner product at twice the MXU rate, winners refined back to f32
 direct-diff); the jnp backend is the f32 reference and rejects it.
@@ -78,6 +80,8 @@ import jax
 import jax.numpy as jnp
 
 from . import blocksparse, ops
+from .blocksparse import vary_like
+from .ref import sq_sum
 
 __all__ = ["KernelBackend", "available_backends", "default_backend_name",
            "get_backend", "register_backend", "rho_delta_sequential"]
@@ -281,11 +285,12 @@ def _range_count_jnp(x, y, d_cut, block: int = 512):
 
         def col_block(j, acc):
             cols = jax.lax.dynamic_slice_in_dim(yp, j * block, block, 0)
-            d2 = jnp.sum((rows[:, None, :] - cols[None, :, :]) ** 2, -1)
+            d2 = sq_sum(rows[:, None, :] - cols[None, :, :])
             return acc + jnp.sum(d2 < d2cut, axis=1).astype(jnp.int32)
 
-        return jax.lax.fori_loop(0, nbc, col_block,
-                                 jnp.zeros((block,), jnp.int32))
+        return jax.lax.fori_loop(
+            0, nbc, col_block, vary_like(jnp.zeros((block,), jnp.int32),
+                                         xp, yp))
 
     cnt = jax.lax.map(row_block, jnp.arange(nbr) * block).reshape(-1)[:n]
     return cnt.astype(jnp.float32)
@@ -310,7 +315,7 @@ def _denser_nn_jnp(x, x_key, y, y_key, block: int = 512):
             best, arg = carry
             cols = jax.lax.dynamic_slice_in_dim(yp, j * block, block, 0)
             crk = jax.lax.dynamic_slice_in_dim(yk, j * block, block, 0)
-            d2 = jnp.sum((rows[:, None, :] - cols[None, :, :]) ** 2, -1)
+            d2 = sq_sum(rows[:, None, :] - cols[None, :, :])
             d2 = jnp.where(crk[None, :] > rrk[:, None], d2, jnp.inf)
             jj = jnp.argmin(d2, axis=1)
             cand = d2[jnp.arange(block), jj]
@@ -319,8 +324,9 @@ def _denser_nn_jnp(x, x_key, y, y_key, block: int = 512):
                     jnp.where(better, j * block + jj, arg))
 
         best, arg = jax.lax.fori_loop(
-            0, nbc, col_block,
-            (jnp.full((block,), jnp.inf), jnp.full((block,), -1, jnp.int64)))
+            0, nbc, col_block, vary_like(
+                (jnp.full((block,), jnp.inf),
+                 jnp.full((block,), -1, jnp.int64)), xp, xk, yp, yk))
         return jnp.sqrt(best), jnp.where(jnp.isfinite(best), arg, -1)
 
     delta, parent = jax.lax.map(row_block, jnp.arange(nbr) * block)
@@ -349,12 +355,13 @@ def _range_count_delta_jnp(x, batch, signs, d_cut, block: int = 512):
         def col_block(j, acc):
             cols = jax.lax.dynamic_slice_in_dim(bp, j * block, block, 0)
             s = jax.lax.dynamic_slice_in_dim(sp, j * block, block, 0)
-            d2 = jnp.sum((rows[:, None, :] - cols[None, :, :]) ** 2, -1)
+            d2 = sq_sum(rows[:, None, :] - cols[None, :, :])
             return acc + jnp.sum(jnp.where(d2 < d2cut, s[None, :], 0.0),
                                  axis=1)
 
-        return jax.lax.fori_loop(0, nbc, col_block,
-                                 jnp.zeros((block,), jnp.float32))
+        return jax.lax.fori_loop(
+            0, nbc, col_block, vary_like(jnp.zeros((block,), jnp.float32),
+                                         xp, bp, sp))
 
     cnt = jax.lax.map(row_block, jnp.arange(nbr) * block).reshape(-1)[:n]
     return cnt
@@ -383,10 +390,11 @@ def _rho_delta_jnp(x, y, jitter, d_cut, y_sel_slots=None, block: int = 512):
 
         def col(j, acc):
             cols = jax.lax.dynamic_slice_in_dim(yp, j * block, block, 0)
-            d2 = jnp.sum((rows[:, None, :] - cols[None, :, :]) ** 2, -1)
+            d2 = sq_sum(rows[:, None, :] - cols[None, :, :])
             return acc + jnp.sum(d2 < d2cut, axis=1).astype(jnp.int32)
 
-        return jax.lax.fori_loop(0, nbc, col, jnp.zeros((block,), jnp.int32))
+        return jax.lax.fori_loop(
+            0, nbc, col, vary_like(jnp.zeros((block,), jnp.int32), xp, yp))
 
     cnt = jax.lax.map(row_count, jnp.arange(nbr) * block).reshape(-1)[:n]
     rho = cnt.astype(jnp.float32)
@@ -408,21 +416,22 @@ def _rho_delta_jnp(x, y, jitter, d_cut, y_sel_slots=None, block: int = 512):
             best, jwin = carry
             cols = jax.lax.dynamic_slice_in_dim(yp, j * block, block, 0)
             crk = jax.lax.dynamic_slice_in_dim(ckp, j * block, block, 0)
-            d2 = jnp.sum((rows[:, None, :] - cols[None, :, :]) ** 2, -1)
+            d2 = sq_sum(rows[:, None, :] - cols[None, :, :])
             cand = jnp.min(jnp.where(crk[None, :] > rrk[:, None], d2,
                                      jnp.inf), axis=1)
             better = cand < best
             return (jnp.where(better, cand, best), jnp.where(better, j, jwin))
 
         best, jwin = jax.lax.fori_loop(
-            0, nbc, col, (jnp.full((block,), jnp.inf),
-                          jnp.zeros((block,), jnp.int32)))
+            0, nbc, col, vary_like((jnp.full((block,), jnp.inf),
+                                    jnp.zeros((block,), jnp.int32)),
+                                   xp, yp, rkp, ckp))
         # recover the argmin inside each row's winning tile (same float ops
         # on the same operands -> bitwise-equal d2 -> the sequential winner)
         cidx = jwin[:, None] * block + jnp.arange(block)[None, :]
         cols = yp[cidx]                              # (block, block, d)
         crk = ckp[cidx]
-        d2r = jnp.sum((rows[:, None, :] - cols) ** 2, -1)
+        d2r = sq_sum(rows[:, None, :] - cols)
         d2m = jnp.where(crk > rrk[:, None], d2r, jnp.inf)
         jloc = jnp.argmin(d2m, axis=1)
         parent = jnp.where(jnp.isfinite(best),
@@ -454,7 +463,7 @@ def _range_count_halo_jnp(x, window, starts, ends, d_cut, span_w: int,
         idx = st[..., None] + jnp.arange(span_w, dtype=st.dtype)
         valid = (idx < en[..., None]) & (idx >= 0)
         cand = window[jnp.clip(idx, 0, W - 1)]
-        d2 = jnp.sum((rows[:, None, None, :] - cand) ** 2, axis=-1)
+        d2 = sq_sum(rows[:, None, None, :] - cand)
         return jnp.sum((d2 < d2cut) & valid, axis=(1, 2))
 
     cnt = jax.lax.map(chunk, jnp.arange(nb) * block).reshape(-1)[:m]
@@ -486,7 +495,7 @@ def _denser_nn_halo_jnp(x, x_key, window, w_key, starts, ends, d_cut,
         idx_c = jnp.clip(idx, 0, W - 1)
         cand = window[idx_c]
         cand_rk = w_key[idx_c]
-        d2 = jnp.sum((rows[:, None, None, :] - cand) ** 2, axis=-1)
+        d2 = sq_sum(rows[:, None, None, :] - cand)
         mask = valid & (cand_rk > rk[:, None, None]) & (d2 < d2cut)
         d2m = jnp.where(mask, d2, jnp.inf).reshape(block, -1)
         j = jnp.argmin(d2m, axis=1)
@@ -584,7 +593,7 @@ def _fused_resolve(x, y, rho_key, col_key, topv, topi):
     n, k = topi.shape
     ti = jnp.maximum(topi, 0)
     y_sel = y[ti]                                      # (n, k, d)
-    d2d = jnp.sum((x[:, None, :] - y_sel) ** 2, -1)
+    d2d = sq_sum(x[:, None, :] - y_sel)
     ok = (topi >= 0) & (col_key[ti] > rho_key[:, None])
     cand = jnp.where(ok, d2d, jnp.inf)
     best = jnp.min(cand, axis=1)

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs import metrics as _obsm
+from .ref import sq_sum
 
 # Conservative slack on the f32 bound arithmetic: shrink lower bounds / grow
 # upper bounds by ~10 ulp-equivalents so a bound can never out-round the
@@ -72,6 +73,20 @@ UB_GROW = 1.0 + 1e-5
 # 64k acceptance shape: (128, 256) beats (256, 256) and (512, 512)).
 BS_BLOCK_N = 128
 BS_BLOCK_M = 256
+
+
+def vary_like(init, *refs):
+    """``init`` marked as varying over every mesh axis ``refs`` vary over.
+
+    A loop whose body mixes per-shard data into its carry must start from a
+    carry of the same type inside ``shard_map`` (jax's varying-axes check);
+    outside ``shard_map`` nothing varies and ``init`` comes back as is.
+    """
+    axes = frozenset().union(*(jax.typeof(r).vma for r in refs))
+    if not axes:
+        return init
+    return jax.tree.map(
+        lambda a: jax.lax.pcast(a, tuple(sorted(axes)), to="varying"), init)
 
 
 def _pad_inf(x: jnp.ndarray, block: int) -> jnp.ndarray:
@@ -194,7 +209,7 @@ def _count_bs_jnp(x, y, weights, d_cut, bn: int = BS_BLOCK_N,
             p, acc = c
             sel = (rank_i == p).astype(jnp.float32)
             cols = _onehot_pick(sel, ypf).reshape(bm, d)
-            d2 = jnp.sum((rows[:, None, :] - cols[None, :, :]) ** 2, -1)
+            d2 = sq_sum(rows[:, None, :] - cols[None, :, :])
             if signed:
                 s = _onehot_pick(sel, wp)
                 upd = jnp.sum(jnp.where(d2 < d2cut, s[None, :], 0.0), axis=1)
@@ -202,9 +217,9 @@ def _count_bs_jnp(x, y, weights, d_cut, bn: int = BS_BLOCK_N,
                 upd = jnp.sum(d2 < d2cut, axis=1).astype(jnp.float32)
             return p + 1, acc + upd
 
-        _, acc = jax.lax.while_loop(lambda c: c[0] < kc, body,
-                                    (jnp.int32(0),
-                                     jnp.zeros((bn,), jnp.float32)))
+        _, acc = jax.lax.while_loop(
+            lambda c: c[0] < kc, body,
+            (jnp.int32(0), vary_like(jnp.zeros((bn,), jnp.float32), xp, yp)))
         return acc
 
     cnt = jax.lax.map(row_tile, jnp.arange(nbr)).reshape(-1)[:n]
@@ -255,7 +270,7 @@ def _nn_ring_rows(xp, rkp, yp, ckp, n, rank, lb, bn: int, bm: int):
             cols = picked[:, :d]
             crk = jnp.where(picked[:, d] <= -_FINITE_CAP, -jnp.inf,
                             picked[:, d])
-            d2 = jnp.sum((rows[:, None, :] - cols[None, :, :]) ** 2, -1)
+            d2 = sq_sum(rows[:, None, :] - cols[None, :, :])
             d2m = jnp.where(crk[None, :] > rrk[:, None], d2, jnp.inf)
             cand = jnp.min(d2m, axis=1)
             carg = (j * bm + jnp.argmin(d2m, axis=1)).astype(jnp.int32)
@@ -265,8 +280,9 @@ def _nn_ring_rows(xp, rkp, yp, ckp, n, rank, lb, bn: int, bm: int):
                     jnp.where(better | tie, carg, barg))
 
         _, best, barg = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), jnp.full((bn,), jnp.inf),
-                         jnp.full((bn,), int_max, jnp.int32)))
+            cond, body, (jnp.int32(0), *vary_like(
+                (jnp.full((bn,), jnp.inf),
+                 jnp.full((bn,), int_max, jnp.int32)), xp, rkp, yp, ckp)))
         parent = jnp.where(jnp.isfinite(best), barg, -1)
         return jnp.sqrt(best), parent
 
@@ -322,12 +338,12 @@ def _rho_delta_bs_jnp(x, y, jitter, d_cut, y_sel_slots=None,
             p, acc = c
             sel = (rank_i == p).astype(jnp.float32)
             cols = _onehot_pick(sel, ypf).reshape(bm, d)
-            d2 = jnp.sum((rows[:, None, :] - cols[None, :, :]) ** 2, -1)
+            d2 = sq_sum(rows[:, None, :] - cols[None, :, :])
             return p + 1, acc + jnp.sum(d2 < d2cut, axis=1).astype(jnp.int32)
 
-        _, acc = jax.lax.while_loop(lambda c: c[0] < kc, body,
-                                    (jnp.int32(0),
-                                     jnp.zeros((bn,), jnp.int32)))
+        _, acc = jax.lax.while_loop(
+            lambda c: c[0] < kc, body,
+            (jnp.int32(0), vary_like(jnp.zeros((bn,), jnp.int32), xp, yp)))
         return acc
 
     cnt = jax.lax.map(row_count, jnp.arange(nbr)).reshape(-1)[:n]
@@ -349,23 +365,67 @@ def _rho_delta_bs_jnp(x, y, jitter, d_cut, y_sel_slots=None,
 # =====================================================================
 # host-built flat worklists (the pallas scalar-prefetch grid)
 # =====================================================================
+# Worklist entries per pallas_call.  Each call scalar-prefetches its chunk
+# into SMEM — (4, C) int32 + (C,) f32, 160 KiB at this size — so the SMEM
+# footprint stays fixed however many tile pairs a fit keeps.
+WL_CHUNK = 8192
+
+
 @dataclass(frozen=True)
 class FlatWorklist:
-    """A kept tile-pair list driving one 1-D ``tile_sweep`` grid.
+    """A kept tile-pair list driving ``tile_sweep``'s worklist grid.
 
     ``meta`` rows: [row_tile, col_tile, first-visit flag, in-d_cut flag];
     entries sorted by (row_tile, lb) so output blocks are revisited
-    consecutively (the Mosaic accumulation contract) in ring order.
+    consecutively (the Mosaic accumulation contract) in ring order.  The
+    list is cut into chunks of whole row tiles, each padded to the same
+    length C with inert entries (flag -1), one ``pallas_call`` per chunk.
     """
 
-    meta: jnp.ndarray          # (4, W) int32
-    lb: jnp.ndarray            # (W,) f32 — the in-kernel NN prune radius
+    meta: jnp.ndarray          # (chunks, 4, C) int32
+    lb: jnp.ndarray            # (chunks, C) f32 — the in-kernel NN prune radius
     n_kept: int                # worklist entries (incl. forced keeps)
     n_total: int               # nbr * nbc dense pair count
 
     @property
     def pruned_frac(self) -> float:
         return 1.0 - self.n_kept / max(self.n_total, 1)
+
+
+def _pow2(v: int) -> int:
+    return 1 << max(int(v) - 1, 0).bit_length()
+
+
+def chunk_worklist(meta: np.ndarray, lb: np.ndarray,
+                   chunk: int) -> tuple[np.ndarray, np.ndarray]:
+    """Cut a (4, W) row-sorted worklist into (chunks, 4, C) / (chunks, C).
+
+    Chunks hold whole row tiles, greedily packed up to C entries, where C
+    is a power of two (one compiled kernel serves every worklist of that
+    bucket) no smaller than the longest row tile.  Padding entries repeat
+    the chunk's last (row, col) pair — no new block DMA, no write-back of a
+    block the chunk did not finish — with flag -1 and an infinite bound, so
+    no accumulator reads them.
+    """
+    W = meta.shape[1]
+    bounds = np.flatnonzero(np.diff(meta[0])) + 1
+    row_start = np.concatenate([[0], bounds, [W]])
+    C = _pow2(max(np.diff(row_start).max(), min(W, chunk), 8))
+    cuts = [0]
+    for a, b in zip(row_start[:-1], row_start[1:]):     # row tile [a, b)
+        if b - cuts[-1] > C:
+            cuts.append(int(a))
+    cuts.append(W)
+    metas, lbs = [], []
+    for a, b in zip(cuts[:-1], cuts[1:]):
+        pad = C - (b - a)
+        fill = np.array([[meta[0, b - 1]], [meta[1, b - 1]], [0], [-1]],
+                        np.int32)
+        metas.append(np.concatenate([meta[:, a:b], np.repeat(fill, pad, 1)],
+                                    axis=1))
+        lbs.append(np.concatenate([lb[a:b], np.full(pad, np.inf,
+                                                    np.float32)]))
+    return np.stack(metas), np.stack(lbs)
 
 
 def _host_bounds(arr: np.ndarray, block: int):
@@ -618,8 +678,8 @@ def build_flat_worklist(x, y, d_cut=None, *, block_n: int, block_m: int,
     first[np.unique(wi, return_index=True)[1]] = 1
     meta = np.stack([wi, wj, first,
                      in_cut[wi, wj].astype(np.int64)]).astype(np.int32)
-    out = FlatWorklist(meta=jnp.asarray(meta),
-                       lb=jnp.asarray(wl.astype(np.float32)),
+    meta_c, lb_c = chunk_worklist(meta, wl.astype(np.float32), WL_CHUNK)
+    out = FlatWorklist(meta=jnp.asarray(meta_c), lb=jnp.asarray(lb_c),
                        n_kept=len(wi), n_total=nbr * nbc)
     _G_WL_LEN.set(out.n_kept)
     _G_WL_PRUNED.set(round(out.pruned_frac, 6))

@@ -1,8 +1,9 @@
 """Range-count kernels (local density, Def. 1) — tile-sweep instantiations.
 
 The compute hot spot of DPC's rho phase: an (n x m) pairwise-distance problem
-tiled into (BLOCK_N x BLOCK_M) VMEM blocks, squared distances in the MXU
-expanded form, counts accumulated across the column grid dimension.  Since
+tiled into (BLOCK_N x BLOCK_M) VMEM blocks, squared distances in direct-
+difference form (rounded as the jnp reference rounds them), counts
+accumulated across the column grid dimension.  Since
 the unified engine landed, this module is the *instantiation* of
 ``kernels.sweep`` for the two count-only primitives; the kernel body itself
 lives in ``sweep.tile_sweep`` (one ``SweepSpec`` per primitive).
@@ -26,16 +27,14 @@ from .sweep import (DEFAULT_BLOCK_M, DEFAULT_BLOCK_N, PAD_COORD,  # noqa: F401
 
 def range_count(x: jnp.ndarray, y: jnp.ndarray, d_cut,
                 block_n: int = DEFAULT_BLOCK_N, block_m: int = DEFAULT_BLOCK_M,
-                interpret: bool = False,
-                precision: str = "f32", worklist=None) -> jnp.ndarray:
+                interpret: bool = False, worklist=None) -> jnp.ndarray:
     """For each row of x (n, d): |{j : ||x_i - y_j|| < d_cut}| over y (m, d).
 
     x and y must already be padded to multiples of block_n/block_m with
     PAD_COORD rows (ops.pad_points does this).  ``d_cut`` may be a python
     float or a traced f32 scalar.
     """
-    spec = SweepSpec(block_n=block_n, block_m=block_m, count=True,
-                     precision=precision)
+    spec = SweepSpec(block_n=block_n, block_m=block_m, count=True)
     wm, wb = (worklist.meta, worklist.lb) if worklist is not None else (None,
                                                                         None)
     (cnt,) = tile_sweep(spec, x, y, d_cut, wl_meta=wm, wl_lb=wb,
@@ -47,7 +46,7 @@ def range_count_signed(x: jnp.ndarray, y: jnp.ndarray, signs: jnp.ndarray,
                        d_cut, block_n: int = DEFAULT_BLOCK_N,
                        block_m: int = DEFAULT_BLOCK_M,
                        interpret: bool = False,
-                       precision: str = "f32", worklist=None) -> jnp.ndarray:
+                       worklist=None) -> jnp.ndarray:
     """For each row of x: sum_j signs[j] * [||x_i - y_j|| < d_cut], f32.
 
     The streaming rho-repair kernel — every surviving point's density changes
@@ -57,7 +56,7 @@ def range_count_signed(x: jnp.ndarray, y: jnp.ndarray, signs: jnp.ndarray,
     rows must carry sign 0 (and PAD_COORD keeps them outside any d_cut).
     """
     spec = SweepSpec(block_n=block_n, block_m=block_m, count=True,
-                     signed=True, precision=precision)
+                     signed=True)
     wm, wb = (worklist.meta, worklist.lb) if worklist is not None else (None,
                                                                         None)
     (cnt,) = tile_sweep(spec, x, y, d_cut, signs=signs, wl_meta=wm, wl_lb=wb,
@@ -70,7 +69,7 @@ def range_count_halo(x: jnp.ndarray, window: jnp.ndarray,
                      block_n: int = DEFAULT_BLOCK_N,
                      block_m: int = DEFAULT_BLOCK_M,
                      interpret: bool = False,
-                     precision: str = "f32", worklist=None) -> jnp.ndarray:
+                     worklist=None) -> jnp.ndarray:
     """Range count against per-row ragged [start, end) windows (halo tiles).
 
     The distributed halo layout: each x-row counts only the window columns
@@ -79,7 +78,7 @@ def range_count_halo(x: jnp.ndarray, window: jnp.ndarray,
     contract; padded x rows must carry empty spans.
     """
     spec = SweepSpec(block_n=block_n, block_m=block_m, count=True, span=True,
-                     span_s=starts.shape[1], precision=precision)
+                     span_s=starts.shape[1])
     wm, wb = (worklist.meta, worklist.lb) if worklist is not None else (None,
                                                                         None)
     (cnt,) = tile_sweep(spec, x, window, d_cut, starts=starts, ends=ends,

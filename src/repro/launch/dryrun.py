@@ -16,12 +16,11 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 from repro.configs import (ARCHS, SHAPES, input_specs, reduce_config,  # noqa: E402
                            skip_reason)
 from repro.launch.hlo_stats import collective_bytes          # noqa: E402
-from repro.launch.mesh import (activate_mesh, batch_sharding,   # noqa: E402
-                               batch_spec, make_production_mesh, rules_for,
-                               specs_to_shardings)
+from repro.launch.mesh import (batch_sharding, batch_spec,   # noqa: E402
+                               make_production_mesh, rules_for)
 from repro.models import build_model                         # noqa: E402
 from repro.models import transformer as tfm                  # noqa: E402
-from repro.launch.hlo_cost import analyze_compiled, xla_cost_dict  # noqa: E402
+from repro.launch.hlo_cost import analyze_compiled           # noqa: E402
 from repro.train import TrainStepConfig, make_train_step     # noqa: E402
 from repro.train.optimizer import adamw_init, opt_state_specs  # noqa: E402
 
@@ -113,7 +112,7 @@ def _analyze(fn, args, in_sh, out_sh, save_hlo=None, donate=()):
     compiled = lowered.compile()
     t_compile = time.time() - t0
     mem = compiled.memory_analysis()
-    xla_cost = xla_cost_dict(compiled)
+    xla_cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     if save_hlo:
         with open(save_hlo, "w") as f:
@@ -162,13 +161,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     rec["devices"] = mesh.devices.size
     rec["variant"] = {"accumulation": accumulation, "data_only": data_only}
 
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, args, in_sh, out_sh, donate = _spec_step(cfg, shape, rules,
                                                      microbatches,
                                                      accumulation)
-        if not hasattr(jax, "set_mesh"):   # 0.4.x: jit wants Shardings
-            in_sh = specs_to_shardings(mesh, in_sh)
-            out_sh = specs_to_shardings(mesh, out_sh)
         rec.update(_analyze(fn, args, in_sh, out_sh, save_hlo=save_hlo,
                             donate=donate))
     rec["microbatches"] = microbatches if shape.kind == "train" else 1

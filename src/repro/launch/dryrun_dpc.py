@@ -12,7 +12,7 @@ import time         # noqa: E402
 import jax          # noqa: E402
 import jax.numpy as jnp                      # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P   # noqa: E402
-from jax.experimental.shard_map import shard_map    # noqa: E402
+from jax import shard_map    # noqa: E402
 
 from repro.distributed.dpc import (_make_delta, _make_delta_halo,  # noqa: E402
                                    _make_rho, _make_rho_halo)
@@ -33,8 +33,7 @@ def run(n: int, d: int, span_w: int, window_blocks: int, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     S = mesh.devices.size
     flat_mesh = Mesh(mesh.devices.reshape(-1), ("data",))
-    if hasattr(jax, "set_mesh"):   # jax >= 0.6; shard_map gets mesh= below
-        jax.set_mesh(flat_mesh)
+    jax.set_mesh(flat_mesh)
     m = n // S                       # rows per shard
     n_spans = 9                      # 3^(g-1), g=3 leading grid dims
     f32 = jnp.float32

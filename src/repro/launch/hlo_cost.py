@@ -370,17 +370,6 @@ def analyze(text: str) -> Cost:
     return comp_cost(entry)
 
 
-def xla_cost_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized to one flat dict.
-
-    Depending on the jax version this returns a dict or a one-element list
-    of dicts (one per partitioned module); normalize so callers can index
-    by property name either way.
-    """
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 def analyze_compiled(compiled) -> dict:
     c = analyze(compiled.as_text())
     return {

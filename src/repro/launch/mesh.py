@@ -13,32 +13,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models.common import MeshRules
 
 
-def activate_mesh(mesh):
-    """Make ``mesh`` ambient for PartitionSpec-based in/out shardings.
-
-    Returns a context manager that deactivates on exit on every jax
-    version: ``jax.sharding.use_mesh`` where it exists (>= 0.5), else the
-    Mesh context manager (0.4.x, this container).
-    """
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
-
-
-def specs_to_shardings(mesh, spec_tree):
-    """PartitionSpec tree -> NamedSharding tree for jit in/out_shardings.
-
-    jax 0.4.x jit accepts only Sharding objects (no ambient-mesh
-    PartitionSpecs); None leaves/subtrees stay None (= unspecified).
-    """
-    from jax.sharding import NamedSharding
-
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s) if isinstance(s, P) else s,
-        spec_tree, is_leaf=lambda x: isinstance(x, P))
-
-
 def flatten_mesh(mesh, axis_name: str = "data"):
     """Collapse every mesh axis into one ``axis_name`` axis.
 

@@ -65,8 +65,7 @@ def main(argv=None):
     model = build_model(cfg)
 
     mesh = build_mesh(args.mesh_shape, args.mesh_names)
-    if hasattr(jax, "set_mesh"):   # jax >= 0.6; shardings below are explicit
-        jax.set_mesh(mesh)
+    jax.set_mesh(mesh)
     rules = rules_for(mesh)
 
     pipeline = TokenPipeline(cfg, args.batch, args.seq, seed=args.seed)

@@ -23,6 +23,10 @@ to disable degradation entirely and surface raw compile errors.
 bf16 precision requires MXU-dense support which ``jnp`` lacks, so a
 bf16 plan never silently lands on ``jnp`` — if the chain bottoms out
 for a bf16 spec the degradation itself raises.
+
+The chain is a CPU convenience only.  On a TPU a failed probe raises with
+the compiler's message: falling back there would run the interpreter or
+the reference path on the chip and report it as the kernels.
 """
 from __future__ import annotations
 
@@ -45,6 +49,12 @@ _M_DEGRADE = obs.counter(
 # backend name -> None (probe passed) | str (failure reason)
 _PROBED: dict[str, str | None] = {}
 _WARNED: set[tuple[str, str]] = set()
+
+
+def _platform() -> str:
+    import jax
+
+    return jax.default_backend()
 
 
 def _enabled() -> bool:
@@ -87,6 +97,10 @@ def resolve_backend(requested: str | None, *, precision: str = "f32") -> str:
         reason = probe_backend(name)
         if reason is None:
             return name
+        if _platform() == "tpu":
+            raise RuntimeError(
+                f"backend {name!r} failed its compile probe on the TPU "
+                f"({reason}); no fallback is taken on a chip")
         nxt = DEGRADE_CHAIN.get(name)
         if nxt is None or (precision == "bf16" and nxt == "jnp"):
             raise RuntimeError(

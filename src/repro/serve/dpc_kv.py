@@ -33,6 +33,7 @@ from repro.engine.planner import as_plan
 from repro.engine.spec import ExecSpec, merge_legacy
 from repro.kernels.backend import get_backend
 from repro.resilience.sanitize import finite_or
+from repro.kernels.ref import sq_sum
 
 
 @dataclass(frozen=True)
@@ -103,7 +104,7 @@ def _dcut_estimate(pts, quantile: float):
     S = pts.shape[0]
     m = min(S, 256)
     sub = pts[:: max(S // m, 1)][:m]
-    d2 = jnp.sum((sub[:, None, :] - sub[None, :, :]) ** 2, -1)
+    d2 = sq_sum(sub[:, None, :] - sub[None, :, :])
     d = jnp.sqrt(jnp.maximum(d2, 0.0)).reshape(-1)
     return jnp.quantile(d, quantile) + 1e-6
 

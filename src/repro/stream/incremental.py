@@ -40,7 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.analysis.audit import audit_check_rep, audit_determinism
 from repro.core.grid import canonical_group_coords
@@ -279,7 +279,7 @@ def make_sharded_repair(mesh, axis: str, backend, d_cut: float):
     sm = shard_map(f, mesh=flat,
                    in_specs=(P(axis), P(axis), P(None), P(None), P(None)),
                    out_specs=(P(axis), P(None)),
-                   check_rep=False)   # pallas_call lacks a rep rule
+                   check_vma=False)   # pallas_call lacks a rep rule
     sm_jit = jax.jit(sm)
 
     def repair(window_dev, rho, delta_batch, signs, ins_batch, slots):

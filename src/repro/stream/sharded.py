@@ -29,7 +29,7 @@ shard_map treatment, over the same flattened data axis:
   center-continuity distance matrix, new centers sharded over the data
   axis, previous centers replicated; the greedy host matching consumes the
   gathered matrix unchanged.  No collectives and only ``P(axis)``-local
-  outputs, so this body keeps ``check_rep=True``.
+  outputs, so this body keeps ``check_vma=True``.
 
 Every stage is bit-identical to its replicated predecessor (parity-tested
 in ``tests/test_stream.py``) and traced by the R1/R2 analysis rules via
@@ -43,7 +43,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.analysis.audit import audit_check_rep
 from repro.launch.mesh import flatten_mesh
@@ -86,7 +86,7 @@ def make_sharded_nn_update(mesh, axis: str, backend, layout: str | None = None):
     sm = shard_map(f, mesh=flat,
                    in_specs=(P(axis), P(axis), P(None), P(None)),
                    out_specs=(P(None), P(None)),
-                   check_rep=False)   # pallas_call lacks a rep rule
+                   check_vma=False)   # pallas_call lacks a rep rule
     sm_jit = jax.jit(sm)
 
     def nn_update(window_dev, rho_key, q_slots):
@@ -146,7 +146,7 @@ def make_sharded_labels(mesh, axis: str, capacity: int):
         return p
 
     sm = shard_map(propagate, mesh=flat, in_specs=(P(None),),
-                   out_specs=P(None), check_rep=False)
+                   out_specs=P(None), check_vma=False)
     sm_jit = jax.jit(sm)
 
     def assign(res, rho_min: float, delta_min: float) -> Clustering:

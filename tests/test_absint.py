@@ -177,12 +177,12 @@ def _taint_hits(fn, *args, require_multi_partition=False):
 def _in_shard_map(body):
     """Wrap body in a 1-device shard_map (single-partition: only visible
     with require_multi_partition=False)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("i",))
     return shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                     check_rep=False)
+                     check_vma=False)
 
 
 class TestWalkerTaintProperties:
@@ -276,8 +276,8 @@ class TestMemoryEstimators:
         assert eqns
         est = absint.pallas_memory(eqns[0])
         assert est["vmem_bytes"] > 0
-        assert est["smem_bytes"] > 0          # worklist meta prefetch
-        assert list(est["grid"]) == [2]       # 2 row-blocks x 1 col-block
+        assert est["smem_bytes"] > 0          # the d_cut^2 scalar in SMEM
+        assert list(est["grid"]) == [2, 1]    # 2 row-blocks x 1 col-block
 
     def test_live_buffer_peak_scales_with_intermediates(self):
         small = jax.make_jaxpr(

@@ -49,7 +49,7 @@ class TestAuditRegistry:
             audit_check_rep("   ")
 
     def test_production_bodies_are_audited(self):
-        """R2 on the real tree: every check_rep=False shard_map body in
+        """R2 on the real tree: every check_vma=False shard_map body in
         src/repro resolves to a def carrying @audit_check_rep."""
         findings = r2_check_rep.CheckRepAuditRule().check_project(_REPO_ROOT)
         assert findings == [], [f.to_dict() for f in findings]
@@ -57,17 +57,17 @@ class TestAuditRegistry:
 
 # ------------------------------------------------------------------- R2
 _R2_BAD = """\
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def build(mesh, spec):
     def body(x):
         return x
     return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)
+                     check_vma=False)
 """
 
 _R2_GOOD = """\
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.analysis.audit import audit_check_rep
 
 def build(mesh, spec):
@@ -75,11 +75,11 @@ def build(mesh, spec):
     def body(x):
         return x
     return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)
+                     check_vma=False)
 """
 
 _R2_FACTORY = """\
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def _make_body(scale):
     def body(x):
@@ -89,15 +89,15 @@ def _make_body(scale):
 def build(mesh, spec):
     body = _make_body(2.0)
     return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)
+                     check_vma=False)
 """
 
 _R2_LAMBDA = """\
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 def build(mesh, spec):
     return shard_map(lambda x: x, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)
+                     check_vma=False)
 """
 
 
@@ -129,7 +129,7 @@ class TestR2CheckRepAudit:
         assert "cannot" in findings[0].message
 
     def test_default_check_rep_ignored(self, tmp_path):
-        src = _R2_BAD.replace(",\n                     check_rep=False", "")
+        src = _R2_BAD.replace(",\n                     check_vma=False", "")
         assert self._scan(tmp_path, src) == []
 
 
@@ -309,7 +309,7 @@ warnings.filterwarnings("ignore")
 os.environ["REPRO_ANALYSIS"] = "suspend"   # probe plans, not production fits
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.analysis import spmd_gather_safe, r1_spmd_gather
 from repro.analysis.rules import analyze_jaxpr
 from repro.analysis.targets import distributed_targets, stream_targets
@@ -353,7 +353,7 @@ def frozen_order_gather_walk(x_my, y):
 
 sm_old = shard_map(frozen_order_gather_walk, mesh=mesh,
                    in_specs=(P("data"), P(None)), out_specs=P("data"),
-                   check_rep=False)
+                   check_vma=False)
 safe_old = spmd_gather_safe(sm_old, pts, pts)
 closed = jax.make_jaxpr(sm_old)(pts, pts)
 r1 = [f for f in analyze_jaxpr("frozen-order-gather", closed)
@@ -364,9 +364,9 @@ r1 = [f for f in analyze_jaxpr("frozen-order-gather", closed)
 rho_fn = ddpc._make_rho_dense("data", 1.0, 256, be, layout="block-sparse")
 delta_fn = ddpc._make_delta_dense("data", 256, be, layout="block-sparse")
 sm_rho = shard_map(rho_fn, mesh=mesh, in_specs=(P("data"), P("data")),
-                   out_specs=P("data"), check_rep=False)
+                   out_specs=P("data"), check_vma=False)
 sm_delta = shard_map(delta_fn, mesh=mesh, in_specs=(P("data"),) * 4,
-                     out_specs=(P("data"),) * 3, check_rep=False)
+                     out_specs=(P("data"),) * 3, check_vma=False)
 safe_rho = spmd_gather_safe(sm_rho, pts, pts)
 safe_delta = spmd_gather_safe(sm_delta, pts, rk, pts, rk)
 pl_bs = plan(None, ExecSpec(backend="jnp", layout="block-sparse"))
@@ -435,7 +435,8 @@ class TestR6PallasRace:
     def _trace(self, spec):
         from repro.kernels import sweep as S
 
-        x = jnp.zeros((128, 2), jnp.float32)
+        # two column tiles: each row block is revisited across the grid
+        x = jnp.zeros((256, 2), jnp.float32)
         return jax.make_jaxpr(
             lambda a, b: S.tile_sweep(spec, a, b, 0.35, interpret=True))(x, x)
 
@@ -563,7 +564,7 @@ class TestR8Determinism:
         return Mesh(np.array(jax.devices()), ("i",))
 
     def _psum_trace(self, body, out_spec):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         sm = shard_map(body, mesh=self._mesh(), in_specs=(P("i"),),
@@ -701,6 +702,8 @@ class TestR9MemoryBudget:
         assert limits.limits_for_platform("tpu").smem_bytes == 17
         assert limits.limits_for_platform("tpu").vmem_bytes == \
             base.vmem_bytes
+        with pytest.raises(ValueError, match="no kernel memory limits"):
+            limits.limits_for_platform("quantum")
 
     def test_plan_telemetry_reports_memory(self):
         pl = planner.plan(None, ExecSpec(backend="pallas-interpret"))

@@ -100,6 +100,31 @@ class TestEngineParity:
                                           "parent")):
             assert _eq(a, b), f"fused {name} differs under block-sparse"
 
+    @pytest.mark.parametrize("primitive", ["count", "fused"])
+    def test_multi_chunk_worklist(self, primitive, monkeypatch):
+        """Worklists longer than one chunk run as several pallas_calls
+        threading their outputs through; each chunk holds whole row tiles,
+        so the result equals the dense sweep bit for bit."""
+        from repro.kernels import blocksparse
+
+        monkeypatch.setattr(blocksparse, "WL_CHUNK", 4)
+        pts, d_cut = _lattice(1500, 2, 0, 5)
+        be = get_backend("pallas-interpret")
+        wl = build_flat_worklist(pts, pts, d_cut, block_n=256, block_m=512,
+                                 count=True, nn="topk", k=8)
+        assert wl.meta.shape[0] > 1, "expected several chunks"
+        if primitive == "count":
+            pairs = [(be.range_count(pts, pts, d_cut),
+                      be.range_count(pts, pts, d_cut,
+                                     layout="block-sparse"))]
+        else:
+            jit_ = density_jitter(len(pts))
+            pairs = zip(be.rho_delta(pts, pts, d_cut, jitter=jit_),
+                        be.rho_delta(pts, pts, d_cut, jitter=jit_,
+                                     layout="block-sparse"))
+        for dense, bs in pairs:
+            assert _eq(dense, bs)
+
     @pytest.mark.parametrize("n,d,sexp,seed", SEED_MATRIX[:3])
     def test_rho_delta_fused_bf16(self, n, d, sexp, seed):
         """precision axis: the bf16 inner-product path prunes identically

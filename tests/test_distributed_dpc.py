@@ -24,8 +24,6 @@ for seed, d, k in ((0, 2, 6), (1, 3, 4)):
     pts, labels = gaussian_mixture(1200, k=k, d=d, overlap=0.03, seed=seed)
     pts, labels = with_noise(pts, labels, 0.05, seed=seed)
     d_cut = 3000.0
-    # jax 0.4.x has no sharding.AxisType / axis_types kwarg; the default
-    # (auto) axis behavior is what shard_map needs anyway.
     mesh = jax.make_mesh((4,), ("data",))
     res_d = distributed_dpc(pts, DistDPCConfig(d_cut=d_cut), mesh)
     res_e = run_exdpc(pts, d_cut)

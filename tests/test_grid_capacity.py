@@ -1,6 +1,10 @@
 """Grid capacity handling: build-time measurement under skew, canonical
 partitions, and the overflow -> rebuild path the streaming subsystem
 relies on."""
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -49,6 +53,25 @@ class TestBuildTimeCapacities:
             for s, e in zip(starts[i], ends[i]):
                 covered.update(range(s, e))
             assert nbrs <= covered
+
+
+def test_real_proxy_same_in_every_process():
+    """The dataset a seed names must not depend on the process: two
+    interpreters with different string-hash salts draw equal arrays."""
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    code = ("import sys, hashlib; from repro.data.points import real_proxy; "
+            "x, y = real_proxy('household', 512, seed=3); "
+            "sys.stdout.write(hashlib.sha256(x.tobytes() + y.tobytes())"
+            ".hexdigest())")
+    digests = []
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, PYTHONPATH=src,
+                   JAX_PLATFORMS="cpu")
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        digests.append(out.stdout.strip())
+    assert digests[0] == digests[1]
 
 
 class TestCanonicalPartition:

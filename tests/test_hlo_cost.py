@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.launch.hlo_cost import analyze, xla_cost_dict
+from repro.launch.hlo_cost import analyze
 
 X = jax.ShapeDtypeStruct((64, 128), jnp.float32)
 W = jax.ShapeDtypeStruct((128, 128), jnp.float32)
@@ -27,8 +27,8 @@ def test_matches_xla_on_unrolled():
         return x
     c = _compiled(f)
     r = analyze(c.as_text())
-    assert r.dot_flops == xla_cost_dict(c)["flops"] == 5 * DOT
-    assert r.bytes == xla_cost_dict(c)["bytes accessed"]
+    assert r.dot_flops == c.cost_analysis()["flops"] == 5 * DOT
+    assert r.bytes == c.cost_analysis()["bytes accessed"]
 
 
 def test_scan_multiplied_by_trip_count():

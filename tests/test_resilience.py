@@ -445,6 +445,18 @@ class TestDegrade:
             faultinject.deactivate()
             degrade.reset()
 
+    def test_failed_probe_raises_on_tpu(self, monkeypatch):
+        # on a chip the chain is never walked: the probe's error surfaces
+        monkeypatch.setattr(degrade, "_platform", lambda: "tpu")
+        faultinject.activate("degrade.probe", trigger=0)
+        degrade.reset()
+        try:
+            with pytest.raises(RuntimeError, match="on the TPU.*injected"):
+                degrade.resolve_backend("pallas")
+        finally:
+            faultinject.deactivate()
+            degrade.reset()
+
     def test_degrade_disabled_returns_request_unprobed(self, monkeypatch):
         monkeypatch.setenv("REPRO_DEGRADE", "0")
         assert degrade.resolve_backend("pallas") == "pallas"

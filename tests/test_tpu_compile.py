@@ -1,0 +1,109 @@
+"""Ahead-of-time compiles of the smoke run's kernels for a TPU v5e.
+
+Each test lowers one Pallas entry point at the widths ``chip_smoke.py``
+drives (Household: d = 4, the full n = 2,049,280 where the kernel sees the
+whole table) and compiles it for one chip of a described ``v5e:2x2``
+topology.  No chip is needed: the TPU compiler refuses here what Mosaic
+would refuse on the device (64-bit operands, block layouts it cannot tile,
+scalar-prefetch tables past SMEM, VMEM overuse).  The topology is built in
+a fixture, never at import, so every test worker collects the same tests.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.kernels.blocksparse import WL_CHUNK, FlatWorklist
+
+N_FULL = 2_049_280          # Household, paper size
+N_CHECK = 65_536            # scan check, stream window
+D = 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_compile_cache():
+    """A described chip's executables cannot be read back from the
+    persistent cache; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return compiled
+
+
+def _f32(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def test_fused_sweep_block_sparse_full_size(one_chip):
+    """The fit's kernel: count + kept-k over a chunked worklist at the
+    paper's n (every chunk reuses this one compiled kernel)."""
+    meta = jax.ShapeDtypeStruct((16, 4, WL_CHUNK), jnp.int32,
+                                sharding=one_chip)
+    lb = jax.ShapeDtypeStruct((16, WL_CHUNK), jnp.float32, sharding=one_chip)
+
+    def fit_sweep(x, m, b):
+        wl = FlatWorklist(meta=m, lb=b, n_kept=0, n_total=0)
+        return ops.fused_sweep(x, x, 1000.0, interpret=False, worklist=wl)
+
+    _compile(fit_sweep, _f32(one_chip, N_FULL, D), meta, lb)
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_fused_sweep_dense(one_chip, precision):
+    _compile(lambda x: ops.fused_sweep(x, x, 1000.0, precision=precision,
+                                       interpret=False),
+             _f32(one_chip, N_CHECK, D))
+
+
+def test_count_sweeps(one_chip):
+    """Range count and the streaming signed count (window x delta batch)."""
+    def counts(w, b, s):
+        return (ops.local_density_xy(w, w, 1000.0, interpret=False),
+                ops.local_density_delta(w, b, s, 1000.0, interpret=False))
+
+    _compile(counts, _f32(one_chip, N_CHECK, D), _f32(one_chip, 512, D),
+             _f32(one_chip, 512))
+
+
+def test_nn_sweep_full_table(one_chip):
+    """Masked NN of a query batch against the fitted table (predict, and
+    the fit's unresolved-row fallback)."""
+    _compile(lambda q, qk, t, tk: ops.dependent_masked(q, qk, t, tk,
+                                                       interpret=False),
+             _f32(one_chip, 4096, D), _f32(one_chip, 4096),
+             _f32(one_chip, N_FULL, D), _f32(one_chip, N_FULL))
+
+
+def test_gather_nn(one_chip):
+    """The stream's maxima re-query: rows gathered in-kernel from the
+    window table."""
+    slots = jax.ShapeDtypeStruct((4096,), jnp.int32, sharding=one_chip)
+    _compile(lambda t, k, s: ops.dependent_masked_gather(t, k, s,
+                                                         interpret=False),
+             _f32(one_chip, N_CHECK, D), _f32(one_chip, N_CHECK), slots)
