@@ -85,7 +85,7 @@ def check_span_tree(n: int) -> list[str]:
         failures.append("span tree: no child phase fenced device time at "
                         "level='trace'")
     child_host = sum(r["host_s"] for p, r in phases.items()
-                     if p.startswith("engine.fit/"))
+                     if p.startswith("engine.fit/") and p.count("/") == 1)
     if root["host_s"] > 0 and child_host < MIN_CHILD_COVERAGE * root["host_s"]:
         failures.append(
             f"span tree: children cover {child_host / root['host_s']:.0%} "

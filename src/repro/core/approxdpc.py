@@ -119,6 +119,10 @@ def run_approxdpc(points, d_cut: float, *, g: int | None = None,
         with obs.span("approxdpc.rules", n=n) as sp:
             is_cm = np.asarray(is_cellmax[grid.inv_order])
             cm_rows = is_cm.nonzero()[0]
+            # the mask comes down; cm_rows goes up once per use (two
+            # gathers, two scatters)
+            sp.set(d2h_bytes=int(is_cm.nbytes),
+                   h2d_bytes=4 * int(cm_rows.nbytes))
             nn_delta = nn_delta_all[cm_rows]
             nn_parent = nn_parent_all[cm_rows]
             parent1 = jnp.where(parent_s >= 0, grid.order[parent_s], -1)

@@ -26,6 +26,7 @@ worklists re-served from the plan's content-addressed cache).
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro import obs
@@ -155,11 +156,12 @@ class DPCEngine:
                     "fit: no points survived admission control "
                     f"({admitted.quarantined} quarantined)")
             points = admitted.points
-        points = jnp.asarray(points, jnp.float32)
-        self._plan = as_plan(self.exec_spec, points)
-        with obs.span("engine.fit", n=int(points.shape[0]),
-                      algorithm=self.algorithm,
-                      plan=self._plan.describe()) as sp:
+        with obs.span("engine.fit", algorithm=self.algorithm) as sp:
+            host = not isinstance(points, jax.Array)
+            points = jnp.asarray(points, jnp.float32)
+            self._plan = as_plan(self.exec_spec, points)
+            sp.set(n=int(points.shape[0]), plan=self._plan.describe(),
+                   h2d_bytes=int(points.nbytes) if host else 0)
             if self.mesh is not None:
                 if self.algorithm not in _DISTRIBUTED_OK:
                     raise ValueError(

@@ -35,6 +35,7 @@ from __future__ import annotations
 from collections import OrderedDict
 from dataclasses import dataclass
 
+from repro import obs
 from repro.kernels import blocksparse
 from repro.kernels.backend import KernelBackend, get_backend
 from repro.obs import metrics as _obsm
@@ -379,19 +380,22 @@ def plan(points_spec: PointsSpec | tuple | None,
         points_spec = PointsSpec(*points_spec)
     spec = exec_spec if exec_spec is not None else ExecSpec()
     key = (points_spec, spec)
-    hit = _PLANS.get(key)
-    if hit is not None:
-        _M_HITS.inc()
-        _PLANS.move_to_end(key)
-        return hit
-    _M_MISSES.inc()
-    pl = DPCPlan(points_spec, spec)
-    _plan_check(pl)
-    _PLANS[key] = pl
-    while len(_PLANS) > _PLAN_CACHE_MAX:
-        _PLANS.popitem(last=False)
-        _M_EVICTIONS.inc()
-    return pl
+    with obs.span("planner.plan") as sp:
+        hit = _PLANS.get(key)
+        sp.set(hit=hit is not None)
+        if hit is not None:
+            _M_HITS.inc()
+            _PLANS.move_to_end(key)
+            return hit
+        _M_MISSES.inc()
+        pl = DPCPlan(points_spec, spec)
+        with obs.span("planner.check"):
+            _plan_check(pl)
+        _PLANS[key] = pl
+        while len(_PLANS) > _PLAN_CACHE_MAX:
+            _PLANS.popitem(last=False)
+            _M_EVICTIONS.inc()
+        return pl
 
 
 def as_plan(exec_spec, points=None) -> DPCPlan:

@@ -79,6 +79,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import blocksparse, ops
 from .blocksparse import vary_like
 from .ref import sq_sum
@@ -719,20 +721,30 @@ class PallasBackend(KernelBackend):
         delta, parent, resolved = _fused_resolve(
             jnp.asarray(x, jnp.float32), jnp.asarray(y, jnp.float32),
             rho_key, col_key, topv, topi)
-        unres_mask = ~np.asarray(resolved)
-        if fallback_interest is not None:
-            unres_mask &= np.asarray(fallback_interest(rho_key), bool)
-        unresolved = np.nonzero(unres_mask)[0]
-        if unresolved.size:
-            cap = _pow2_pad(unresolved.size)
-            rows = np.pad(unresolved, (0, cap - unresolved.size))
-            fd, fp = self.denser_nn(jnp.asarray(x)[rows], rho_key[rows],
-                                    y, col_key, block=block)
-            dd = np.asarray(delta).copy()
-            pp = np.asarray(parent).copy()
-            dd[unresolved] = np.asarray(fd)[: unresolved.size]
-            pp[unresolved] = np.asarray(fp)[: unresolved.size]
-            delta, parent = jnp.asarray(dd), jnp.asarray(pp)
+        with obs.span("rho_delta.fallback") as sp:
+            d2h = blocksparse.d2h_nbytes(resolved)
+            unres_mask = ~np.asarray(resolved)
+            if fallback_interest is not None:
+                interest = fallback_interest(rho_key)
+                d2h += blocksparse.d2h_nbytes(interest)
+                unres_mask &= np.asarray(interest, bool)
+            unresolved = np.nonzero(unres_mask)[0]
+            cap = h2d = 0
+            if unresolved.size:
+                cap = _pow2_pad(unresolved.size)
+                rows = np.pad(unresolved, (0, cap - unresolved.size))
+                fd, fp = self.denser_nn(jnp.asarray(x)[rows], rho_key[rows],
+                                        y, col_key, block=block)
+                d2h += blocksparse.d2h_nbytes(delta, parent, fd, fp)
+                dd = np.asarray(delta).copy()
+                pp = np.asarray(parent).copy()
+                dd[unresolved] = np.asarray(fd)[: unresolved.size]
+                pp[unresolved] = np.asarray(fp)[: unresolved.size]
+                delta, parent = jnp.asarray(dd), jnp.asarray(pp)
+                # rows goes up once per gather (x and rho_key)
+                h2d = 2 * rows.nbytes + dd.nbytes + pp.nbytes
+            sp.set(rows=int(unresolved.size), padded=int(cap),
+                   d2h_bytes=int(d2h), h2d_bytes=int(h2d))
         return rho, rho_key, delta, parent
 
     def range_count_halo(self, x, window, starts, ends, d_cut, *,

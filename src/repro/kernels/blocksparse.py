@@ -57,6 +57,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.obs import metrics as _obsm
 from .ref import sq_sum
 
@@ -494,8 +495,6 @@ _M_FP_MISSES = _obsm.counter(
     "cache was active but the content fingerprint was absent (true rebuild)")
 _G_WL_LEN = _obsm.gauge(
     "worklist_len", "kept tile-pair count of the most recent build")
-_G_WL_PRUNED = _obsm.gauge(
-    "worklist_pruned_frac", "pruned tile fraction of the most recent build")
 
 
 @contextmanager
@@ -522,8 +521,7 @@ def suspend_counters():
     restored to its value at entry, atomically per family.
     """
     saved = [(m, m._state()) for m in
-             (_M_BUILDS, _M_CACHE_HITS, _M_FP_MISSES, _G_WL_LEN,
-              _G_WL_PRUNED)]
+             (_M_BUILDS, _M_CACHE_HITS, _M_FP_MISSES, _G_WL_LEN)]
     try:
         yield
     finally:
@@ -545,6 +543,15 @@ def worklist_cache_hits() -> int:
 
 def worklist_fingerprint_misses() -> int:
     return int(_M_FP_MISSES.value())
+
+
+def d2h_nbytes(*arrays) -> int:
+    """Bytes that host copies of ``arrays`` fetch from the device: each
+    distinct ``jax.Array`` once (jax keeps the host copy of an array it
+    has fetched, so a second ``np.asarray`` of it moves nothing); host
+    arrays and ``None`` nothing.  Shapes only: no device sync."""
+    dev = {id(a): a for a in arrays if isinstance(a, jax.Array)}
+    return sum(int(a.nbytes) for a in dev.values())
 
 
 def _src_dtype_tag(arr) -> str:
@@ -604,23 +611,52 @@ def build_flat_worklist(x, y, d_cut=None, *, block_n: int, block_m: int,
     results are memoized by content fingerprint — same data, same knobs,
     no rebuild.
     """
-    src_dtypes = (_src_dtype_tag(x), _src_dtype_tag(y))
-    x = np.asarray(x, np.float32)
-    y = np.asarray(y, np.float32)
-    key = None
-    if _WL_CACHE_STACK:
-        cache, max_entries, max_bytes = _WL_CACHE_STACK[-1]
-        key = _wl_fingerprint(x, y, d_cut, block_n, block_m, count, nn, k,
-                              nn_dcut, nn_col_counts, starts, ends,
-                              src_dtypes)
-        hit = cache.get(key)
-        if hit is not None:
-            _M_CACHE_HITS.inc()
-            if hasattr(cache, "move_to_end"):
-                cache.move_to_end(key)
-            return hit
-        _M_FP_MISSES.inc()
-    _M_BUILDS.inc()
+    with obs.span("worklist.build") as sp:
+        src_dtypes = (_src_dtype_tag(x), _src_dtype_tag(y))
+        with obs.span("worklist.fetch", d2h_bytes=d2h_nbytes(x, y)):
+            x = np.asarray(x, np.float32)
+            y = np.asarray(y, np.float32)
+        key = None
+        if _WL_CACHE_STACK:
+            cache, max_entries, max_bytes = _WL_CACHE_STACK[-1]
+            with obs.span("worklist.fingerprint"):
+                key = _wl_fingerprint(x, y, d_cut, block_n, block_m, count,
+                                      nn, k, nn_dcut, nn_col_counts, starts,
+                                      ends, src_dtypes)
+            hit = cache.get(key)
+            if hit is not None:
+                _M_CACHE_HITS.inc()
+                if hasattr(cache, "move_to_end"):
+                    cache.move_to_end(key)
+                sp.set(kept=hit.n_kept, total=hit.n_total, hit=True,
+                       chunks=int(hit.meta.shape[0]))
+                return hit
+            _M_FP_MISSES.inc()
+        _M_BUILDS.inc()
+        with obs.span("worklist.bounds"):
+            meta_c, lb_c, n_kept, n_total = _kept_pairs(
+                x, y, d_cut, block_n, block_m, count, nn, k, nn_dcut,
+                nn_col_counts, starts, ends)
+        with obs.span("worklist.upload",
+                      h2d_bytes=int(meta_c.nbytes + lb_c.nbytes)):
+            out = FlatWorklist(meta=jnp.asarray(meta_c), lb=jnp.asarray(lb_c),
+                               n_kept=n_kept, n_total=n_total)
+        _G_WL_LEN.set(out.n_kept)
+        sp.set(kept=out.n_kept, total=out.n_total, hit=False,
+               chunks=int(meta_c.shape[0]))
+        if key is not None:
+            cache[key] = out
+            while len(cache) > 1 and (
+                    len(cache) > max_entries
+                    or sum(map(_wl_nbytes, cache.values())) > max_bytes):
+                cache.pop(next(iter(cache)))    # oldest entry (insertion order)
+        return out
+
+
+def _kept_pairs(x, y, d_cut, block_n, block_m, count, nn, k, nn_dcut,
+                nn_col_counts, starts, ends):
+    """The kept tile pairs of :func:`build_flat_worklist`, row-sorted and
+    chunked on the host: (meta chunks, lb chunks, kept, dense total)."""
     n, _ = x.shape
     m = y.shape[0]
     nbr, nbc = -(-n // block_n), -(-m // block_m)
@@ -679,17 +715,7 @@ def build_flat_worklist(x, y, d_cut=None, *, block_n: int, block_m: int,
     meta = np.stack([wi, wj, first,
                      in_cut[wi, wj].astype(np.int64)]).astype(np.int32)
     meta_c, lb_c = chunk_worklist(meta, wl.astype(np.float32), WL_CHUNK)
-    out = FlatWorklist(meta=jnp.asarray(meta_c), lb=jnp.asarray(lb_c),
-                       n_kept=len(wi), n_total=nbr * nbc)
-    _G_WL_LEN.set(out.n_kept)
-    _G_WL_PRUNED.set(round(out.pruned_frac, 6))
-    if key is not None:
-        cache[key] = out
-        while len(cache) > 1 and (
-                len(cache) > max_entries
-                or sum(map(_wl_nbytes, cache.values())) > max_bytes):
-            cache.pop(next(iter(cache)))    # oldest entry (insertion order)
-    return out
+    return meta_c, lb_c, len(wi), nbr * nbc
 
 
 def worklist_stats(x, y, d_cut, *, block_n: int = BS_BLOCK_N,

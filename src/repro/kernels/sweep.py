@@ -491,6 +491,7 @@ def _tile_sweep_jit(spec: SweepSpec, x, y, d_cut=None, x_key=None,
                 out_specs=[_block_spec(k, b, False) for k, b in out_kinds],
                 out_shape=out_shape,
                 interpret=interpret,
+                name="tile_sweep_dense",
             )(*args)
     # per-row vector outputs go back to (n,); kept-k lists stay (n, k)
     n_vec = len(outs) - 2 if spec.nn == "topk" else len(outs)
@@ -539,6 +540,7 @@ def _sweep_worklist(spec, args, kinds, out_shape, out_kinds, wl_meta, wl_lb,
         out_shape=out_shape,
         input_output_aliases={2 + len(args) + o: o for o in range(n_out)},
         interpret=interpret,
+        name="tile_sweep_worklist",
     )
     outs = tuple(jnp.zeros(s.shape, s.dtype) for s in out_shape)
     if n_chunks == 1:
@@ -636,5 +638,6 @@ def gather_nn(table, keys, q_slots, *, m_valid: int,
             scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32),
                             pltpu.VMEM((block_n, 1), jnp.float32)],
             interpret=interpret,
+            name="gather_nn",
         )(slots, table, keys)
     return best.reshape(q), arg.reshape(q)

@@ -19,8 +19,11 @@ Levels (``configure(level=...)``):
 * ``"trace"``   — spans record host + fenced device time, and are
   optionally appended to a JSON-lines trace file as they close.
 
-Optionally a ``jax.profiler`` trace can be captured alongside
-(``configure(profile_dir=...)``) for TensorBoard-level detail.
+At either instrumented level every span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a profiler capture
+started by the caller (``jax.profiler.trace(dir)``) shows the program's
+phases on the device timeline's clock.  Each record carries ``root``, the
+id of its outermost span: every span of one ``engine.fit`` shares it.
 
 This module must stay a leaf: it may import jax/numpy/stdlib only, never
 ``repro.engine``/``repro.kernels`` — those import *us*.
@@ -56,8 +59,6 @@ class _State:
     level: str = "off"
     trace_path: str | None = None
     file: Any = None  # lazily-opened JSONL handle
-    profile_dir: str | None = None
-    profiling: bool = False
 
 
 _STATE = _State()
@@ -66,16 +67,13 @@ _DONE: list[dict] = []
 _KEEP = object()  # configure() sentinel: leave this setting unchanged
 
 
-def configure(level: Any = _KEEP, trace_path: Any = _KEEP,
-              profile_dir: Any = _KEEP) -> None:
-    """Set the global observability level and trace sinks.
+def configure(level: Any = _KEEP, trace_path: Any = _KEEP) -> None:
+    """Set the global observability level and trace sink.
 
     ``level`` is one of ``LEVELS``.  ``trace_path`` names a JSON-lines file
     that closed spans are appended to (``None`` disables file emission;
-    spans stay available in memory via :func:`spans`).  ``profile_dir``
-    starts a ``jax.profiler`` trace into that directory; it is stopped when
-    the level returns to ``"off"`` or ``profile_dir=None`` is passed.
-    Arguments left unspecified keep their current value.
+    spans stay available in memory via :func:`spans`).  Arguments left
+    unspecified keep their current value.
     """
     with _LOCK:
         if level is not _KEEP:
@@ -90,27 +88,6 @@ def configure(level: Any = _KEEP, trace_path: Any = _KEEP,
                     pass
                 _STATE.file = None
             _STATE.trace_path = trace_path
-        if profile_dir is not _KEEP and profile_dir != _STATE.profile_dir:
-            _stop_profile()
-            _STATE.profile_dir = profile_dir
-            if profile_dir is not None:
-                try:
-                    jax.profiler.start_trace(profile_dir)
-                    _STATE.profiling = True
-                except Exception as e:  # pragma: no cover - env dependent
-                    warnings.warn(f"obs: jax.profiler capture unavailable: {e}",
-                                  stacklevel=2)
-        if _STATE.level == "off":
-            _stop_profile()
-
-
-def _stop_profile() -> None:
-    if _STATE.profiling:
-        try:
-            jax.profiler.stop_trace()
-        except Exception:  # pragma: no cover - env dependent
-            pass
-        _STATE.profiling = False
 
 
 def level() -> str:
@@ -151,19 +128,21 @@ NULL_SPAN = _NullSpan()
 
 
 class Span:
-    __slots__ = ("name", "attrs", "id", "parent", "depth", "path",
-                 "_t0", "_mark", "_fence_s")
+    __slots__ = ("name", "attrs", "id", "parent", "root", "depth", "path",
+                 "_t0", "_mark", "_fence_s", "_ann")
 
     def __init__(self, name: str, attrs: dict) -> None:
         self.name = name
         self.attrs = attrs
         self.id = next(_IDS)
         self.parent = None
+        self.root = self.id
         self.depth = 0
         self.path = name
         self._t0 = 0.0
         self._mark = 0.0
         self._fence_s = 0.0
+        self._ann = None
 
     def set(self, **attrs: Any) -> None:
         """Attach attributes to an open span (e.g. sizes known mid-phase)."""
@@ -202,14 +181,20 @@ class Span:
         if stack:
             top = stack[-1]
             self.parent = top.id
+            self.root = top.root
             self.depth = top.depth + 1
             self.path = f"{top.path}/{self.name}"
         stack.append(self)
+        # the profiler's host event of this span (a no-op unless a capture
+        # is running); its name stays plain so trace readers match it exactly
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = self._mark = time.perf_counter()
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
         t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
         stack = getattr(_TLS, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
@@ -219,6 +204,7 @@ class Span:
             "path": self.path,
             "id": self.id,
             "parent": self.parent,
+            "root": self.root,
             "depth": self.depth,
             "t0": self._t0 - _ORIGIN,
             "host_s": host_s,

@@ -120,6 +120,41 @@ class TestTracer:
         with pytest.raises(ValueError, match="level"):
             obs.configure(level="verbose")
 
+    def test_span_opens_a_profiler_annotation(self, monkeypatch):
+        events = _annotations(monkeypatch)
+        obs.configure(level="metrics")
+        with obs.span("outer", n=1):
+            with obs.span("inner"):
+                pass
+        assert events == [("enter", "outer"), ("enter", "inner"),
+                          ("exit", "inner"), ("exit", "outer")]
+
+    def test_off_opens_no_annotation(self, monkeypatch):
+        events = _annotations(monkeypatch)
+        with obs.span("a", n=2) as sp:
+            assert sp is obs.NULL_SPAN
+        assert events == [] and obs.spans() == []
+
+
+def _annotations(monkeypatch) -> list:
+    """Replace the profiler's annotation with one that logs its use."""
+    import jax
+
+    events = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    return events
+
 
 # -------------------------------------------------------------- metrics
 class TestMetrics:
@@ -191,9 +226,85 @@ class TestEngineTracing:
         phases = obs_report.aggregate(obs.spans())
         assert phases["engine.fit/approxdpc.rho_delta"]["device_s"] is not None
         root = phases["engine.fit"]
+        # direct children only: spans nest further below them
         child = sum(r["host_s"] for p, r in phases.items()
-                    if p.startswith("engine.fit/"))
+                    if p.startswith("engine.fit/") and p.count("/") == 1)
         assert child <= root["host_s"] + 1e-6
+
+    def test_spans_of_one_fit_share_its_root(self):
+        pts = _blobs(256)
+        eng = DPCEngine(d_cut=300.0, algorithm="approxdpc",
+                        exec_spec=ExecSpec(backend="jnp",
+                                           layout="block-sparse"))
+        obs.configure(level="metrics")
+        eng.fit(pts)
+        first = obs.spans()
+        obs.reset_spans()
+        eng.fit(pts[::-1].copy())
+        second = obs.spans()
+        for recs in (first, second):
+            (fit,) = [r for r in recs if r["name"] == "engine.fit"]
+            assert len(recs) > 4
+            assert {r["root"] for r in recs} == {fit["id"]}
+        assert first[0]["root"] != second[0]["root"]
+
+    def test_block_sparse_fit_traces_worklist_and_fallback(self):
+        pts = _blobs(1024)
+        eng = DPCEngine(d_cut=300.0, algorithm="approxdpc",
+                        exec_spec=ExecSpec(backend="pallas-interpret",
+                                           layout="block-sparse"))
+        obs.configure(level="metrics")
+        eng.fit(pts)
+        recs = obs.spans()
+        by = {r["name"]: r for r in recs}
+        build = by["worklist.build"]
+        children = {r["name"]: r for r in recs
+                    if r["parent"] == build["id"]}
+        assert set(children) == {"worklist.fetch", "worklist.fingerprint",
+                                 "worklist.bounds", "worklist.upload"}
+        assert build["path"] == ("engine.fit/approxdpc.rho_delta/"
+                                 "worklist.build")
+        a = build["attrs"]
+        assert a["hit"] is False and a["chunks"] >= 1
+        assert 0 < a["kept"] <= a["total"]
+        # x and y are one device array: fetched once
+        assert children["worklist.fetch"]["attrs"] == {
+            "d2h_bytes": pts.nbytes}
+        # (chunks, 4, C) int32 pairs and (chunks, C) f32 bounds
+        up = children["worklist.upload"]["attrs"]["h2d_bytes"]
+        assert up % (20 * a["chunks"]) == 0 and up >= 20 * a["kept"]
+        fb = by["rho_delta.fallback"]
+        assert fb["path"] == "engine.fit/approxdpc.rho_delta/" \
+                             "rho_delta.fallback"
+        f = fb["attrs"]
+        n = len(pts)
+        assert 0 < f["rows"] <= f["padded"] < 2 * f["rows"]
+        # resolved and the interest mask (bool), delta and parent, the
+        # fallback's answers; rows (int64) up twice, delta and parent up
+        assert f["d2h_bytes"] == 2 * n + 8 * n + 8 * f["padded"]
+        assert f["h2d_bytes"] == 2 * 8 * f["padded"] + 8 * n
+        assert by["engine.fit"]["attrs"]["h2d_bytes"] == pts.nbytes
+        assert by["approxdpc.rules"]["attrs"]["d2h_bytes"] == n
+        assert by["approxdpc.rules"]["attrs"]["h2d_bytes"] > 0
+
+        # the same points again: the worklist cache answers
+        obs.reset_spans()
+        eng.fit(pts)
+        recs = obs.spans()
+        (build,) = [r for r in recs if r["name"] == "worklist.build"]
+        assert build["attrs"]["hit"] is True
+        assert {r["name"] for r in recs if r["parent"] == build["id"]} \
+            == {"worklist.fetch", "worklist.fingerprint"}
+
+    def test_kept_pairs_equal_the_worklist_len_gauge(self):
+        obs.configure(level="metrics")
+        pts = _blobs(1024, seed=3)
+        DPCEngine(d_cut=300.0, algorithm="approxdpc",
+                  exec_spec=ExecSpec(backend="pallas-interpret",
+                                     layout="block-sparse")).fit(pts)
+        (build,) = [r for r in obs.spans() if r["name"] == "worklist.build"]
+        assert build["attrs"]["kept"] == \
+            obs.get_metric("worklist_len").value()
 
     def test_fit_off_emits_nothing(self):
         pts = _blobs(128)
