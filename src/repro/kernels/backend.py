@@ -615,7 +615,10 @@ class PallasBackend(KernelBackend):
     list whose pairs the kernel skips against its live prune radius, and
     the fused ``rho_delta`` the union of the d_cut prefix and the static
     k-NN ring.  Host-built means not jit-callable (``worklist_traceable``
-    stays False) — the same contract as the grid build itself.
+    stays False) — the same contract as the grid build itself.  The one
+    exception is the fused ``rho_delta``'s unresolved-row fallback, whose
+    keep-every-pair ring is built on the device
+    (:func:`blocksparse.ring_worklist`).
     """
 
     mxu_dense = True
@@ -673,7 +676,11 @@ class PallasBackend(KernelBackend):
         whose k nearest neighbors are all less dense (the local-maxima /
         jitter-tail fraction) fall through to the fallback —
         ``fallback_interest`` restricts that pass to the rows the caller
-        will read (Approx-DPC: the |G| << n cell maxima).  The fallback is
+        will read (Approx-DPC: the |G| << n cell maxima).  Under the
+        block-sparse layout it walks a device-built ring worklist, so it
+        computes only the tile pairs within each row tile's final radius
+        (span ``rho_delta.fallback``: ``pairs`` listed, ``live`` computed);
+        under the dense layout it is the dense sweep.  The fallback is
         host-orchestrated, so this path is not jit-safe (fused_traceable is
         False); jitted consumers use the two-pass formulation instead.
         """
@@ -732,9 +739,26 @@ class PallasBackend(KernelBackend):
             cap = h2d = 0
             if unresolved.size:
                 cap = _pow2_pad(unresolved.size)
+                # pad rows repeat row 0, real key included: a pad row keyed
+                # +inf would hold its row tile's ring radius at +inf
                 rows = np.pad(unresolved, (0, cap - unresolved.size))
-                fd, fp = self.denser_nn(jnp.asarray(x)[rows], rho_key[rows],
-                                        y, col_key, block=block)
+                xr, kr = jnp.asarray(x)[rows], rho_key[rows]
+                if _sparse(layout):
+                    # a ring-ordered walk over every column tile, built on
+                    # the device: the kernel skips each pair whose lower
+                    # bound clears its row tile's live radius
+                    bn, bm = min(block or 128, 1024), blocksparse.BS_BLOCK_M
+                    wl = blocksparse.ring_worklist(xr, y, block_n=bn,
+                                                   block_m=bm)
+                    fd, fp = ops.dependent_masked(
+                        xr, kr, y, col_key, block_n=bn, block_m=bm,
+                        interpret=self.interpret, worklist=wl)
+                    live = blocksparse.ring_live_pairs(wl.meta, wl.lb, fd,
+                                                       bn=bn)
+                    d2h += blocksparse.d2h_nbytes(live)
+                    sp.set(pairs=wl.n_kept, live=int(live))
+                else:
+                    fd, fp = self.denser_nn(xr, kr, y, col_key, block=block)
                 d2h += blocksparse.d2h_nbytes(delta, parent, fd, fp)
                 dd = np.asarray(delta).copy()
                 pp = np.asarray(parent).copy()

@@ -41,7 +41,10 @@ owns that pruning logic once, in three forms:
   (``best1``: the current best; ``topk``: the worst kept kth — statically
   pre-pruned by the k-nearest upper-bound radius, which is exact because a
   tile whose lower bound clears k strictly-closer candidates can never
-  contribute a kept entry).
+  contribute a kept entry).  A best-1 list that keeps every pair is a pure
+  function of the tile bounds, so the fused pallas ``rho_delta``'s
+  unresolved-row fallback builds it on the device (:func:`ring_worklist`)
+  in the same chunked form.
 
 Every builder force-keeps at least one pair per row tile so output blocks
 are always initialized, and all-in-one-cell data degenerates to the dense
@@ -145,11 +148,16 @@ def _ring(x_pad, nx, y_pad, ny, bn: int, bm: int):
     shard_map bodies, where the pinned XLA CPU SPMD pipeline miscompiles
     sort-derived gather indices.
     """
-    rlo, rhi = tile_bounds(x_pad, nx, bn)
-    clo, chi = tile_bounds(y_pad, ny, bm)
-    lb = pair_lower_bounds(rlo, rhi, clo, chi)
+    lb = _tile_pair_lb(x_pad, nx, y_pad, ny, bn, bm)
     rank = jnp.argsort(jnp.argsort(lb, axis=1), axis=1).astype(jnp.int32)
     return rank, lb
+
+
+def _tile_pair_lb(x_pad, nx, y_pad, ny, bn: int, bm: int) -> jnp.ndarray:
+    """(nbr, nbc) pair lower bounds of two padded tables (pad rows masked)."""
+    rlo, rhi = tile_bounds(x_pad, nx, bn)
+    clo, chi = tile_bounds(y_pad, ny, bm)
+    return pair_lower_bounds(rlo, rhi, clo, chi)
 
 
 # One-hot contractions never see ±inf pad values: 0 * inf = NaN would leak
@@ -427,6 +435,72 @@ def chunk_worklist(meta: np.ndarray, lb: np.ndarray,
         lbs.append(np.concatenate([lb[a:b], np.full(pad, np.inf,
                                                     np.float32)]))
     return np.stack(metas), np.stack(lbs)
+
+
+def ring_worklist(x, y, *, block_n: int, block_m: int) -> FlatWorklist:
+    """Device-built best-1 ring worklist: every tile pair of ``x`` rows
+    against ``y`` columns, each row tile's column tiles in ascending lower
+    bound (stable: ties by tile index), chunked as :func:`chunk_worklist`
+    chunks it.
+
+    The list ``build_flat_worklist(nn="best1")`` builds on the host (its
+    bounds equal up to f32 summation order), without the round trip: a
+    best-1 list keeps every pair, so it is a pure function of the tile
+    bounds, and its shapes depend only on the table shapes.  The kernel's ``lb <= max(best)`` test then skips every pair
+    past the row tile's final radius.  Sets no worklist counter.
+    """
+    nbr, nbc = -(-x.shape[0] // block_n), -(-y.shape[0] // block_m)
+    meta, lb = _ring_chunks(x, y, bn=block_n, bm=block_m, chunk=WL_CHUNK)
+    return FlatWorklist(meta=meta, lb=lb, n_kept=nbr * nbc,
+                        n_total=nbr * nbc)
+
+
+@partial(jax.jit, static_argnames=("bn", "bm", "chunk"))
+def _ring_chunks(x, y, *, bn: int, bm: int, chunk: int):
+    """(chunks, 4, C) meta and (chunks, C) bounds of :func:`ring_worklist`.
+
+    Every row tile holds nbc entries, so a chunk holds C // nbc whole row
+    tiles; the entries past a chunk's last real one repeat it with flag -1
+    and an infinite bound, as the host chunker pads.
+    """
+    n, m = x.shape[0], y.shape[0]
+    xp, yp = _pad_inf(x.astype(jnp.float32), bn), \
+        _pad_inf(y.astype(jnp.float32), bm)
+    nbr, nbc = xp.shape[0] // bn, yp.shape[0] // bm
+    lb = _tile_pair_lb(xp, n, yp, m, bn, bm)
+    # a stable int32 key-value sort: jnp.argsort's 64-bit iota (the package
+    # enables x64) makes the TPU compile of a stable sort ~5x slower
+    lb_sorted, order = jax.lax.sort(
+        (lb, jax.lax.broadcasted_iota(jnp.int32, lb.shape, 1)),
+        dimension=1, num_keys=1, is_stable=True)
+    C = _pow2(max(nbc, min(nbr * nbc, chunk), 8))
+    per = C // nbc                                  # row tiles per chunk
+    n_chunks = -(-nbr // per)
+    c = jnp.arange(n_chunks)[:, None]
+    q = jnp.arange(C)[None, :]
+    real = jnp.minimum(nbr - c * per, per) * nbc    # entries before padding
+    pad = q >= real
+    qe = jnp.minimum(q, real - 1)
+    row = c * per + qe // nbc
+    r = qe % nbc
+    meta = jnp.stack([row, order[row, r], (r == 0) & ~pad,
+                      jnp.where(pad, -1, 0)], axis=1).astype(jnp.int32)
+    return meta, jnp.where(pad, jnp.inf, lb_sorted[row, r])
+
+
+@partial(jax.jit, static_argnames=("bn",))
+def ring_live_pairs(meta, lb, delta, *, bn: int):
+    """Entries of a best-1 ring worklist whose lower bound is within their
+    row tile's final radius: the max over the tile's rows of ``delta``
+    squared, where rows past ``delta`` (the kernel's pad rows, keyed +inf)
+    hold it at +inf.  Visited in ascending bound, these are the pairs the
+    kernel computes; it skips the rest.  Chunk padding is not counted."""
+    nbr = -(-delta.shape[0] // bn)
+    d2 = jnp.pad(jnp.square(delta.astype(jnp.float32)),
+                 (0, nbr * bn - delta.shape[0]), constant_values=jnp.inf)
+    radius = jnp.max(d2.reshape(nbr, bn), axis=1)
+    live = (meta[:, 3] >= 0) & (lb <= radius[meta[:, 0]])
+    return jnp.sum(live, dtype=jnp.int32)
 
 
 def _host_bounds(arr: np.ndarray, block: int):

@@ -200,6 +200,166 @@ class TestHaloParity:
             assert _eq(a, b)
 
 
+def _clusters(n, d, seed, k=12, spread=20.0):
+    """Gaussian blobs, grid-sorted: many cell maxima, so the fused sweep
+    leaves a real unresolved tail for its fallback."""
+    rng = np.random.default_rng(seed)
+    c = rng.uniform(0, 1000, (k, d))
+    pts = c[rng.integers(0, k, n)] + rng.normal(0, spread, (n, d))
+    d_cut = 8.0
+    return build_grid(jnp.asarray(pts.astype(np.float32)), d_cut).points, \
+        d_cut
+
+
+class TestFallbackRing:
+    """The fused pallas ``rho_delta``'s unresolved-row fallback: under the
+    block-sparse layout a device-built ring worklist, under the dense
+    layout the dense sweep, with the same answers bit for bit."""
+
+    @pytest.mark.parametrize("n_rows, m, bn, bm, chunk", [
+        (700, 3000, 128, 256, 8192),     # one chunk
+        (700, 3000, 8, 16, 8192),        # several chunks, C = 8192
+        (300, 1000, 16, 64, 4),          # C = the column-tile count
+    ])
+    def test_device_ring_equals_host_best1(self, n_rows, m, bn, bm, chunk,
+                                           monkeypatch):
+        from repro.kernels import blocksparse
+
+        monkeypatch.setattr(blocksparse, "WL_CHUNK", chunk)
+        rng = np.random.default_rng(n_rows + m)
+        y = np.asarray(_clusters(m, 3, 0)[0])
+        x = y[np.sort(rng.choice(m, n_rows, replace=False))]
+        dev = blocksparse.ring_worklist(jnp.asarray(x), jnp.asarray(y),
+                                        block_n=bn, block_m=bm)
+        host = build_flat_worklist(x, y, None, block_n=bn, block_m=bm,
+                                   count=False, nn="best1")
+        assert dev.meta.shape == host.meta.shape
+        assert (dev.n_kept, dev.n_total) == (host.n_kept, host.n_total)
+        dm, hm = np.asarray(dev.meta), np.asarray(host.meta)
+        dl, hl = np.asarray(dev.lb), np.asarray(host.lb)
+        # chunk padding in the same places, flagged -1 with infinite bounds
+        assert np.array_equal(dm[:, 3], hm[:, 3])
+        real = dm[:, 3] >= 0
+        assert np.all(np.isinf(dl[~real])) and np.all(dm[:, 2][~real] == 0)
+        # padding repeats its chunk's last real pair
+        for c in range(dm.shape[0]):
+            last = np.flatnonzero(real[c])[-1]
+            assert np.all(dm[c, :2, last + 1:] == dm[c, :2, last:last + 1])
+        rows, cols, lbs = dm[:, 0][real], dm[:, 1][real], dl[real]
+        hrows, hcols = hm[:, 0][real], hm[:, 1][real]
+        assert np.array_equal(rows, hrows)
+        for i in np.unique(rows):
+            mine = rows == i
+            assert sorted(cols[mine]) == sorted(hcols[hrows == i])
+            assert np.all(np.diff(lbs[mine]) >= 0), "not in ring order"
+            assert np.array_equal(np.flatnonzero(dm[:, 2][real] & mine),
+                                  np.flatnonzero(mine)[:1])
+        np.testing.assert_allclose(lbs, hl[real], rtol=1e-6)
+
+    @staticmethod
+    def _record_fallback(monkeypatch):
+        """Wrap ``ops.dependent_masked``: each call's worklist and answers."""
+        from repro.kernels import ops
+
+        calls = []
+        inner = ops.dependent_masked
+
+        def spy(*a, **kw):
+            out = inner(*a, **kw)
+            calls.append((kw.get("worklist"), out))
+            return out
+        monkeypatch.setattr(ops, "dependent_masked", spy)
+        return calls
+
+    @staticmethod
+    def _fallback_span():
+        from repro import obs
+
+        (fb,) = [s for s in obs.spans() if s["name"] == "rho_delta.fallback"]
+        return fb["attrs"]
+
+    @pytest.mark.parametrize("case", ["interest", "rep_subset", "lattice"])
+    def test_ring_fallback_parity_and_live_count(self, case, monkeypatch):
+        from repro import obs
+
+        rng = np.random.default_rng(11)
+        kw = {}
+        if case == "lattice":
+            x, d_cut = _lattice(600, 3, 1, 21)
+            y = x
+        else:
+            y, d_cut = _clusters(1500, 3, 4)
+            x = y
+        if case == "interest":
+            keep = jnp.asarray(rng.random(len(x)) < 0.6)
+            kw["fallback_interest"] = lambda rk: keep
+        elif case == "rep_subset":
+            slots = jnp.asarray(np.sort(rng.choice(len(y), 800,
+                                                   replace=False)))
+            x, kw["y_sel_slots"] = y[slots], slots
+        be = get_backend("pallas-interpret")
+        jit_ = density_jitter(len(x))
+        calls = self._record_fallback(monkeypatch)
+        obs.configure(level="metrics")
+        try:
+            dense = be.rho_delta(x, y, d_cut, jitter=jit_, block=8, **kw)
+            obs.reset_spans()
+            bs = be.rho_delta(x, y, d_cut, jitter=jit_, block=8,
+                              layout="block-sparse", **kw)
+            f = self._fallback_span()
+        finally:
+            obs.configure(level="off")
+            obs.reset_spans()
+        for a, b, name in zip(dense[2:], bs[2:], ("delta", "parent")):
+            assert _eq(a, b), f"fallback {name} differs under block-sparse"
+        rows = f["rows"]
+        assert rows & (rows - 1), f"{rows} unresolved rows: a power of two"
+        # the dense call took no worklist; the block-sparse one the ring
+        (wl_dense, _), (wl, (fd, _)) = calls
+        assert wl_dense is None
+        nbr, nbc = f["padded"] // 8, -(-len(y) // 256)
+        assert f["pairs"] == wl.n_kept == nbr * nbc
+        assert 0 < f["live"] <= f["pairs"]
+        if case != "lattice":           # compact tiles: the ring prunes
+            assert f["live"] < f["pairs"]
+        # recount on the host from the answers: pairs within their row
+        # tile's final radius (max over its rows of the squared answer)
+        meta, lb = np.asarray(wl.meta), np.asarray(wl.lb)
+        d2 = np.square(np.asarray(fd, np.float32))
+        radius = d2.reshape(nbr, 8).max(axis=1)
+        real = meta[:, 3] >= 0
+        assert f["live"] == int(np.sum(
+            real & (lb <= radius[meta[:, 0]])))
+
+    def test_dense_layout_fallback_is_the_dense_call(self, monkeypatch):
+        """The dense layout sweeps every column with no worklist and records
+        no ring counters; the block-sparse fallback builds nothing on the
+        host, so the worklist counters keep counting the fused sweep's."""
+        from repro import obs
+        from repro.kernels import blocksparse
+
+        pts, d_cut = _clusters(600, 3, 4)
+        be = get_backend("pallas-interpret")
+        calls = self._record_fallback(monkeypatch)
+        obs.configure(level="metrics")
+        try:
+            be.rho_delta(pts, pts, d_cut)
+            f = self._fallback_span()
+            obs.reset_spans()
+            builds = blocksparse.worklist_build_count()
+            be.rho_delta(pts, pts, d_cut, layout="block-sparse")
+            (build,) = [s for s in obs.spans()
+                        if s["name"] == "worklist.build"]
+        finally:
+            obs.configure(level="off")
+            obs.reset_spans()
+        assert f["rows"] > 0 and "pairs" not in f and "live" not in f
+        assert [wl is None for wl, _ in calls] == [True, False]
+        assert blocksparse.worklist_build_count() == builds + 1
+        assert obs.get_metric("worklist_len").value() \
+            == build["attrs"]["kept"]
+
+
 class TestDegenerateWorklists:
     """All points in one grouping cell: nothing prunes, the worklist is the
     dense pair set, and the engine must behave exactly as worklist=None."""

@@ -280,8 +280,10 @@ class TestEngineTracing:
         n = len(pts)
         assert 0 < f["rows"] <= f["padded"] < 2 * f["rows"]
         # resolved and the interest mask (bool), delta and parent, the
-        # fallback's answers; rows (int64) up twice, delta and parent up
-        assert f["d2h_bytes"] == 2 * n + 8 * n + 8 * f["padded"]
+        # fallback's answers and its live-pair count (int32); rows (int64)
+        # up twice, delta and parent up
+        assert f["d2h_bytes"] == 2 * n + 8 * n + 8 * f["padded"] + 4
+        assert 0 < f["live"] <= f["pairs"]
         assert f["h2d_bytes"] == 2 * 8 * f["padded"] + 8 * n
         assert by["engine.fit"]["attrs"]["h2d_bytes"] == pts.nbytes
         assert by["approxdpc.rules"]["attrs"]["d2h_bytes"] == n

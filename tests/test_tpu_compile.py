@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import ops
+from repro.kernels import blocksparse, ops
 from repro.kernels.blocksparse import WL_CHUNK, FlatWorklist
 
 N_FULL = 2_049_280          # Household, paper size
@@ -98,6 +98,36 @@ def test_nn_sweep_full_table(one_chip):
                                                        interpret=False),
              _f32(one_chip, 4096, D), _f32(one_chip, 4096),
              _f32(one_chip, N_FULL, D), _f32(one_chip, N_FULL))
+
+
+def test_nn_sweep_ring_worklist(one_chip):
+    """The fit's unresolved-row fallback under the block-sparse layout: the
+    chunked best-1 sweep ((4, 8192) meta, 128 x 256 tiles) over the full
+    table."""
+    meta = jax.ShapeDtypeStruct((32, 4, WL_CHUNK), jnp.int32,
+                                sharding=one_chip)
+    lb = jax.ShapeDtypeStruct((32, WL_CHUNK), jnp.float32, sharding=one_chip)
+
+    def fallback(q, qk, t, tk, m, b):
+        wl = FlatWorklist(meta=m, lb=b, n_kept=0, n_total=0)
+        return ops.dependent_masked(q, qk, t, tk, interpret=False,
+                                    worklist=wl)
+
+    _compile(fallback, _f32(one_chip, 4096, D), _f32(one_chip, 4096),
+             _f32(one_chip, N_FULL, D), _f32(one_chip, N_FULL), meta, lb)
+
+
+def test_ring_worklist_build(one_chip):
+    """The fallback's ring worklist, built on the device, and its live-pair
+    count (plain XLA programs: no kernel to look for)."""
+    def build(q, t):
+        wl = blocksparse.ring_worklist(q, t, block_n=128, block_m=256)
+        assert wl.meta.shape[1:] == (4, WL_CHUNK)
+        return wl.meta, blocksparse.ring_live_pairs(
+            wl.meta, wl.lb, q[:, 0], bn=128)
+
+    jax.jit(build).lower(_f32(one_chip, 4096, D),
+                         _f32(one_chip, N_CHECK, D)).compile()
 
 
 def test_gather_nn(one_chip):
