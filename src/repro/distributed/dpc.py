@@ -26,10 +26,16 @@ Phases (each a shard_map over the ``data`` axis; fixed shapes throughout):
 3. fallback: stencil-unresolved rows (padded to a static cap) x gathered
            table, dense masked NN — the (1-alpha) remainder.
 
+A pallas backend under the block-sparse layout runs its own phases
+(:func:`_device_worklist_fit`): each shard builds its worklists on the
+device, a d_cut count list for one fused count + kept-k sweep, then a ring
+for the rows the kept-k does not resolve, merged on the device.
+
 Everything is exact: output equals core.run_exdpc / run_scan (tested).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import numpy as np
@@ -40,11 +46,13 @@ from jax import shard_map
 
 from repro import obs
 from repro.analysis.audit import audit_check_rep
-from repro.core.dpc_types import DPCResult, with_jitter
+from repro.core.dpc_types import DPCResult, density_jitter, with_jitter
 from repro.core.grid import build_grid, point_span_bounds
 from repro.engine.planner import as_plan
 from repro.engine.spec import ExecSpec, merge_legacy
 from repro.launch.mesh import flatten_mesh
+from repro.kernels import blocksparse, ops
+from repro.kernels.backend import _fused_resolve
 from repro.kernels.ref import sq_sum
 
 _STRATEGIES = ("gather", "halo")
@@ -69,10 +77,9 @@ class DistDPCConfig:
     * layout 'block-sparse' — grid-pruned worklists for the per-shard
       gather-strategy phases: each shard owns a contiguous chunk of the
       space-sorted table, so its row tiles have compact AABBs against the
-      gathered table and most tile pairs prune away.  Requires a backend
-      whose worklists are jit-built (``worklist_traceable`` — the jnp
-      backend): pallas worklists are host-built and cannot be constructed
-      inside shard_map, so pallas shards keep the dense MXU tiles.
+      gathered table and most tile pairs prune away.  The jnp backend's
+      worklists are jit-built (``worklist_traceable``); pallas shards
+      build theirs on the device (:func:`_device_worklist_fit`).
       Honored on any mesh that passes the R1 probe
       (:func:`shard_blocksparse_layout`) — with the one-hot ring walk no
       sort-derived index reaches a gather inside the shard body, so
@@ -319,6 +326,235 @@ def _make_delta_dense(axis, block, be, layout=None):
     return delta
 
 
+# ---- pallas block-sparse shard phases: worklists built on the device ----
+# The pallas sweeps take scalar-prefetched tile-pair lists.  Each shard
+# builds its own inside shard_map: its row tiles x the gathered table's
+# column tiles, the pairs whose lower bound is within d_cut, for a fused
+# count + kept-k sweep; then, for the rows the kept-k does not resolve, the
+# ascending-bound ring of every column tile, swept best-1.  Each phase is a
+# program of its own, so a sort in one never indexes a read in another (the
+# R1 pattern is per compiled module).  Static lengths are powers of two of
+# what the shards need, read back once per list: a re-fit in a new order
+# reuses every program.
+_SM = functools.partial(shard_map, check_vma=False)   # pallas: no rep rule
+
+
+def _my_rows(axis, m_s: int, n: int):
+    """Rows of my shard that are points (the last shards' tails pad)."""
+    return jnp.clip(n - jax.lax.axis_index(axis) * m_s, 0, m_s)
+
+
+@functools.lru_cache(maxsize=8)
+def _prog_cut_prefix(mesh, axis, n: int, m_s: int, d_cut: float, bn: int):
+    """Count list, stage one: per row tile the column tiles within d_cut,
+    sorted; (chunks, entries) per shard for the host."""
+    def body(pts_my):
+        tbl = jax.lax.all_gather(pts_my, axis, axis=0, tiled=True)
+        cp = blocksparse.cut_prefix(pts_my, _my_rows(axis, m_s, n), tbl, n,
+                                    d_cut, bn=bn, bm=ops.DENSITY_BLOCK_M)
+        stats = jnp.stack([cp.chunks,
+                           jnp.sum(cp.kept, dtype=jnp.int32)])[None]
+        return cp.lb_sorted, cp.order, cp.kept, cp.start, stats
+
+    return jax.jit(_SM(body, mesh=mesh, in_specs=P(axis),
+                       out_specs=(P(axis),) * 5))
+
+
+@functools.lru_cache(maxsize=8)
+def _prog_cut_list(mesh, axis, d_cut: float, n_chunks: int):
+    """Count list, stage two: the chunked list at ``n_chunks`` chunks."""
+    def body(lb_sorted, order, kept, start, stats):
+        cp = blocksparse.CutPrefix(lb_sorted=lb_sorted, order=order,
+                                   kept=kept, start=start,
+                                   chunks=stats[0, 0])
+        wl = blocksparse.cut_worklist(cp, d_cut, n_chunks=n_chunks)
+        return wl.meta, wl.lb, wl.live_chunks[None]
+
+    return jax.jit(_SM(body, mesh=mesh, in_specs=(P(axis),) * 5,
+                       out_specs=(P(axis),) * 3))
+
+
+@functools.lru_cache(maxsize=8)
+def _prog_count(mesh, axis, d_cut: float, bn: int, precision: str, be):
+    """dist.rho: the fused count + kept-k sweep over my count list."""
+    def body(pts_my, meta, lb, live):
+        tbl = jax.lax.all_gather(pts_my, axis, axis=0, tiled=True)
+        wl = blocksparse.FlatWorklist(meta=meta, lb=lb, n_kept=-1,
+                                      n_total=-1, live_chunks=live[0])
+        return be.count_kept(pts_my, tbl, d_cut, worklist=wl, block=bn,
+                             precision=precision)
+
+    return jax.jit(_SM(body, mesh=mesh, in_specs=(P(axis),) * 4,
+                       out_specs=(P(axis),) * 3))
+
+
+def _pad_to(x, multiple: int, value):
+    return _pad_rows(x, -(-x.shape[0] // multiple) * multiple, value)
+
+
+@functools.lru_cache(maxsize=8)
+def _prog_resolve(mesh, axis, n: int, m_s: int, d_cut: float):
+    """dist.delta: density keys, then each row's nearest strictly denser
+    kept candidate.  A row is resolved when that candidate lies within
+    d_cut: every point nearer than it is then inside a listed pair, so
+    inside the kept-k.  Returns the column keys (-inf on pad rows), delta,
+    parent (a global sorted slot), resolved, and the unresolved count."""
+    def body(pts_my, cnt, jit_my, topv, topi):
+        real = jnp.arange(m_s) < _my_rows(axis, m_s, n)
+        rk = jnp.where(real, cnt + jit_my, -jnp.inf)
+        # pad to the sweep's column tiles: every kept index is a table row
+        tbl = _pad_to(jax.lax.all_gather(pts_my, axis, axis=0, tiled=True),
+                      ops.DENSITY_BLOCK_M, ops.PAD_COORD)
+        rk_all = _pad_to(jax.lax.all_gather(rk, axis, axis=0, tiled=True),
+                         ops.DENSITY_BLOCK_M, -jnp.inf)
+        delta, parent, ok = _fused_resolve(
+            pts_my, tbl, jnp.where(real, rk, jnp.inf), rk_all, topv, topi)
+        ok = (ok & (delta < d_cut)) | ~real
+        return rk, delta, parent, ok, jnp.sum(~ok, dtype=jnp.int32)[None]
+
+    return jax.jit(_SM(body, mesh=mesh, in_specs=(P(axis),) * 5,
+                       out_specs=(P(axis),) * 5))
+
+
+@functools.lru_cache(maxsize=8)
+def _prog_ring(mesh, axis, n: int, cap: int, bn: int):
+    """The ring of my unresolved rows, gathered into ``cap`` slots (the
+    slots past them repeat the first, so no row tile's radius is held at
+    +inf); only the chunks that hold unresolved rows run."""
+    def body(ok, pts_my, rk, u):
+        unres = ~ok
+        rows = jnp.nonzero(unres, size=cap,
+                           fill_value=jnp.argmax(unres))[0].astype(jnp.int32)
+        xr = pts_my[rows]
+        tbl = jax.lax.all_gather(pts_my, axis, axis=0, tiled=True)
+        wl = blocksparse.ring_worklist(xr, tbl, block_n=bn,
+                                       block_m=blocksparse.BS_BLOCK_M,
+                                       ny=n, live_rows=u[0])
+        return rows, xr, rk[rows], wl.meta, wl.lb, wl.live_chunks[None]
+
+    return jax.jit(_SM(body, mesh=mesh, in_specs=(P(axis),) * 4,
+                       out_specs=(P(axis),) * 6))
+
+
+@functools.lru_cache(maxsize=8)
+def _prog_fallback(mesh, axis, m_s: int, bn: int, be):
+    """dist.fallback: the best-1 sweep of my ring, merged into my rows on
+    the device; also the ring pairs within their row tile's final radius."""
+    def body(rows, xr, kr, meta, lb, live, u, pts_my, rk, delta, parent):
+        tbl = jax.lax.all_gather(pts_my, axis, axis=0, tiled=True)
+        rk_all = jax.lax.all_gather(rk, axis, axis=0, tiled=True)
+        wl = blocksparse.FlatWorklist(meta=meta, lb=lb, n_kept=-1,
+                                      n_total=-1, live_chunks=live[0])
+        fd, fp = be.denser_nn(xr, kr, tbl, rk_all, block=bn, worklist=wl)
+        pairs = blocksparse.ring_live_pairs(meta, lb, fd, live[0], bn=bn)
+        at = jnp.where(jnp.arange(rows.shape[0]) < u[0], rows, m_s)
+        delta = delta.at[at].set(jnp.where(jnp.isfinite(fd), fd, jnp.inf),
+                                 mode="drop")
+        parent = parent.at[at].set(fp, mode="drop")
+        return delta, parent, pairs[None]
+
+    return jax.jit(_SM(body, mesh=mesh, in_specs=(P(axis),) * 11,
+                       out_specs=(P(axis),) * 3))
+
+
+def _ring_listed(u: np.ndarray, cap: int, bn: int, n_tbl: int) -> tuple:
+    """(ring pairs, chunks, row tiles) the shards' fallback sweeps run."""
+    nbr, nbc = _blocked(cap, bn), _blocked(n_tbl, blocksparse.BS_BLOCK_M)
+    per = blocksparse.ring_rows_per_chunk(nbr, nbc)
+    chunks = _blocked(_blocked(u, bn), per)
+    tiles = np.minimum(chunks * per, nbr)
+    return int(tiles.sum() * nbc), int(chunks.sum()), int(tiles.sum())
+
+
+def _device_worklist_fit(pts_s, grid, flat_mesh, axis, d_cut: float,
+                         block: int, precision: str, be):
+    """Exact rho, delta and parent (sorted slots) of the grid-sorted table
+    ``pts_s`` (padded to equal shards) through the pallas block-sparse shard
+    phases, every list built on the device.  A fit reads back two small
+    vectors per shard: the count lists' chunk needs and the unresolved row
+    counts."""
+    n = grid.points.shape[0]
+    S = flat_mesh.devices.size
+    m_s = pts_s.shape[0] // S
+    d_cut = float(d_cut)
+    layout = "block-sparse"
+    with obs.span("dist.worklist", phase="count") as sp:
+        pre = _prog_cut_prefix(flat_mesh, axis, n, m_s, d_cut, block)(pts_s)
+        stats = np.asarray(pre[4])                      # (S, 2)
+        n_chunks = blocksparse._pow2(int(stats[:, 0].max()))
+        meta, lb, live = _prog_cut_list(flat_mesh, axis, d_cut,
+                                        n_chunks)(*pre)
+        listed = int(stats[:, 1].sum())
+        C = meta.shape[-1]
+        sp.set(listed=listed, chunks=int(stats[:, 0].sum()),
+               row_tiles=S * _blocked(m_s, block),
+               d2h_bytes=int(stats.nbytes))
+        sp.sync(meta)
+    with obs.span("dist.rho", n=n, shards=S, strategy="gather",
+                  layout=layout, listed=listed,
+                  padded=int(stats[:, 0].sum()) * C, d2h_bytes=0,
+                  h2d_bytes=0) as sp:
+        cnt, topv, topi = sp.sync(_prog_count(
+            flat_mesh, axis, d_cut, block, precision, be)(pts_s, meta, lb,
+                                                          live))
+    jit_s = _pad_rows(density_jitter(n)[grid.order], pts_s.shape[0], 0.0)
+    with obs.span("dist.delta", n=n, shards=S, layout=layout) as sp:
+        rk, dlt, par, ok, u_dev = _prog_resolve(
+            flat_mesh, axis, n, m_s, d_cut)(pts_s, cnt, jit_s, topv, topi)
+        u = np.asarray(u_dev)
+        sp.set(d2h_bytes=int(u.nbytes), h2d_bytes=0)
+    bn = min(block, 1024)
+    with obs.span("dist.fallback", unresolved=int(u.sum()), shards=S,
+                  layout=layout) as sp:
+        listed = live_pairs = cap = d2h = 0
+        if u.max() > 0:
+            # at least one row tile: a tile the kernel pads with +inf-keyed
+            # rows would keep every ring pair live
+            cap = max(blocksparse._pow2(int(u.max())), bn)
+            with obs.span("dist.worklist", phase="ring") as wsp:
+                ring = _prog_ring(flat_mesh, axis, n, cap, bn)(
+                    ok, pts_s, rk, u_dev)
+                listed, chunks, tiles = _ring_listed(u, cap, bn,
+                                                     pts_s.shape[0])
+                wsp.set(listed=listed, chunks=chunks, row_tiles=tiles,
+                        d2h_bytes=0)
+                wsp.sync(ring)
+            dlt, par, pairs = _prog_fallback(flat_mesh, axis, m_s, bn, be)(
+                *ring, u_dev, pts_s, rk, dlt, par)
+            if obs.enabled():
+                live_pairs = int(np.asarray(pairs).sum())
+                d2h = int(pairs.nbytes)
+        sp.set(listed=listed, live=live_pairs, padded=S * cap,
+               d2h_bytes=d2h, h2d_bytes=0)
+        sp.sync(dlt)
+    return cnt[:n], dlt[:n], par[:n]
+
+
+def _device_phase_probes(flat_mesh, axis, be) -> list:
+    """(program, abstract arguments) of each device-worklist phase at a
+    tiny size, each traced alone: a program's inputs are data to it."""
+    S = flat_mesh.devices.size
+    m_s, bn, d_cut = 8, 256, 1.0
+    pts = jax.ShapeDtypeStruct((S * m_s, 2), jnp.float32)
+    out = []
+
+    def add(prog, *args):
+        out.append((prog, args))
+        return jax.eval_shape(prog, *args)
+
+    pre = add(_prog_cut_prefix(flat_mesh, axis, S * m_s, m_s, d_cut, bn), pts)
+    meta, lb, live = add(_prog_cut_list(flat_mesh, axis, d_cut, 1), *pre)
+    cnt, topv, topi = add(_prog_count(flat_mesh, axis, d_cut, bn, "f32", be),
+                          pts, meta, lb, live)
+    rk, dlt, par, ok, u = add(
+        _prog_resolve(flat_mesh, axis, S * m_s, m_s, d_cut), pts, cnt,
+        jax.ShapeDtypeStruct((S * m_s,), jnp.float32), topv, topi)
+    ring = add(_prog_ring(flat_mesh, axis, S * m_s, bn, bn), ok, pts, rk, u)
+    add(_prog_fallback(flat_mesh, axis, m_s, bn, be), *ring, u, pts, rk, dlt,
+        par)
+    return out
+
+
 _BS_SAFE_CACHE: dict = {}
 
 
@@ -337,6 +573,11 @@ def _bs_shards_safe(flat_mesh, axis: str, be) -> bool:
         return hit
     from repro.analysis import spmd_gather_safe
 
+    if not be.worklist_traceable:
+        ok = all(spmd_gather_safe(prog, *args) for prog, args
+                 in _device_phase_probes(flat_mesh, axis, be))
+        _BS_SAFE_CACHE[key] = ok
+        return ok
     rho_fn = _make_rho_dense(axis, 1.0, 256, be, layout="block-sparse")
     delta_fn = _make_delta_dense(axis, 256, be, layout="block-sparse")
     sm_rho = shard_map(rho_fn, mesh=flat_mesh,
@@ -375,13 +616,16 @@ def shard_blocksparse_layout(pl, mesh) -> str | None:
     it, else ``None`` (dense degrade — correct results always beat pruned
     tile counts).
 
-    Per-shard block-sparse needs jit-built worklists (inside shard_map),
-    so only ``worklist_traceable`` backends qualify.  On multi-partition
-    meshes the phases must additionally pass the R1 probe
+    Per-shard block-sparse needs worklists built inside shard_map: the
+    ``worklist_traceable`` (jnp) backend's jit-built ring walks, and for
+    the pallas backends the device-built lists of
+    :func:`_device_worklist_fit` (reason ``device-worklist``).  On
+    multi-partition meshes the phases must pass the R1 probe
     (:func:`_bs_shards_safe`) against the pinned jax-0.4.37 XLA CPU SPMD
-    miscompile.  The one-hot ring walk keeps every sort-derived value out
-    of gather/dynamic-slice index position, so the probe passes and
-    multi-device meshes run block-sparse shard phases
+    miscompile.  The one-hot ring walk, and the device lists' index
+    arithmetic (from counts, never from a sort), keep every sort-derived
+    value out of gather/dynamic-slice index position, so the probe passes
+    and multi-device meshes run block-sparse shard phases
     (tests/test_distributed_dpc.py pins both the probe verdict and
     bit-parity with ``run_exdpc`` in a 4-device subprocess).
 
@@ -399,13 +643,12 @@ def shard_blocksparse_layout(pl, mesh) -> str | None:
         _G_BS_LAYOUT.set(0.0 if layout is None else 1.0, reason=reason)
         return layout
 
-    if not be.worklist_traceable:
-        return decide(None, "host-worklist-backend")
     flat_mesh = flatten_mesh(mesh, pl.data_axis)
     if flat_mesh.devices.size == 1:
         return decide("block-sparse", "single-partition")
     if _bs_shards_safe(flat_mesh, pl.data_axis, be):
-        return decide("block-sparse", "r1-probe-passed")
+        return decide("block-sparse", "r1-probe-passed"
+                      if be.worklist_traceable else "device-worklist")
     return decide(None, "r1-probe-failed")
 
 
@@ -464,6 +707,12 @@ def distributed_dpc(points, cfg: DistDPCConfig | None = None,
 
     halo = cfg.strategy == "halo"
     shard_layout = shard_blocksparse_layout(pl, flat_mesh)
+    if not be.worklist_traceable and shard_layout == "block-sparse":
+        if not halo:
+            return _unsort(grid, *_device_worklist_fit(
+                pts_s, grid, flat_mesh, axis, cfg.d_cut, block,
+                pl.precision, be))
+        shard_layout = None     # the pallas halo phases sweep densely
     dense = (be.mxu_dense or shard_layout == "block-sparse") and not halo
     if halo or not dense:   # the dense kernel tiles never read the spans
         starts, ends = point_span_bounds(grid)      # (n, S_spans)
@@ -588,8 +837,14 @@ def distributed_dpc(points, cfg: DistDPCConfig | None = None,
         par[unresolved] = fp
         dlt_s, par_s = jnp.asarray(dlt), jnp.asarray(par)
 
-    delta = dlt_s[grid.inv_order]
+    return _unsort(grid, rho_sorted, dlt_s, par_s)
+
+
+def _unsort(grid, rho_sorted, dlt_s, par_s) -> DPCResult:
+    """Per-row results of the grid-sorted table back in input order."""
+    rho = rho_sorted[grid.inv_order]
     parent_sorted = par_s[grid.inv_order]
     parent = jnp.where(parent_sorted >= 0, grid.order[parent_sorted],
                        -1).astype(jnp.int32)
-    return DPCResult(rho=rho, rho_key=rho_key, delta=delta, parent=parent)
+    return DPCResult(rho=rho, rho_key=with_jitter(rho),
+                     delta=dlt_s[grid.inv_order], parent=parent)

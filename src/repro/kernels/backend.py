@@ -46,8 +46,9 @@ with a progressively-shrinking prune radius.  The jnp worklists are
 jit-built (``worklist_traceable``: block-sparse stays legal inside
 jit/shard_map and ``rho_delta`` stays ``fused_traceable``); the pallas
 worklists are host-built, like the grid itself, and drive a scalar-
-prefetched 1-D kernel grid.  f32 results are bit-identical to the dense
-layout of the same backend — pruning bounds carry conservative slack
+prefetched 1-D kernel grid — or built on the device by a caller that hands
+them in (``count_kept``, ``denser_nn(worklist=)``: the distributed shards).
+f32 results are bit-identical to the dense layout of the same backend — pruning bounds carry conservative slack
 covering f32 rounding of the bound arithmetic, NN tie-breaks are
 explicitly lexicographic — which is property-tested on tie-heavy lattice
 data (tests/test_blocksparse.py).  Under ``precision="bf16"`` the bounds
@@ -181,6 +182,14 @@ class KernelBackend:
     def prefix_nn(self, pts_sorted_desc, *, block: int | None = None):
         """(delta, parent): NN among strict-prefix rows, input pre-sorted by
         descending density key — Def. 2 as a triangular sweep (Ex-DPC)."""
+        raise NotImplementedError
+
+    def count_kept(self, x, y, d_cut, *, worklist, block: int | None = None,
+                   precision: str | None = None):
+        """The first half of the fused ``rho_delta`` over a given worklist
+        (one built on the device: traceable): (rho (n,) f32, the k nearest
+        visited candidates' expanded d2 (n, k) and y-rows (n, k)), the
+        denser-mask left to the caller once every count is known."""
         raise NotImplementedError
 
     # ---- fused rho + delta (the unified-engine primitive) ----
@@ -615,10 +624,12 @@ class PallasBackend(KernelBackend):
     list whose pairs the kernel skips against its live prune radius, and
     the fused ``rho_delta`` the union of the d_cut prefix and the static
     k-NN ring.  Host-built means not jit-callable (``worklist_traceable``
-    stays False) — the same contract as the grid build itself.  The one
-    exception is the fused ``rho_delta``'s unresolved-row fallback, whose
-    keep-every-pair ring is built on the device
-    (:func:`blocksparse.ring_worklist`).
+    stays False) — the same contract as the grid build itself.  The
+    exceptions are lists built on the device: the fused ``rho_delta``'s
+    unresolved-row fallback, whose keep-every-pair ring is built there
+    (:func:`blocksparse.ring_worklist`), and a worklist handed to
+    ``count_kept`` or ``denser_nn`` (the distributed shard phases build
+    theirs inside ``shard_map``), which sweep it under a trace.
     """
 
     mxu_dense = True
@@ -650,15 +661,27 @@ class PallasBackend(KernelBackend):
         return ops.local_density_delta(x, batch, signs, d_cut, block_n=bn,
                                        interpret=self.interpret, worklist=wl)
 
-    def denser_nn(self, x, x_key, y, y_key, *, block=None, layout=None):
+    def denser_nn(self, x, x_key, y, y_key, *, block=None, layout=None,
+                  worklist=None):
+        """``worklist``: a best-1 ring built on the device at the sweep's
+        tiles (``block`` rows, ``blocksparse.BS_BLOCK_M`` columns), swept
+        as given — traceable; otherwise ``layout`` decides."""
         bn = min(block or 128, 1024)
-        wl = None
-        if _sparse(layout):
+        wl = worklist
+        if wl is None and _sparse(layout):
             _require_host("denser_nn", x, y)
             wl = blocksparse.build_flat_worklist(
                 x, y, None, block_n=bn, block_m=256, count=False, nn="best1")
         return ops.dependent_masked(x, x_key, y, y_key, block_n=bn,
                                     interpret=self.interpret, worklist=wl)
+
+    def count_kept(self, x, y, d_cut, *, worklist, block=None,
+                   precision=None):
+        """The fused sweep (count + unmasked kept-k) over ``worklist``, at
+        ``block`` x ``ops.DENSITY_BLOCK_M`` tiles."""
+        return ops.fused_sweep(x, y, d_cut, precision=precision or "f32",
+                               block_n=block or ops.DENSITY_BLOCK_N,
+                               interpret=self.interpret, worklist=worklist)
 
     def prefix_nn(self, pts_sorted_desc, *, block=None):
         return ops.dependent_prefix(pts_sorted_desc, block=block or 256,

@@ -44,7 +44,9 @@ owns that pruning logic once, in three forms:
   contribute a kept entry).  A best-1 list that keeps every pair is a pure
   function of the tile bounds, so the fused pallas ``rho_delta``'s
   unresolved-row fallback builds it on the device (:func:`ring_worklist`)
-  in the same chunked form.
+  in the same chunked form.  So do the distributed shards, for their
+  d_cut count lists as well (:func:`cut_prefix`, :func:`cut_worklist`: a
+  static, bucketed length of which only the live chunks run).
 
 Every builder force-keeps at least one pair per row tile so output blocks
 are always initialized, and all-in-one-cell data degenerates to the dense
@@ -393,8 +395,13 @@ class FlatWorklist:
 
     meta: jnp.ndarray          # (chunks, 4, C) int32
     lb: jnp.ndarray            # (chunks, C) f32 — the in-kernel NN prune radius
-    n_kept: int                # worklist entries (incl. forced keeps)
+    n_kept: int                # worklist entries (incl. forced keeps);
+                               # -1 where only the device knows it
     n_total: int               # nbr * nbc dense pair count
+    # () int32 on the device: the leading chunks the sweep runs, the rest
+    # unused capacity of a list built at a static, bucketed length; None:
+    # every chunk
+    live_chunks: jnp.ndarray | None = None
 
     @property
     def pruned_frac(self) -> float:
@@ -437,7 +444,8 @@ def chunk_worklist(meta: np.ndarray, lb: np.ndarray,
     return np.stack(metas), np.stack(lbs)
 
 
-def ring_worklist(x, y, *, block_n: int, block_m: int) -> FlatWorklist:
+def ring_worklist(x, y, *, block_n: int, block_m: int, ny=None,
+                  live_rows=None) -> FlatWorklist:
     """Device-built best-1 ring worklist: every tile pair of ``x`` rows
     against ``y`` columns, each row tile's column tiles in ascending lower
     bound (stable: ties by tile index), chunked as :func:`chunk_worklist`
@@ -446,24 +454,45 @@ def ring_worklist(x, y, *, block_n: int, block_m: int) -> FlatWorklist:
     The list ``build_flat_worklist(nn="best1")`` builds on the host (its
     bounds equal up to f32 summation order), without the round trip: a
     best-1 list keeps every pair, so it is a pure function of the tile
-    bounds, and its shapes depend only on the table shapes.  The kernel's ``lb <= max(best)`` test then skips every pair
-    past the row tile's final radius.  Sets no worklist counter.
+    bounds, and its shapes depend only on the table shapes.  The kernel's
+    ``lb <= max(best)`` test then skips every pair past the row tile's
+    final radius.  Sets no worklist counter.  ``ny``: the leading rows of
+    ``y`` that are points; ``live_rows`` (traceable): the leading rows of
+    ``x`` to answer, so only the chunks that hold them run.
     """
     nbr, nbc = -(-x.shape[0] // block_n), -(-y.shape[0] // block_m)
-    meta, lb = _ring_chunks(x, y, bn=block_n, bm=block_m, chunk=WL_CHUNK)
+    meta, lb = _ring_chunks(x, y, ny, bn=block_n, bm=block_m, chunk=WL_CHUNK)
+    live = None
+    if live_rows is not None:
+        tiles = -(-live_rows // block_n)
+        live = -(-tiles // ring_rows_per_chunk(nbr, nbc))
     return FlatWorklist(meta=meta, lb=lb, n_kept=nbr * nbc,
-                        n_total=nbr * nbc)
+                        n_total=nbr * nbc, live_chunks=live)
+
+
+def _chunk_len(nbr: int, nbc: int, chunk: int) -> int:
+    """Entries per chunk of a device-built list: a power of two that holds
+    a whole row tile of ``nbc`` entries."""
+    return _pow2(max(nbc, min(nbr * nbc, chunk), 8))
+
+
+def ring_rows_per_chunk(nbr: int, nbc: int) -> int:
+    """Row tiles in each chunk of a :func:`ring_worklist` (every row tile
+    lists all ``nbc`` column tiles)."""
+    return _chunk_len(nbr, nbc, WL_CHUNK) // nbc
 
 
 @partial(jax.jit, static_argnames=("bn", "bm", "chunk"))
-def _ring_chunks(x, y, *, bn: int, bm: int, chunk: int):
+def _ring_chunks(x, y, ny=None, *, bn: int, bm: int, chunk: int):
     """(chunks, 4, C) meta and (chunks, C) bounds of :func:`ring_worklist`.
 
     Every row tile holds nbc entries, so a chunk holds C // nbc whole row
     tiles; the entries past a chunk's last real one repeat it with flag -1
-    and an infinite bound, as the host chunker pads.
+    and an infinite bound, as the host chunker pads.  ``ny``: the leading
+    rows of ``y`` that are points (the rest bound nothing); all of them
+    when None.
     """
-    n, m = x.shape[0], y.shape[0]
+    n, m = x.shape[0], y.shape[0] if ny is None else ny
     xp, yp = _pad_inf(x.astype(jnp.float32), bn), \
         _pad_inf(y.astype(jnp.float32), bm)
     nbr, nbc = xp.shape[0] // bn, yp.shape[0] // bm
@@ -473,7 +502,7 @@ def _ring_chunks(x, y, *, bn: int, bm: int, chunk: int):
     lb_sorted, order = jax.lax.sort(
         (lb, jax.lax.broadcasted_iota(jnp.int32, lb.shape, 1)),
         dimension=1, num_keys=1, is_stable=True)
-    C = _pow2(max(nbc, min(nbr * nbc, chunk), 8))
+    C = _chunk_len(nbr, nbc, chunk)
     per = C // nbc                                  # row tiles per chunk
     n_chunks = -(-nbr // per)
     c = jnp.arange(n_chunks)[:, None]
@@ -489,18 +518,100 @@ def _ring_chunks(x, y, *, bn: int, bm: int, chunk: int):
 
 
 @partial(jax.jit, static_argnames=("bn",))
-def ring_live_pairs(meta, lb, delta, *, bn: int):
+def ring_live_pairs(meta, lb, delta, live_chunks=None, *, bn: int):
     """Entries of a best-1 ring worklist whose lower bound is within their
     row tile's final radius: the max over the tile's rows of ``delta``
     squared, where rows past ``delta`` (the kernel's pad rows, keyed +inf)
     hold it at +inf.  Visited in ascending bound, these are the pairs the
-    kernel computes; it skips the rest.  Chunk padding is not counted."""
+    kernel computes; it skips the rest.  Chunk padding is not counted, nor
+    chunks past ``live_chunks`` (the sweep never runs them)."""
     nbr = -(-delta.shape[0] // bn)
     d2 = jnp.pad(jnp.square(delta.astype(jnp.float32)),
                  (0, nbr * bn - delta.shape[0]), constant_values=jnp.inf)
     radius = jnp.max(d2.reshape(nbr, bn), axis=1)
     live = (meta[:, 3] >= 0) & (lb <= radius[meta[:, 0]])
+    if live_chunks is not None:
+        live &= (jnp.arange(meta.shape[0]) < live_chunks)[:, None]
     return jnp.sum(live, dtype=jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# Device-built d_cut count lists (traceable: the shard phases of
+# ``distributed.dpc`` build them inside shard_map).  Two stages with one host
+# read between them: :func:`cut_prefix` finds each row tile's ascending-lb
+# prefix within d_cut and packs whole row tiles greedily into chunks; the
+# host takes the largest chunk count over the shards up to a power of two,
+# the list's static length; :func:`cut_worklist` then lays the entries out
+# in :class:`FlatWorklist` chunks, the host builder's format.
+@dataclass(frozen=True)
+class CutPrefix:
+    """Stage one of a device-built count list (all on the device)."""
+
+    lb_sorted: jnp.ndarray     # (nbr, nbc) f32, each row tile ascending
+    order: jnp.ndarray         # (nbr, nbc) int32 column tile of each entry
+    kept: jnp.ndarray          # (nbr,) int32 entries of each row tile
+    start: jnp.ndarray         # (nbr,) int32 slot of each row tile's first
+    chunks: jnp.ndarray        # () int32 chunks the packing fills
+
+
+def cut_prefix(x, nx, y, ny, d_cut, *, bn: int, bm: int,
+               chunk: int = WL_CHUNK) -> CutPrefix:
+    """The kept pairs of ``build_flat_worklist(count=True)`` for ``x`` rows
+    against ``y`` columns, on the device: per row tile the column tiles
+    whose lower bound is within d_cut, in ascending bound (stable, ties by
+    tile index), at least the nearest one (output blocks initialize).
+    ``nx`` / ``ny``: the leading rows of each table that are points."""
+    xp, yp = _pad_inf(x.astype(jnp.float32), bn), \
+        _pad_inf(y.astype(jnp.float32), bm)
+    nbr, nbc = xp.shape[0] // bn, yp.shape[0] // bm
+    C = _chunk_len(nbr, nbc, chunk)
+    lb = _tile_pair_lb(xp, nx, yp, ny, bn, bm)
+    d2cut = jnp.asarray(d_cut, jnp.float32) ** 2
+    kept = jnp.maximum(jnp.sum(lb <= d2cut, axis=1, dtype=jnp.int32), 1)
+    # the same stable int32 key-value sort as the ring's (_ring_chunks)
+    lb_sorted, order = jax.lax.sort(
+        (lb, jax.lax.broadcasted_iota(jnp.int32, lb.shape, 1)),
+        dimension=1, num_keys=1, is_stable=True)
+
+    def pack(carry, k):                 # whole row tiles, greedily
+        c, fill = carry
+        new = fill + k > C
+        c = c + new.astype(jnp.int32)
+        fill = jnp.where(new, 0, fill)
+        return (c, fill + k), c * C + fill
+
+    (last, _), start = jax.lax.scan(pack, (jnp.int32(0), jnp.int32(0)),
+                                    kept)
+    return CutPrefix(lb_sorted=lb_sorted, order=order, kept=kept,
+                     start=start, chunks=last + 1)
+
+
+def cut_worklist(cp: CutPrefix, d_cut, *, n_chunks: int,
+                 chunk: int = WL_CHUNK) -> FlatWorklist:
+    """Stage two: ``n_chunks`` chunks (at least ``cp.chunks``) in
+    :func:`chunk_worklist`'s format.  Slot ``s`` belongs to the last row
+    tile that starts at or before it; past that tile's entries it repeats
+    the tile's last entry with flag -1 and an infinite bound, so a chunk's
+    padding repeats its last pair, as the host chunker pads.  Chunks past
+    ``cp.chunks`` hold only padding and are not run
+    (``FlatWorklist.live_chunks``).  Every index here comes from the
+    packing, none from the sort: no sort-derived gather index (R1)."""
+    nbr, nbc = cp.lb_sorted.shape
+    C = _chunk_len(nbr, nbc, chunk)
+    d2cut = jnp.asarray(d_cut, jnp.float32) ** 2
+    s = jnp.arange(n_chunks * C, dtype=jnp.int32)
+    row = (jnp.searchsorted(cp.start, s, side="right") - 1).astype(jnp.int32)
+    r = s - cp.start[row]
+    real = r < cp.kept[row]
+    r = jnp.minimum(r, cp.kept[row] - 1)
+    lb = cp.lb_sorted[row, r]
+    flag = jnp.where(real, (lb <= d2cut).astype(jnp.int32), -1)
+    meta = jnp.stack([row, cp.order[row, r], (real & (r == 0)), flag])
+    meta = meta.astype(jnp.int32).reshape(4, n_chunks, C).transpose(1, 0, 2)
+    return FlatWorklist(meta=meta,
+                        lb=jnp.where(real, lb, jnp.inf).reshape(n_chunks, C),
+                        n_kept=-1, n_total=nbr * nbc,
+                        live_chunks=cp.chunks)
 
 
 def _host_bounds(arr: np.ndarray, block: int):

@@ -22,7 +22,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .sweep import (DEFAULT_BLOCK_M, DEFAULT_BLOCK_N, PAD_COORD,  # noqa: F401
-                    SweepSpec, tile_sweep)
+                    SweepSpec, tile_sweep, worklist_args)
 
 
 def range_count(x: jnp.ndarray, y: jnp.ndarray, d_cut,
@@ -35,9 +35,7 @@ def range_count(x: jnp.ndarray, y: jnp.ndarray, d_cut,
     float or a traced f32 scalar.
     """
     spec = SweepSpec(block_n=block_n, block_m=block_m, count=True)
-    wm, wb = (worklist.meta, worklist.lb) if worklist is not None else (None,
-                                                                        None)
-    (cnt,) = tile_sweep(spec, x, y, d_cut, wl_meta=wm, wl_lb=wb,
+    (cnt,) = tile_sweep(spec, x, y, d_cut, **worklist_args(worklist),
                         interpret=interpret)
     return cnt
 
@@ -57,10 +55,8 @@ def range_count_signed(x: jnp.ndarray, y: jnp.ndarray, signs: jnp.ndarray,
     """
     spec = SweepSpec(block_n=block_n, block_m=block_m, count=True,
                      signed=True)
-    wm, wb = (worklist.meta, worklist.lb) if worklist is not None else (None,
-                                                                        None)
-    (cnt,) = tile_sweep(spec, x, y, d_cut, signs=signs, wl_meta=wm, wl_lb=wb,
-                        interpret=interpret)
+    (cnt,) = tile_sweep(spec, x, y, d_cut, signs=signs,
+                        **worklist_args(worklist), interpret=interpret)
     return cnt
 
 
@@ -79,8 +75,6 @@ def range_count_halo(x: jnp.ndarray, window: jnp.ndarray,
     """
     spec = SweepSpec(block_n=block_n, block_m=block_m, count=True, span=True,
                      span_s=starts.shape[1])
-    wm, wb = (worklist.meta, worklist.lb) if worklist is not None else (None,
-                                                                        None)
     (cnt,) = tile_sweep(spec, x, window, d_cut, starts=starts, ends=ends,
-                        wl_meta=wm, wl_lb=wb, interpret=interpret)
+                        **worklist_args(worklist), interpret=interpret)
     return cnt

@@ -20,7 +20,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .sweep import (REFINE_TOPK, SweepSpec, tile_sweep,  # noqa: F401
-                    refine_topk_d2 as _refine_topk_d2)
+                    worklist_args, refine_topk_d2 as _refine_topk_d2)
 
 DEFAULT_BLOCK = 256
 
@@ -46,10 +46,8 @@ def masked_min_dist(x, x_key, y, y_key, block_n: int = 128,
     """NN among y-rows with y_key > x_key, per x-row (global fallback)."""
     spec = SweepSpec(block_n=block_n, block_m=block_m, nn="best1", key=True,
                      refine_k=refine_k, precision=precision)
-    wm, wb = (worklist.meta, worklist.lb) if worklist is not None else (None,
-                                                                       None)
     best, arg = tile_sweep(spec, x, y, x_key=x_key, y_key=y_key,
-                           wl_meta=wm, wl_lb=wb, interpret=interpret)
+                           **worklist_args(worklist), interpret=interpret)
     return jnp.sqrt(best), arg
 
 
@@ -69,9 +67,7 @@ def masked_min_dist_halo(x, x_key, window, w_key, starts, ends, d_cut,
     spec = SweepSpec(block_n=block_n, block_m=block_m, nn="best1", key=True,
                      span=True, span_s=starts.shape[1], nn_dcut=True,
                      refine_k=refine_k, precision=precision)
-    wm, wb = (worklist.meta, worklist.lb) if worklist is not None else (None,
-                                                                       None)
     best, arg = tile_sweep(spec, x, window, d_cut, x_key=x_key, y_key=w_key,
-                           starts=starts, ends=ends, wl_meta=wm, wl_lb=wb,
+                           starts=starts, ends=ends, **worklist_args(worklist),
                            interpret=interpret)
     return jnp.sqrt(best), arg

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 from .density import (PAD_COORD, range_count, range_count_halo,
                       range_count_signed)
 from .dependent import masked_min_dist, masked_min_dist_halo, prefix_min_dist
-from .sweep import FUSED_TOPK, SweepSpec, gather_nn, tile_sweep
+from .sweep import (FUSED_TOPK, SweepSpec, gather_nn, tile_sweep,
+                    worklist_args)
 
 
 def _on_cpu() -> bool:
@@ -134,10 +135,9 @@ def fused_sweep(x, y, d_cut, *, nn_sel=None, k: int = FUSED_TOPK,
         sel = pad_vec(nn_sel.astype(jnp.float32), block_m, 0.0)
     spec = SweepSpec(block_n=block_n, block_m=block_m, count=True, nn="topk",
                      nn_sel=sel is not None, k=k, precision=precision)
-    wm, wb = (worklist.meta, worklist.lb) if worklist is not None else (None,
-                                                                       None)
     cnt, topv, topi = tile_sweep(spec, xp, yp, d_cut, nn_sel=sel,
-                                 wl_meta=wm, wl_lb=wb, interpret=interpret)
+                                 **worklist_args(worklist),
+                                 interpret=interpret)
     return cnt[:n].astype(jnp.float32), topv[:n], topi[:n]
 
 

@@ -393,9 +393,19 @@ def _when(cond, fn):
         pl.when(cond)(fn)
 
 
+def worklist_args(worklist) -> dict:
+    """``tile_sweep``'s worklist arguments from a ``FlatWorklist`` (or
+    ``None``: the dense sweep)."""
+    if worklist is None:
+        return {}
+    return {"wl_meta": worklist.meta, "wl_lb": worklist.lb,
+            "wl_chunks": worklist.live_chunks}
+
+
 def tile_sweep(spec: SweepSpec, x, y, d_cut=None, x_key=None, y_key=None,
                signs=None, nn_sel=None, starts=None, ends=None,
-               wl_meta=None, wl_lb=None, *, interpret: bool = False):
+               wl_meta=None, wl_lb=None, wl_chunks=None, *,
+               interpret: bool = False):
     """Run the sweep described by ``spec`` over padded inputs.
 
     Shape contract (as for every kernel here): ``x`` is (n, d) padded to a
@@ -404,7 +414,9 @@ def tile_sweep(spec: SweepSpec, x, y, d_cut=None, x_key=None, y_key=None,
     on padded queries / -inf on padded candidates; signs 0; spans empty).
     ``wl_meta``/``wl_lb`` (``blocksparse.FlatWorklist`` chunk arrays, shapes
     (chunks, 4, C) and (chunks, C)) select the block-sparse tile-pair
-    worklist; ``None`` runs the dense all-pairs sweep.  Returns the tuple of
+    worklist; ``None`` runs the dense all-pairs sweep.  ``wl_chunks`` (a
+    device scalar) runs only that many leading chunks, for a list built on
+    the device at a static, bucketed length.  Returns the tuple of
     requested accumulators, in order: ``count`` (n,), then ``nn`` —
     (best_d2, arg) of shape (n,) or (topv, topi) of shape (n, k).
 
@@ -416,14 +428,14 @@ def tile_sweep(spec: SweepSpec, x, y, d_cut=None, x_key=None, y_key=None,
     if d_cut is not None:
         d_cut = jnp.asarray(d_cut, jnp.float32)
     return _tile_sweep_jit(spec, x, y, d_cut, x_key, y_key, signs, nn_sel,
-                           starts, ends, wl_meta, wl_lb,
+                           starts, ends, wl_meta, wl_lb, wl_chunks,
                            interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "interpret"))
 def _tile_sweep_jit(spec: SweepSpec, x, y, d_cut=None, x_key=None,
                     y_key=None, signs=None, nn_sel=None, starts=None,
-                    ends=None, wl_meta=None, wl_lb=None, *,
+                    ends=None, wl_meta=None, wl_lb=None, wl_chunks=None, *,
                     interpret: bool = False):
     n, d = x.shape
     m, _ = y.shape
@@ -482,7 +494,7 @@ def _tile_sweep_jit(spec: SweepSpec, x, y, d_cut=None, x_key=None,
     with jax.enable_x64(False):
         if sparse:
             outs = _sweep_worklist(spec, args, kinds, out_shape, out_kinds,
-                                   wl_meta, wl_lb, interpret)
+                                   wl_meta, wl_lb, wl_chunks, interpret)
         else:
             outs = pl.pallas_call(
                 _make_sweep_kernel(spec, False, len(out_shape)),
@@ -517,14 +529,16 @@ def _block_spec(kind: str, block, sparse: bool):
 
 
 def _sweep_worklist(spec, args, kinds, out_shape, out_kinds, wl_meta, wl_lb,
-                    interpret):
+                    wl_chunks, interpret):
     """Block-sparse sweep: one ``pallas_call`` per worklist chunk.
 
     Chunks hold whole row tiles (``blocksparse`` packs them so), so each
     output block is finished inside one call; the outputs thread through
     the chunks as aliased operands and every call writes back only the row
     tiles it visits.  The scalar-prefetch table per call is bounded by the
-    chunk size, whatever n is.
+    chunk size, whatever n is.  ``wl_chunks``: run only the leading chunks
+    (a runtime trip count); the outputs of row tiles no run chunk visits
+    stay zero.
     """
     n_out = len(out_shape)
     n_chunks, _, C = wl_meta.shape
@@ -543,10 +557,11 @@ def _sweep_worklist(spec, args, kinds, out_shape, out_kinds, wl_meta, wl_lb,
         name="tile_sweep_worklist",
     )
     outs = tuple(jnp.zeros(s.shape, s.dtype) for s in out_shape)
-    if n_chunks == 1:
+    if wl_chunks is None and n_chunks == 1:
         return call(wl_meta[0], wl_lb[0], *args, *outs)
+    stop = n_chunks if wl_chunks is None else jnp.minimum(wl_chunks, n_chunks)
     return jax.lax.fori_loop(
-        0, n_chunks,
+        0, stop,
         lambda c, o: tuple(call(wl_meta[c], wl_lb[c], *args, *o)), outs)
 
 

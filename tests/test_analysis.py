@@ -289,8 +289,10 @@ class TestPlanTimeGate:
 # ----------------------------------------------- R1 + the distributed gate
 def test_single_device_blocksparse_layout():
     """shard_blocksparse_layout: single-partition meshes never hit the
-    miscompile (no SPMD partitioning), so traceable-worklist plans keep
-    block-sparse; dense plans and host-worklist backends never do."""
+    miscompile (no SPMD partitioning), so block-sparse plans keep
+    block-sparse: traceable-worklist backends, and the pallas backends,
+    whose shards build their worklists on the device; dense plans never
+    do."""
     from repro.distributed import dpc as ddpc
 
     mesh = jax.make_mesh((1,), ("data",))
@@ -300,7 +302,7 @@ def test_single_device_blocksparse_layout():
     assert ddpc.shard_blocksparse_layout(dense, mesh) is None
     host = planner.plan(None, ExecSpec(backend="pallas-interpret",
                                        layout="block-sparse"))
-    assert ddpc.shard_blocksparse_layout(host, mesh) is None
+    assert ddpc.shard_blocksparse_layout(host, mesh) == "block-sparse"
 
 
 _R1_SCRIPT = r"""

@@ -360,6 +360,153 @@ class TestFallbackRing:
             == build["attrs"]["kept"]
 
 
+class TestDeviceCountList:
+    """The d_cut count list a distributed shard builds on the device
+    (``cut_prefix`` then ``cut_worklist``): the host count builder's pairs
+    in its chunk format, swept with only its live chunks run."""
+
+    @staticmethod
+    def _shard(m, bn, bm, seed, lattice=False):
+        """A contiguous block of the sorted table (a shard's rows), the
+        table, and d_cut."""
+        if lattice:
+            y, d_cut = _lattice(m, 3, 1, seed)
+        else:
+            y, d_cut = _clusters(m, 3, seed)
+        y = np.asarray(y)
+        lo = m // 3
+        return y[lo:lo + m // 4], y, d_cut
+
+    @staticmethod
+    def _device(x, y, d_cut, bn, bm, chunk, nx=None, spare=1):
+        from repro.kernels import blocksparse
+
+        cp = blocksparse.cut_prefix(
+            jnp.asarray(x), len(x) if nx is None else nx, jnp.asarray(y),
+            len(y), d_cut, bn=bn, bm=bm, chunk=chunk)
+        n_chunks = blocksparse._pow2(int(cp.chunks)) * spare
+        return cp, blocksparse.cut_worklist(cp, d_cut, n_chunks=n_chunks,
+                                            chunk=chunk)
+
+    @pytest.mark.parametrize("m, bn, bm, chunk, lattice", [
+        (3000, 128, 256, 8192, False),   # one chunk
+        (3000, 8, 16, 64, False),        # many chunks of whole row tiles
+        (1000, 16, 64, 4, False),        # C = the column-tile count
+        (1200, 8, 16, 64, True),         # ties on the lattice
+    ])
+    def test_pairs_equal_host_count_builder(self, m, bn, bm, chunk, lattice):
+        x, y, d_cut = self._shard(m, bn, bm, 5, lattice)
+        cp, wl = self._device(x, y, d_cut, bn, bm, chunk, spare=2)
+        host = build_flat_worklist(x, y, d_cut, block_n=bn, block_m=bm,
+                                   count=True)
+        dm, dl = np.asarray(wl.meta), np.asarray(wl.lb)
+        hm, hl = np.asarray(host.meta), np.asarray(host.lb)
+        live = int(wl.live_chunks)
+        assert live == int(cp.chunks) and dm.shape[0] == 2 * (
+            1 << max(live - 1, 0).bit_length())
+        real = dm[:, 3] >= 0
+        hreal = hm[:, 3] >= 0
+        assert not real[live:].any(), "entries past the live chunks"
+        assert int(real.sum()) == int(hreal.sum()) == host.n_kept \
+            == int(np.sum(np.asarray(cp.kept)))
+        rows, cols = dm[:, 0][real], dm[:, 1][real]
+        # the same (row, col, in-cut) set, row tiles in order, each row
+        # tile's entries in ascending bound, its first flagged first
+        assert sorted(zip(rows, cols, dm[:, 3][real])) == sorted(
+            zip(hm[:, 0][hreal], hm[:, 1][hreal], hm[:, 3][hreal]))
+        assert np.all(np.diff(rows) >= 0)
+        for i in np.unique(rows):
+            mine = rows == i
+            assert np.all(np.diff(dl[real][mine]) >= 0)
+            firsts = np.flatnonzero(dm[:, 2][real] == 1)
+            assert np.flatnonzero(mine)[0] in firsts
+        assert int(np.sum(dm[:, 2][real])) == len(np.unique(rows))
+        np.testing.assert_allclose(np.sort(dl[real]), np.sort(hl[hreal]),
+                                   rtol=1e-6)
+        # whole row tiles per chunk; padding repeats the chunk's last pair
+        chunk_of = np.repeat(np.arange(dm.shape[0]), dm.shape[2]).reshape(
+            dm.shape[0], -1)[real]
+        for i in np.unique(rows):
+            assert len(np.unique(chunk_of[rows == i])) == 1
+        for c in range(live):
+            last = np.flatnonzero(real[c])[-1]
+            assert np.all(dm[c, :2, last + 1:] == dm[c, :2, last:last + 1])
+            assert np.all(np.isinf(dl[c, last + 1:]))
+
+    def test_masked_rows_bound_nothing(self):
+        """Rows past ``nx`` (a shard's padding) are not points: their tile
+        keeps one forced, out-of-cut pair and no other."""
+        x, y, d_cut = self._shard(2000, 16, 64, 2)
+        x = np.concatenate([x[:100], np.full((28, 3), 1e9, np.float32)])
+        _, wl = self._device(x, y, d_cut, 16, 64, 64, nx=100)
+        dm = np.asarray(wl.meta)
+        real = dm[:, 3] >= 0
+        rows, flags = dm[:, 0][real], dm[:, 3][real]
+        host = build_flat_worklist(x[:100], y, d_cut, block_n=16,
+                                   block_m=64, count=True)
+        hm = np.asarray(host.meta)
+        hreal = hm[:, 3] >= 0
+        # tiles 0-6 hold the 100 points (tile 6 four of them): as the host
+        # builds them from the points alone; tile 7 is all padding
+        assert sorted(zip(rows[rows < 7], dm[:, 1][real][rows < 7])) == \
+            sorted(zip(hm[:, 0][hreal], hm[:, 1][hreal]))
+        assert np.sum(rows == 7) == 1 and flags[rows == 7][0] == 0
+
+    @pytest.mark.parametrize("precision", ["f32", "bf16"])
+    def test_sweep_equals_host_list_sweep(self, precision):
+        """The fused sweep over the device list, spare chunks left unrun,
+        equals the sweep over the host list: counts and kept-k."""
+        from repro.kernels import ops
+
+        x, y, d_cut = self._shard(3000, 16, 64, 9)
+        x, y = jnp.asarray(x), jnp.asarray(y)
+        _, wl = self._device(x, y, d_cut, 16, 64, 64, spare=2)
+        host = build_flat_worklist(x, y, d_cut, block_n=16, block_m=64,
+                                   count=True)
+        kw = dict(block_n=16, block_m=64, precision=precision,
+                  interpret=True)
+        for a, b in zip(ops.fused_sweep(x, y, d_cut, worklist=wl, **kw),
+                        ops.fused_sweep(x, y, d_cut, worklist=host, **kw)):
+            assert _eq(a, b)
+        assert _eq(ops.fused_sweep(x, y, d_cut, worklist=wl, **kw)[0],
+                   get_backend("jnp").range_count(x, y, d_cut))
+
+    def test_ring_live_rows_run_only_their_chunks(self, monkeypatch):
+        """``ring_worklist(live_rows=r)``: the chunks that hold the first r
+        rows run, their answers equal the whole ring's, and the live count
+        covers only them."""
+        from repro.kernels import blocksparse, ops
+
+        monkeypatch.setattr(blocksparse, "WL_CHUNK", 16)   # 2 tiles a chunk
+
+        y, _ = _clusters(2000, 3, 3)
+        y = np.asarray(y)
+        rng = np.random.default_rng(4)
+        x = jnp.asarray(y[np.sort(rng.choice(len(y), 96, replace=False))])
+        y = jnp.asarray(y)
+        xk = jnp.asarray(rng.random(96).astype(np.float32))
+        yk = jnp.asarray(rng.random(len(y)).astype(np.float32))
+        full = blocksparse.ring_worklist(x, y, block_n=8, block_m=256)
+        part = blocksparse.ring_worklist(x, y, block_n=8, block_m=256,
+                                         live_rows=jnp.int32(21))
+        nbc = -(-len(y) // 256)
+        per = blocksparse.ring_rows_per_chunk(12, nbc)
+        assert per == 2 and int(part.live_chunks) == 2
+        kw = dict(block_n=8, block_m=256, interpret=True)
+        fd, fp = ops.dependent_masked(x, xk, y, yk, worklist=full, **kw)
+        pd, pp = ops.dependent_masked(x, xk, y, yk, worklist=part, **kw)
+        rows = int(part.live_chunks) * per * 8
+        assert _eq(fd[:rows], pd[:rows]) and _eq(fp[:rows], pp[:rows])
+        live = blocksparse.ring_live_pairs(part.meta, part.lb, fd,
+                                           part.live_chunks, bn=8)
+        meta, lb = np.asarray(full.meta), np.asarray(full.lb)
+        radius = np.square(np.asarray(fd)).reshape(12, 8).max(axis=1)
+        hit = (meta[:, 3] >= 0) & (lb <= radius[meta[:, 0]])
+        assert int(live) == int(hit[:int(part.live_chunks)].sum())
+        assert int(live) < int(blocksparse.ring_live_pairs(
+            full.meta, full.lb, fd, bn=8))
+
+
 class TestDegenerateWorklists:
     """All points in one grouping cell: nothing prunes, the worklist is the
     dense pair set, and the engine must behave exactly as worklist=None."""

@@ -199,3 +199,147 @@ def test_halo_strategy_runs_kernel_backend():
         assert r["rho_eq"], (key, r)
         assert r["delta_eq"], (key, r)
         assert r["parent_eq"] == 1.0, (key, r)
+
+
+# Pallas block-sparse shard phases, every worklist built on the device
+# inside shard_map (pallas-interpret on 4 fake devices): exact against the
+# single-device algorithms, the layout decision, the shards' count lists
+# against the host builder, the fallback's power-of-two pad, and a re-fit
+# in a new order compiling nothing.
+_DEVICE_WL_SCRIPT = r"""
+import warnings, json
+warnings.filterwarnings("ignore")
+import numpy as np, jax, jax.numpy as jnp
+from repro import obs
+from repro.distributed import distributed_dpc
+from repro.distributed import dpc
+from repro.core.exdpc import run_exdpc
+from repro.core.scan import run_scan
+from repro.core.grid import build_grid
+from repro.data.points import gaussian_mixture, with_noise
+from repro.engine import ExecSpec
+from repro.engine.planner import plan
+from repro.kernels import ops
+from repro.kernels.blocksparse import build_flat_worklist
+
+mesh = jax.make_mesh((4,), ("data",))
+spec = ExecSpec(backend="pallas-interpret", layout="block-sparse")
+out = {"layout": dpc.shard_blocksparse_layout(plan(None, spec), mesh),
+       "gauge": obs.get_metric("dist_bs_layout").value(
+           reason="device-worklist"),
+       "enabled": obs.get_metric("dist_bs_enabled").value(
+           reason="device-worklist")}
+
+cases = {}
+for seed, d, k in ((0, 2, 6), (1, 3, 4)):
+    pts, lab = gaussian_mixture(1200, k=k, d=d, overlap=0.03, seed=seed)
+    cases[f"mixture_{seed}_{d}"] = (with_noise(pts, lab, 0.05, seed=seed)[0],
+                                    3000.0)
+rng = np.random.default_rng(7)       # integer lattice: exact distance ties
+cases["lattice"] = ((rng.integers(0, 13, (900, 3)) * 64.0).astype(np.float32),
+                    160.0)
+cases["n_1203"] = (gaussian_mixture(1203, k=5, d=2, overlap=0.03, seed=4)[0],
+                   3000.0)
+obs.configure(level="metrics")
+for name, (pts, d_cut) in cases.items():
+    obs.reset_spans()
+    res = distributed_dpc(pts, mesh=mesh, d_cut=d_cut, exec_spec=spec)
+    (fb,) = [s["attrs"] for s in obs.spans() if s["name"] == "dist.fallback"]
+    r = {"padded": fb["padded"], "unresolved": fb["unresolved"]}
+    for ref_name, ref in (("ex", run_exdpc(pts, d_cut)),
+                          ("scan", run_scan(jnp.asarray(pts), d_cut))):
+        binf = jnp.isinf(res.delta) & jnp.isinf(ref.delta)
+        par, rpar = np.asarray(res.parent), np.asarray(ref.parent)
+        # on exact distance ties: a parent as near, and strictly denser
+        rk = np.asarray(res.rho_key)
+        p = np.asarray(pts, np.float64)
+        tie = (par >= 0) & (rpar >= 0) & (rk[np.maximum(par, 0)] > rk)
+        tie &= np.isclose(np.linalg.norm(p - p[np.maximum(par, 0)], axis=1),
+                          np.linalg.norm(p - p[np.maximum(rpar, 0)], axis=1))
+        r[ref_name] = {
+            "rho": bool(jnp.all(res.rho == ref.rho)),
+            "delta": bool(jnp.all((res.delta == ref.delta) | binf)),
+            "parent": float((par == rpar).mean()),
+            "parent_or_tie": float(((par == rpar) | tie).mean())}
+    out[name] = r
+
+# every shard's device-built count list holds the host builder's pairs
+pts, d_cut = cases["mixture_0_2"]
+grid = build_grid(jnp.asarray(pts), d_cut)
+n = grid.points.shape[0]
+pre = dpc._prog_cut_prefix(mesh, "data", n, n // 4, d_cut, 256)(grid.points)
+n_chunks = 1 << max(int(np.asarray(pre[4])[:, 0].max()) - 1, 0).bit_length()
+meta, lb, live = dpc._prog_cut_list(mesh, "data", d_cut, n_chunks)(*pre)
+meta = np.asarray(meta).reshape(4, n_chunks, 4, -1)
+same = []
+for s in range(4):
+    x = np.asarray(grid.points)[s * (n // 4):(s + 1) * (n // 4)]
+    host = build_flat_worklist(x, np.asarray(grid.points), d_cut,
+                               block_n=256, block_m=ops.DENSITY_BLOCK_M,
+                               count=True)
+    m, hm = meta[s], np.asarray(host.meta)
+    pairs = sorted(map(tuple, m[:, [0, 1, 3]].transpose(0, 2, 1).reshape(
+        -1, 3)[m[:, 3].reshape(-1) >= 0].tolist()))
+    hpairs = sorted(map(tuple, hm[:, [0, 1, 3]].transpose(0, 2, 1).reshape(
+        -1, 3)[hm[:, 3].reshape(-1) >= 0].tolist()))
+    same.append(pairs == hpairs and len(pairs) == host.n_kept)
+out["count_lists_equal"] = same
+
+# two orders of one draw: the second fit compiles nothing
+compiles = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda ev, d, **_: compiles.append(ev)
+    if ev == "/jax/core/compile/backend_compile_duration" else None)
+order = np.random.default_rng(3).permutation(len(pts))
+distributed_dpc(pts[order], mesh=mesh, d_cut=d_cut, exec_spec=spec)
+compiles.clear()
+res = distributed_dpc(pts[order[::-1]], mesh=mesh, d_cut=d_cut,
+                      exec_spec=spec)
+jax.block_until_ready(res.delta)
+out["refit_compiles"] = len(compiles)
+print("RESULT" + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def device_worklists():
+    return _run_subprocess(_DEVICE_WL_SCRIPT)
+
+
+def test_pallas_shards_keep_block_sparse(device_worklists):
+    """The pallas shard phases stay block-sparse on a 4-device mesh: the
+    layout decision, its reason, the counter and the gauge."""
+    out = device_worklists
+    assert out["layout"] == "block-sparse"
+    assert out["gauge"] == 1.0 and out["enabled"] >= 1
+
+
+@pytest.mark.parametrize("case", ["mixture_0_2", "mixture_1_3", "lattice",
+                                  "n_1203"])
+def test_pallas_device_worklists_exact(device_worklists, case):
+    """rho, delta and parent equal ``run_exdpc``'s, and ``run_scan``'s up
+    to exact distance ties (the lattice), whose parents scan picks by
+    input index and the sorted-table algorithms by sorted slot."""
+    r = device_worklists[case]
+    assert r["ex"] == {"rho": True, "delta": True, "parent": 1.0,
+                       "parent_or_tie": 1.0}, r
+    assert r["scan"]["rho"] and r["scan"]["delta"], r
+    assert r["scan"]["parent_or_tie"] == 1.0, r
+    if case != "lattice":
+        assert r["scan"]["parent"] == 1.0, r
+
+
+def test_pallas_fallback_pads_to_a_power_of_two(device_worklists):
+    for case in ("mixture_0_2", "mixture_1_3", "lattice", "n_1203"):
+        r = device_worklists[case]
+        per = r["padded"] // 4
+        assert r["padded"] == 4 * per and per & (per - 1) == 0, r
+        assert 0 < r["unresolved"] <= r["padded"], r
+
+
+def test_shard_count_lists_equal_the_host_builder(device_worklists):
+    assert device_worklists["count_lists_equal"] == [True] * 4
+
+
+def test_refit_in_a_new_order_compiles_nothing(device_worklists):
+    assert device_worklists["refit_compiles"] == 0

@@ -298,6 +298,60 @@ class TestEngineTracing:
         assert {r["name"] for r in recs if r["parent"] == build["id"]} \
             == {"worklist.fetch", "worklist.fingerprint"}
 
+    def test_mesh_fit_traces_device_worklists_and_fallback(self):
+        """A 4-device block-sparse pallas fit (a subprocess: the fake
+        devices need XLA_FLAGS before jax starts): the shards' lists under
+        ``dist.worklist``, the ring pairs computed within those listed, and
+        the host copies of a fit, one int32 per shard and read."""
+        import os
+        import subprocess
+        import sys
+
+        script = (
+            "import json, numpy as np, jax\n"
+            "from repro import obs\n"
+            "from repro.engine import DPCEngine, ExecSpec\n"
+            "rng = np.random.default_rng(0)\n"
+            "c = rng.uniform(0, 6000.0, (4, 2))\n"
+            "pts = (c[rng.integers(0, 4, 1024)]\n"
+            "       + rng.normal(0, 150.0, (1024, 2))).astype(np.float32)\n"
+            "eng = DPCEngine(d_cut=300.0, algorithm='exdpc',\n"
+            "    exec_spec=ExecSpec(backend='pallas-interpret',\n"
+            "                       layout='block-sparse'),\n"
+            "    mesh=jax.make_mesh((4,), ('data',)))\n"
+            "obs.configure(level='metrics')\n"
+            "eng.fit(pts)\n"
+            "print('RESULT' + json.dumps([(r['name'], r['path'],\n"
+            "    r['attrs']) for r in obs.spans()\n"
+            "    if r['name'].startswith('dist.')]))\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                   PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                           "src"))
+        p = subprocess.run([sys.executable, "-c", script], env=env,
+                           capture_output=True, text=True, timeout=900)
+        assert p.returncode == 0, p.stderr[-3000:]
+        recs = json.loads([ln for ln in p.stdout.splitlines()
+                           if ln.startswith("RESULT")][0][len("RESULT"):])
+        by = {}
+        for name, path, attrs in recs:
+            by.setdefault(name, []).append((path, attrs))
+        count, ring = [a for _, a in by["dist.worklist"]]
+        assert count["phase"] == "count" and ring["phase"] == "ring"
+        assert 0 < count["listed"] and count["chunks"] >= 4
+        assert count["d2h_bytes"] == 4 * 2 * 4      # (chunks, pairs) a shard
+        [(path, rho)] = by["dist.rho"]
+        assert rho["layout"] == "block-sparse"
+        assert rho["listed"] == count["listed"] <= rho["padded"]
+        [(_, delta)] = by["dist.delta"]
+        assert delta["d2h_bytes"] == 4 * 4           # unresolved, a shard
+        [(path, fb)] = by["dist.fallback"]
+        assert by["dist.worklist"][1][0] == "engine.fit/dist.fallback/" \
+                                            "dist.worklist"
+        assert 0 < fb["live"] <= fb["listed"] == ring["listed"]
+        assert 0 < fb["unresolved"] <= fb["padded"]
+        assert fb["d2h_bytes"] == 4 * 4 and fb["h2d_bytes"] == 0
+
     def test_kept_pairs_equal_the_worklist_len_gauge(self):
         obs.configure(level="metrics")
         pts = _blobs(1024, seed=3)

@@ -137,3 +137,38 @@ def test_gather_nn(one_chip):
     _compile(lambda t, k, s: ops.dependent_masked_gather(t, k, s,
                                                          interpret=False),
              _f32(one_chip, N_CHECK, D), _f32(one_chip, N_CHECK), slots)
+
+
+N_SHARD = N_FULL // 4       # one of four shards of the distributed fit
+
+
+def test_shard_count_list_build_and_sweep(one_chip):
+    """One shard of the distributed Ex-DPC fit: its d_cut count list built
+    on the device (bounds, sort, packing, then the chunked layout at a
+    bucketed length) and the fused count + kept-k sweep over it, running
+    only the live chunks, against the whole table."""
+    def shard(x, t):
+        cp = blocksparse.cut_prefix(x, x.shape[0], t, t.shape[0], 1000.0,
+                                    bn=256, bm=ops.DENSITY_BLOCK_M)
+        wl = blocksparse.cut_worklist(cp, 1000.0, n_chunks=32)
+        assert wl.meta.shape == (32, 4, WL_CHUNK)
+        return ops.fused_sweep(x, t, 1000.0, interpret=False, worklist=wl)
+
+    _compile(shard, _f32(one_chip, N_SHARD, D), _f32(one_chip, N_FULL, D))
+
+
+def test_shard_ring_build_and_sweep(one_chip):
+    """The shard's fallback: the ring of its unresolved rows (a power-of-two
+    pad of 65,536) built on the device, swept best-1 over the live chunks
+    only."""
+    def fallback(q, qk, t, tk, live):
+        wl = blocksparse.ring_worklist(q, t, block_n=256, block_m=256,
+                                       ny=t.shape[0] - 3, live_rows=live)
+        fd, fp = ops.dependent_masked(q, qk, t, tk, block_n=256,
+                                      interpret=False, worklist=wl)
+        return fd, fp, blocksparse.ring_live_pairs(
+            wl.meta, wl.lb, fd, wl.live_chunks, bn=256)
+
+    live = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    _compile(fallback, _f32(one_chip, 65536, D), _f32(one_chip, 65536),
+             _f32(one_chip, N_FULL, D), _f32(one_chip, N_FULL), live)
