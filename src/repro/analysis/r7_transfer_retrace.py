@@ -110,9 +110,9 @@ def _spelling_probes(pl: Any) -> list[tuple[str, Any, Any]]:
         bn = pl.block or ops.DENSITY_BLOCK_N
         wl = None
         if pl.sparse:
-            wl = blocksparse.build_flat_worklist(
-                x_np, x_np, D_CUT, block_n=bn, block_m=ops.DENSITY_BLOCK_M,
-                count=True, nn="topk", k=ops.FUSED_TOPK)
+            wl = blocksparse.fused_worklist(
+                x, x, D_CUT, block_n=bn, block_m=ops.DENSITY_BLOCK_M,
+                k=ops.FUSED_TOPK)
 
         def make(d: Any) -> Any:
             return jax.make_jaxpr(

@@ -59,9 +59,10 @@ def plan_targets(pl: Any) -> list:
     directly.  The pallas backends' fused path is host-orchestrated (the
     unresolved-tail fallback), so their targets are the traced *segments*
     the host stitches: the fused tile sweep + the f32 direct-diff resolve
-    epilogue (the R3 subject), and the masked-NN kernel — with host-built
-    worklists when the plan is block-sparse (built here, outside the trace,
-    exactly as the backend does).
+    epilogue (the R3 subject), and the masked-NN kernel — with the
+    backend's worklists when the plan is block-sparse (built here, outside
+    the trace, exactly as the backend does: the fused list on the device,
+    the masked-NN list on the host).
     """
     import jax
     import jax.numpy as jnp
@@ -95,9 +96,9 @@ def plan_targets(pl: Any) -> list:
     nn_bn = min(pl.block or 128, 1024)
     wl = nn_wl = None
     if pl.sparse:
-        wl = blocksparse.build_flat_worklist(
-            x_np, x_np, D_CUT, block_n=bn, block_m=ops.DENSITY_BLOCK_M,
-            count=True, nn="topk", k=ops.FUSED_TOPK)
+        wl = blocksparse.fused_worklist(
+            x, x, D_CUT, block_n=bn, block_m=ops.DENSITY_BLOCK_M,
+            k=ops.FUSED_TOPK)
         nn_wl = blocksparse.build_flat_worklist(
             x_np, x_np, None, block_n=nn_bn, block_m=256, count=False,
             nn="best1")

@@ -286,13 +286,14 @@ class DPCPlan:
             import jax.numpy as jnp
 
             d_cut = jnp.asarray(d_cut, jnp.float32)
-        with self._ctx():
-            return self.backend.rho_delta(
-                x, y, d_cut, jitter=jitter, y_sel_slots=y_sel_slots,
-                fallback_interest=fallback_interest,
-                block=self._sweep_block(block),
-                precision=self.precision if precision is _PLAN else precision,
-                layout=self._layout(layout))
+        # no worklist cache: the fused list and its fallback ring are built
+        # on the device
+        return self.backend.rho_delta(
+            x, y, d_cut, jitter=jitter, y_sel_slots=y_sel_slots,
+            fallback_interest=fallback_interest,
+            block=self._sweep_block(block),
+            precision=self.precision if precision is _PLAN else precision,
+            layout=self._layout(layout))
 
 
 # ------------------------------------------------------------- plan cache

@@ -113,11 +113,13 @@ def _sparse(layout: str | None) -> bool:
 
 
 def _require_host(name: str, *arrays) -> None:
-    """Pallas worklists are host-built (like the grid index itself)."""
+    """Pallas worklists are host-built or host-sized (like the grid index
+    itself)."""
     if any(isinstance(a, jax.core.Tracer) for a in arrays):
         raise ValueError(
-            f"{name}(layout='block-sparse') on a pallas backend builds its "
-            "tile worklist on the host; call it outside jit/shard_map, or "
+            f"{name}(layout='block-sparse') on a pallas backend builds or "
+            "sizes its tile worklist on the host; call it outside "
+            "jit/shard_map, or "
             "use the jnp backend (worklist_traceable) for traced callers")
 
 
@@ -618,16 +620,19 @@ def _fused_resolve(x, y, rho_key, col_key, topv, topi):
 class PallasBackend(KernelBackend):
     """MXU tiled kernels; ``interpret=True`` is the CPU-CI variant.
 
-    Block-sparse routes host-build a :class:`blocksparse.FlatWorklist` and
+    Block-sparse routes build a :class:`blocksparse.FlatWorklist` and
     hand it to the scalar-prefetched 1-D sweep grid: count primitives get a
     genuinely pruned grid (kept pairs only), NN primitives a ring-ordered
     list whose pairs the kernel skips against its live prune radius, and
     the fused ``rho_delta`` the union of the d_cut prefix and the static
-    k-NN ring.  Host-built means not jit-callable (``worklist_traceable``
-    stays False) — the same contract as the grid build itself.  The
-    exceptions are lists built on the device: the fused ``rho_delta``'s
-    unresolved-row fallback, whose keep-every-pair ring is built there
-    (:func:`blocksparse.ring_worklist`), and a worklist handed to
+    k-NN ring.  The fused list is built on the device
+    (:func:`blocksparse.fused_worklist`), the others on the host; either
+    way its length is read on the host, so these routes are not
+    jit-callable (``worklist_traceable`` stays False) — the same contract
+    as the grid build itself.  The exceptions are the fused
+    ``rho_delta``'s unresolved-row fallback, whose keep-every-pair ring is
+    built on the device (:func:`blocksparse.ring_worklist`), and a
+    worklist handed to
     ``count_kept`` or ``denser_nn`` (the distributed shard phases build
     theirs inside ``shard_map``), which sweep it under a trace.
     """
@@ -723,19 +728,12 @@ class PallasBackend(KernelBackend):
             # a pair whose lower bound clears k strictly-closer candidates
             # can never contribute a kept entry, so pruning it preserves
             # the kept set bit-for-bit; rows whose true denser-NN lies
-            # beyond the kept-k fall to the existing unresolved fallback
-            sel_counts = None
-            if y_sel_slots is not None:
-                # selection-gated kept-k: the static ring must count only
-                # the admissible (representative) columns per tile
-                nbc = -(-y.shape[0] // ops.DENSITY_BLOCK_M)
-                sel_counts = np.bincount(
-                    np.asarray(y_sel_slots) // ops.DENSITY_BLOCK_M,
-                    minlength=nbc)
-            wl = blocksparse.build_flat_worklist(
+            # beyond the kept-k fall to the existing unresolved fallback.
+            # Built on the device; a selection gate counts only the
+            # admissible (representative) columns per tile
+            wl = blocksparse.fused_worklist(
                 x, y, d_cut, block_n=bn, block_m=ops.DENSITY_BLOCK_M,
-                count=True, nn="topk", k=ops.FUSED_TOPK,
-                nn_col_counts=sel_counts)
+                k=ops.FUSED_TOPK, sel_slots=y_sel_slots)
         cnt, topv, topi = ops.fused_sweep(x, y, d_cut, nn_sel=nn_sel,
                                           precision=precision,
                                           block_n=bn,

@@ -33,20 +33,21 @@ owns that pruning logic once, in three forms:
   the *work* is data-proportional because ``while_loop`` trip counts are
   runtime values.
 
-* **host-built flat worklists** (the pallas backends) — the kept tile pairs
-  flatten into a scalar-prefetched (wi, wj, first-visit, in-cut) table that
-  drives a 1-D ``pallas_call`` grid (``sweep.tile_sweep``); the grid size IS
-  the kept-pair count for count primitives, while NN primitives keep a
+* **flat worklists** (the pallas backends) — the kept tile pairs flatten
+  into a scalar-prefetched (wi, wj, first-visit, in-cut) table that drives
+  a 1-D ``pallas_call`` grid (``sweep.tile_sweep``); the grid size IS the
+  kept-pair count for count primitives, while NN primitives keep a
   ring-ordered list and skip tiles in-kernel against the live accumulator
   (``best1``: the current best; ``topk``: the worst kept kth — statically
   pre-pruned by the k-nearest upper-bound radius, which is exact because a
   tile whose lower bound clears k strictly-closer candidates can never
-  contribute a kept entry).  A best-1 list that keeps every pair is a pure
-  function of the tile bounds, so the fused pallas ``rho_delta``'s
-  unresolved-row fallback builds it on the device (:func:`ring_worklist`)
-  in the same chunked form.  So do the distributed shards, for their
-  d_cut count lists as well (:func:`cut_prefix`, :func:`cut_worklist`: a
-  static, bucketed length of which only the live chunks run).
+  contribute a kept entry).  The host builds the count-only, halo and
+  best-1 lists (:func:`build_flat_worklist`).  The device builds the rest
+  in the same chunked form: the fused pallas ``rho_delta``'s count + kept-k
+  list (:func:`fused_worklist`) and the distributed shards' d_cut count
+  lists (:func:`cut_prefix`, :func:`cut_worklist`: a static, bucketed
+  length of which only the live chunks run), and the keep-every-pair
+  best-1 ring of the unresolved-row fallbacks (:func:`ring_worklist`).
 
 Every builder force-keeps at least one pair per row tile so output blocks
 are always initialized, and all-in-one-cell data degenerates to the dense
@@ -536,13 +537,16 @@ def ring_live_pairs(meta, lb, delta, live_chunks=None, *, bn: int):
 
 
 # ---------------------------------------------------------------------------
-# Device-built d_cut count lists (traceable: the shard phases of
-# ``distributed.dpc`` build them inside shard_map).  Two stages with one host
-# read between them: :func:`cut_prefix` finds each row tile's ascending-lb
-# prefix within d_cut and packs whole row tiles greedily into chunks; the
-# host takes the largest chunk count over the shards up to a power of two,
-# the list's static length; :func:`cut_worklist` then lays the entries out
-# in :class:`FlatWorklist` chunks, the host builder's format.
+# Device-built prefix lists (traceable: the shard phases of
+# ``distributed.dpc`` build their d_cut count lists inside shard_map, and the
+# one-chip fused sweep its count + kept-k list, :func:`fused_worklist`).  Two
+# stages with one host read between them: :func:`cut_prefix` finds each row
+# tile's ascending-lb prefix within its threshold and packs whole row tiles
+# greedily into chunks; the host takes the chunk count (the largest over the
+# shards) up to a power of two, the list's static length; :func:`cut_worklist`
+# then lays the entries out in :class:`FlatWorklist` chunks, the host
+# builder's format.
+@jax.tree_util.register_dataclass
 @dataclass(frozen=True)
 class CutPrefix:
     """Stage one of a device-built count list (all on the device)."""
@@ -555,19 +559,23 @@ class CutPrefix:
 
 
 def cut_prefix(x, nx, y, ny, d_cut, *, bn: int, bm: int,
-               chunk: int = WL_CHUNK) -> CutPrefix:
+               chunk: int = WL_CHUNK, r2=None) -> CutPrefix:
     """The kept pairs of ``build_flat_worklist(count=True)`` for ``x`` rows
     against ``y`` columns, on the device: per row tile the column tiles
     whose lower bound is within d_cut, in ascending bound (stable, ties by
     tile index), at least the nearest one (output blocks initialize).
-    ``nx`` / ``ny``: the leading rows of each table that are points."""
+    ``nx`` / ``ny``: the leading rows of each table that are points.
+    ``r2`` (nbr,): a squared radius per row tile that widens its threshold
+    to ``max(d_cut^2, r2)`` (the fused sweep's static k-NN ring,
+    :func:`knn_radius`); the in-cut flag stays ``lb <= d_cut^2``."""
     xp, yp = _pad_inf(x.astype(jnp.float32), bn), \
         _pad_inf(y.astype(jnp.float32), bm)
     nbr, nbc = xp.shape[0] // bn, yp.shape[0] // bm
     C = _chunk_len(nbr, nbc, chunk)
     lb = _tile_pair_lb(xp, nx, yp, ny, bn, bm)
     d2cut = jnp.asarray(d_cut, jnp.float32) ** 2
-    kept = jnp.maximum(jnp.sum(lb <= d2cut, axis=1, dtype=jnp.int32), 1)
+    thr = d2cut if r2 is None else jnp.maximum(d2cut, r2)[:, None]
+    kept = jnp.maximum(jnp.sum(lb <= thr, axis=1, dtype=jnp.int32), 1)
     # the same stable int32 key-value sort as the ring's (_ring_chunks)
     lb_sorted, order = jax.lax.sort(
         (lb, jax.lax.broadcasted_iota(jnp.int32, lb.shape, 1)),
@@ -599,18 +607,106 @@ def cut_worklist(cp: CutPrefix, d_cut, *, n_chunks: int,
     nbr, nbc = cp.lb_sorted.shape
     C = _chunk_len(nbr, nbc, chunk)
     d2cut = jnp.asarray(d_cut, jnp.float32) ** 2
-    s = jnp.arange(n_chunks * C, dtype=jnp.int32)
-    row = (jnp.searchsorted(cp.start, s, side="right") - 1).astype(jnp.int32)
-    r = s - cp.start[row]
-    real = r < cp.kept[row]
-    r = jnp.minimum(r, cp.kept[row] - 1)
-    lb = cp.lb_sorted[row, r]
-    flag = jnp.where(real, (lb <= d2cut).astype(jnp.int32), -1)
-    meta = jnp.stack([row, cp.order[row, r], (real & (r == 0)), flag])
-    meta = meta.astype(jnp.int32).reshape(4, n_chunks, C).transpose(1, 0, 2)
-    return FlatWorklist(meta=meta,
-                        lb=jnp.where(real, lb, jnp.inf).reshape(n_chunks, C),
-                        n_kept=-1, n_total=nbr * nbc,
+    # Each row tile writes a segment of 2 nbc slots at its start: its kept
+    # entries, then its last kept entry as padding.  Tiles write in order,
+    # each over the rest of the one before, so a chunk's padding (fewer
+    # slots than the next tile keeps) is its last tile's segment tail;
+    # slots no segment reaches repeat the last tile's padding.  A write per
+    # tile, not a binary search and a gather per slot: on the TPU those
+    # cost ~200 ms at the one-chip Household list's 2.1 M slots.
+    q = jnp.arange(2 * nbc, dtype=jnp.int32)
+
+    def segment(i):                     # (5, 2 nbc): meta rows, lb bits
+        kept, order = cp.kept[i], cp.order[i]
+        real = q < kept
+        last = jnp.sum(jnp.where(q[:nbc] == kept - 1, order, 0),
+                       dtype=jnp.int32)
+        lb = jnp.where(real, jnp.tile(cp.lb_sorted[i], 2), jnp.inf)
+        return jnp.stack([
+            jnp.broadcast_to(i, q.shape).astype(jnp.int32),
+            jnp.where(real, jnp.tile(order, 2), last),
+            (q == 0).astype(jnp.int32),
+            jnp.where(real, (lb <= d2cut).astype(jnp.int32), -1),
+            jax.lax.bitcast_convert_type(lb, jnp.int32)])
+
+    out = jnp.broadcast_to(segment(nbr - 1)[:, -1:],
+                           (5, n_chunks * C + 2 * nbc))
+    out = jax.lax.fori_loop(0, nbr, lambda i, o: jax.lax.dynamic_update_slice(
+        o, segment(i), (jnp.int32(0), cp.start[i])), out)[:, :n_chunks * C]
+    return FlatWorklist(
+        meta=out[:4].reshape(4, n_chunks, C).transpose(1, 0, 2),
+        lb=jax.lax.bitcast_convert_type(out[4], jnp.float32).reshape(
+            n_chunks, C),
+        n_kept=-1, n_total=nbr * nbc, live_chunks=cp.chunks)
+
+
+def knn_radius(ub, col_counts, k: int) -> jnp.ndarray:
+    """Traced :func:`_knn_radius`: per row tile the smallest upper bound
+    whose tiles hold at least ``k`` candidates, +inf where no prefix does.
+    The stable int32 key-value sort of the rings, then a masked min over
+    the sorted bounds (no gather at a sort-derived index: R1)."""
+    cnt = jnp.broadcast_to(col_counts.astype(jnp.int32), ub.shape)
+    ub_sorted, cnt_sorted = jax.lax.sort((ub, cnt), dimension=1, num_keys=1,
+                                         is_stable=True)
+    enough = jnp.cumsum(cnt_sorted, axis=1) >= k
+    return jnp.min(jnp.where(enough, ub_sorted, jnp.inf), axis=1)
+
+
+@partial(jax.jit, static_argnames=("bn", "bm", "k", "chunk"))
+def _fused_prefix(x, y, d_cut, sel_slots=None, *, bn: int, bm: int, k: int,
+                  chunk: int):
+    """Stage one of :func:`fused_worklist`: the prefix of each row tile
+    within ``max(d_cut^2, k-NN radius)``, packed, and (chunks, kept) for
+    the host.  ``sel_slots``: the columns that may enter the kept-k (the
+    radius counts only those); every column when None."""
+    n, m = x.shape[0], y.shape[0]
+    xp, yp = _pad_inf(x.astype(jnp.float32), bn), \
+        _pad_inf(y.astype(jnp.float32), bm)
+    nbc = yp.shape[0] // bm
+    if sel_slots is None:
+        counts = jnp.clip(m - bm * jnp.arange(nbc, dtype=jnp.int32), 0, bm)
+    else:
+        counts = jnp.zeros((nbc,), jnp.int32).at[sel_slots // bm].add(1)
+    ub = pair_upper_bounds(*tile_bounds(xp, n, bn), *tile_bounds(yp, m, bm))
+    cp = cut_prefix(x, n, y, m, d_cut, bn=bn, bm=bm, chunk=chunk,
+                    r2=knn_radius(ub, counts, max(k, 1)))
+    return cp, jnp.stack([cp.chunks, jnp.sum(cp.kept, dtype=jnp.int32)])
+
+
+@partial(jax.jit, static_argnames=("n_chunks", "chunk"))
+def _cut_list(cp: CutPrefix, d_cut, *, n_chunks: int, chunk: int):
+    """Stage two of :func:`fused_worklist`: the chunked (meta, lb)."""
+    wl = cut_worklist(cp, d_cut, n_chunks=n_chunks, chunk=chunk)
+    return wl.meta, wl.lb
+
+
+def fused_worklist(x, y, d_cut, *, block_n: int, block_m: int, k: int,
+                   sel_slots=None) -> FlatWorklist:
+    """The list of the fused count + kept-k sweep, built on the device:
+    the pairs, order and chunks of ``build_flat_worklist(count=True,
+    nn="topk", k=k)`` (with ``nn_col_counts`` the selected slots of each
+    column tile when ``sel_slots`` gates the kept-k).  In ascending lower
+    bound that kept set is each row tile's prefix within ``max(d_cut^2,
+    radius)``, at least one pair: :func:`cut_prefix` with the k-NN radius.
+    One host read between the stages, the 8-byte (chunks, kept); the
+    length is the chunk count up to a power of two, so a re-fit in a new
+    order reuses one compiled sweep per bucket, and only the live chunks
+    run.  Nothing is fetched, hashed or uploaded; no content cache."""
+    chunk = WL_CHUNK
+    with obs.span("worklist.build") as sp:
+        _M_BUILDS.inc()
+        with obs.span("worklist.prefix"):
+            cp, stats = _fused_prefix(x, y, d_cut, sel_slots, bn=block_n,
+                                      bm=block_m, k=k, chunk=chunk)
+        with obs.span("worklist.read", d2h_bytes=d2h_nbytes(stats)):
+            chunks, kept = (int(v) for v in np.asarray(stats))
+        n_chunks = _pow2(chunks)
+        with obs.span("worklist.layout"):
+            meta, lb = _cut_list(cp, d_cut, n_chunks=n_chunks, chunk=chunk)
+        total = cp.lb_sorted.size
+        _G_WL_LEN.set(kept)
+        sp.set(kept=kept, total=total, chunks=chunks, bucket=n_chunks)
+    return FlatWorklist(meta=meta, lb=lb, n_kept=kept, n_total=total,
                         live_chunks=cp.chunks)
 
 
@@ -672,7 +768,8 @@ _WL_CACHE_STACK: list[tuple[dict, int]] = []
 # ``_WL_BUILDS``/``_WL_CACHE_HITS`` module globals are gone); the functions
 # below are the stable read surface tests and callers use.
 _M_BUILDS = _obsm.counter(
-    "worklist_builds", "host flat-worklist builds (cache misses included)")
+    "worklist_builds",
+    "flat-worklist builds, host (cache misses included) and fused device")
 _M_CACHE_HITS = _obsm.counter(
     "worklist_cache_hits", "fingerprint hits inside a worklist_cache scope")
 _M_FP_MISSES = _obsm.counter(

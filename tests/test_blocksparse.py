@@ -507,6 +507,119 @@ class TestDeviceCountList:
             full.meta, full.lb, fd, bn=8))
 
 
+
+class TestDeviceFusedList:
+    """The fused pallas ``rho_delta``'s count + kept-k list, built on the
+    device (``fused_worklist``: ``cut_prefix`` with the k-NN radius), equals
+    the host builder's (``_kept_pairs(count=True, nn="topk", k=8)``) entry
+    for entry up to the live chunks; the chunks past them are padding."""
+
+    BN, BM, CHUNK = 16, 64, 64
+
+    @staticmethod
+    def _far_rows(y, lo):
+        """``y``'s first ``lo`` rows, then a last row tile (13 points and
+        pad rows) far from every column: none of its pairs lies within
+        d_cut."""
+        far = y[:13] + np.float32(5000.0)
+        return np.concatenate([y[:lo], far])
+
+    def _device(self, case, x, y, d_cut, sel):
+        """(meta, lb, live chunks, kept) of the device list for ``case``."""
+        from repro.kernels import blocksparse
+
+        if case in ("forced", "no_threshold"):
+            cp = blocksparse.cut_prefix(
+                jnp.asarray(x), len(x), jnp.asarray(y), len(y), d_cut,
+                bn=self.BN, bm=self.BM, chunk=self.CHUNK,
+                r2=(jnp.zeros((-(-len(x) // self.BN),), jnp.float32)
+                    if case == "forced" else None))
+            live = int(cp.chunks)
+            meta, lb = blocksparse._cut_list(
+                cp, d_cut, n_chunks=blocksparse._pow2(live), chunk=self.CHUNK)
+            return np.asarray(meta), np.asarray(lb), live, int(
+                np.sum(np.asarray(cp.kept)))
+        wl = blocksparse.fused_worklist(
+            jnp.asarray(x), jnp.asarray(y), d_cut, block_n=self.BN,
+            block_m=self.BM, k=8,
+            sel_slots=None if sel is None else jnp.asarray(sel))
+        assert wl.n_total == -(-len(x) // self.BN) * -(-len(y) // self.BM)
+        return np.asarray(wl.meta), np.asarray(wl.lb), int(
+            wl.live_chunks), wl.n_kept
+
+    @pytest.mark.parametrize("case", ["uneven", "far_row_tile", "forced",
+                                      "sel_gate", "non_pow2",
+                                      "no_threshold"])
+    def test_list_equals_host_builder(self, case, monkeypatch):
+        from repro.kernels import blocksparse
+
+        monkeypatch.setattr(blocksparse, "WL_CHUNK", self.CHUNK)
+        y, d_cut = _clusters(3000 if case == "non_pow2" else 1003, 3, 6)
+        y = np.asarray(y)
+        x, sel, counts, nn = y, None, None, "topk"
+        if case in ("far_row_tile", "forced"):
+            x = self._far_rows(y, 208)
+        if case in ("forced", "no_threshold"):
+            # the count-only list (the mesh's), long enough for whole chunks
+            nn, d_cut = None, 5 * d_cut
+        if case == "no_threshold":
+            x = y[300:550]              # a shard's rows against the table
+        if case == "sel_gate":
+            rng = np.random.default_rng(7)
+            sel = np.sort(rng.choice(len(y), 60, replace=False))
+            counts = np.bincount(sel // self.BM,
+                                 minlength=-(-len(y) // self.BM))
+        dm, dl, live, kept = self._device(case, x, y, d_cut, sel)
+        hm, hl, hkept, _ = blocksparse._kept_pairs(
+            np.asarray(x, np.float32), y, d_cut, self.BN, self.BM, True, nn,
+            8, False, counts, None, None)
+        assert len(x) % self.BN and len(y) % self.BM, "pad rows expected"
+        assert kept == hkept
+        assert dm.shape == (blocksparse._pow2(live),) + hm.shape[1:]
+        assert hm.shape[0] == live
+        np.testing.assert_array_equal(dm[:live], hm)
+        # bounds in the same places; equal up to the f32 summation order
+        # of the host and device bound arithmetic
+        assert np.array_equal(np.isinf(dl[:live]), np.isinf(hl))
+        fin = np.isfinite(hl)
+        np.testing.assert_allclose(dl[:live][fin], hl[fin], rtol=1e-6)
+        assert np.all(dm[live:, 3] == -1) and np.all(np.isinf(dl[live:]))
+        real = hm[:, 3] >= 0
+        rows, flags = hm[:, 0][real], hm[:, 3][real]
+        if case == "far_row_tile":
+            # nothing within d_cut: the k-NN ring alone keeps its pairs
+            far = rows == 13
+            assert far.sum() > 1 and not flags[far].any()
+        if case == "forced":
+            # past both thresholds: the force-kept nearest pair alone
+            assert np.sum(rows == 13) == 1 and flags[rows == 13][0] == 0
+        if case == "sel_gate":
+            # the gate widens the ring: fewer candidates per column tile
+            assert kept > self._device("uneven", x, y, d_cut, None)[3]
+        if case == "non_pow2":
+            assert live & (live - 1), f"{live} chunks: a power of two"
+
+    @pytest.mark.parametrize("gate", [False, True])
+    def test_rho_delta_equals_dense_layout(self, gate):
+        """rho, delta and parent of the block-sparse fused ``rho_delta``
+        (device-built list, then the ring fallback) equal the dense
+        layout's bit for bit."""
+        y, d_cut = _clusters(1500, 3, 8)
+        x, kw = y, {}
+        if gate:
+            rng = np.random.default_rng(5)
+            slots = jnp.asarray(np.sort(rng.choice(len(y), 700,
+                                                   replace=False)))
+            x, kw["y_sel_slots"] = y[slots], slots
+        be = get_backend("pallas-interpret")
+        jit_ = density_jitter(len(x))
+        dense = be.rho_delta(x, y, d_cut, jitter=jit_, **kw)
+        bs = be.rho_delta(x, y, d_cut, jitter=jit_, layout="block-sparse",
+                          **kw)
+        for a, b, name in zip(dense, bs, ("rho", "rho_key", "delta",
+                                          "parent")):
+            assert _eq(a, b), f"{name} differs under block-sparse"
+
 class TestDegenerateWorklists:
     """All points in one grouping cell: nothing prunes, the worklist is the
     dense pair set, and the engine must behave exactly as worklist=None."""

@@ -210,21 +210,55 @@ class TestPlanReuse:
         assert as_plan(spec).spec == spec
 
     def test_host_worklist_reuse(self):
-        """pallas block-sparse: the second same-data fit serves every host
-        worklist from the plan's content-addressed cache."""
+        """pallas block-sparse: a plan primitive that builds its worklist
+        on the host (the best-1 ``denser_nn``) serves a same-data call
+        from the plan's content-addressed cache."""
+        pts = jnp.asarray(_mix(160, seed=13))
+        key = jnp.arange(160, dtype=jnp.float32)
+        pl = as_plan(ExecSpec(backend="pallas-interpret",
+                              layout="block-sparse"), pts)
+        pl.denser_nn(pts, key, pts, key)
+        builds_after_first = blocksparse.worklist_build_count()
+        assert builds_after_first > 0
+        pl.denser_nn(pts, key, pts, key)            # same data
+        assert blocksparse.worklist_build_count() == builds_after_first, \
+            "same-data call rebuilt a host worklist"
+        # different data with the same shape must rebuild (fingerprinted)
+        other = jnp.asarray(_mix(160, seed=14))
+        pl.denser_nn(other, key, other, key)
+        assert blocksparse.worklist_build_count() > builds_after_first
+
+    def test_fused_worklist_fetches_nothing(self, monkeypatch):
+        """pallas block-sparse: the fused rho/delta list is built on the
+        device at every fit, same data included.  No host builder runs, and
+        its one host copy is the 8-byte (chunks, kept) read."""
+        from repro import obs
+
         pts = _mix(160, seed=13)
         eng = DPCEngine(d_cut=900.0, algorithm="scan",
                         exec_spec=ExecSpec(backend="pallas-interpret",
                                            layout="block-sparse"))
-        eng.fit(pts)
-        builds_after_first = blocksparse.worklist_build_count()
-        assert builds_after_first > 0
-        eng.fit(pts)                                # same data
-        assert blocksparse.worklist_build_count() == builds_after_first, \
-            "same-data re-fit rebuilt a host worklist"
-        # different data with the same shape must rebuild (fingerprinted)
-        eng.fit(_mix(160, seed=14))
-        assert blocksparse.worklist_build_count() > builds_after_first
+        eng.fit(pts)                    # plans (and analyzes) the spec
+
+        def host_build(*a, **kw):
+            raise AssertionError("the fused path built a host worklist")
+        monkeypatch.setattr(blocksparse, "build_flat_worklist", host_build)
+        obs.configure(level="metrics")
+        try:
+            for _ in range(2):          # the same data twice
+                obs.reset_spans()
+                before = blocksparse.worklist_build_count()
+                eng.fit(pts)
+                assert blocksparse.worklist_build_count() == before + 1
+                recs = obs.spans()
+                (build,) = [r for r in recs if r["name"] == "worklist.build"]
+                sub = [r.get("attrs", {}) for r in recs
+                       if r["parent"] == build["id"]]
+                assert sum(a.get("d2h_bytes", 0) for a in sub) == 8
+                assert not any("h2d_bytes" in a for a in sub)
+        finally:
+            obs.configure(level="off")
+            obs.reset_spans()
 
     def test_worklist_fingerprint_source_dtype_miss(self):
         """Cache identity is the caller's data, not its f32 shadow: the

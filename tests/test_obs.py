@@ -260,19 +260,19 @@ class TestEngineTracing:
         build = by["worklist.build"]
         children = {r["name"]: r for r in recs
                     if r["parent"] == build["id"]}
-        assert set(children) == {"worklist.fetch", "worklist.fingerprint",
-                                 "worklist.bounds", "worklist.upload"}
+        # built on the device: the prefix stage, the one host read of
+        # (chunks, kept), the chunked layout; nothing fetched or uploaded
+        assert set(children) == {"worklist.prefix", "worklist.read",
+                                 "worklist.layout"}
         assert build["path"] == ("engine.fit/approxdpc.rho_delta/"
                                  "worklist.build")
         a = build["attrs"]
-        assert a["hit"] is False and a["chunks"] >= 1
+        assert "hit" not in a and a["chunks"] >= 1
+        assert a["bucket"] == 1 << (a["chunks"] - 1).bit_length()
         assert 0 < a["kept"] <= a["total"]
-        # x and y are one device array: fetched once
-        assert children["worklist.fetch"]["attrs"] == {
-            "d2h_bytes": pts.nbytes}
-        # (chunks, 4, C) int32 pairs and (chunks, C) f32 bounds
-        up = children["worklist.upload"]["attrs"]["h2d_bytes"]
-        assert up % (20 * a["chunks"]) == 0 and up >= 20 * a["kept"]
+        assert children["worklist.read"]["attrs"] == {"d2h_bytes": 8}
+        assert not any({"h2d_bytes", "d2h_bytes"} & set(c.get("attrs", {}))
+                       for n, c in children.items() if n != "worklist.read")
         fb = by["rho_delta.fallback"]
         assert fb["path"] == "engine.fit/approxdpc.rho_delta/" \
                              "rho_delta.fallback"
@@ -289,14 +289,15 @@ class TestEngineTracing:
         assert by["approxdpc.rules"]["attrs"]["d2h_bytes"] == n
         assert by["approxdpc.rules"]["attrs"]["h2d_bytes"] > 0
 
-        # the same points again: the worklist cache answers
+        # the same points again: no content cache, the device builds the
+        # same list anew
         obs.reset_spans()
         eng.fit(pts)
         recs = obs.spans()
-        (build,) = [r for r in recs if r["name"] == "worklist.build"]
-        assert build["attrs"]["hit"] is True
-        assert {r["name"] for r in recs if r["parent"] == build["id"]} \
-            == {"worklist.fetch", "worklist.fingerprint"}
+        (again,) = [r for r in recs if r["name"] == "worklist.build"]
+        assert again["attrs"] == a
+        assert {r["name"] for r in recs if r["parent"] == again["id"]} \
+            == set(children)
 
     def test_mesh_fit_traces_device_worklists_and_fallback(self):
         """A 4-device block-sparse pallas fit (a subprocess: the fake

@@ -172,3 +172,36 @@ def test_shard_ring_build_and_sweep(one_chip):
     live = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     _compile(fallback, _f32(one_chip, 65536, D), _f32(one_chip, 65536),
              _f32(one_chip, N_FULL, D), _f32(one_chip, N_FULL), live)
+
+
+N_CELL = 512_320            # the one-chip Household cell (household.fit)
+
+
+def test_fused_list_build_and_sweep(one_chip):
+    """The one-chip fused rho/delta list built on the device at the cell's
+    shapes: stage one (tile bounds, k-NN radius, sorts, packing), stage two
+    (the chunked layout at a bucketed length), then the fused count +
+    kept-k sweep over the live chunks only."""
+    bn, bm = ops.DENSITY_BLOCK_N, ops.DENSITY_BLOCK_M
+    nbr, nbc = -(-N_CELL // bn), -(-N_CELL // bm)
+    pts, d_cut = _f32(one_chip, N_CELL, D), _f32(one_chip)
+    blocksparse._fused_prefix.lower(
+        pts, pts, d_cut, bn=bn, bm=bm, k=ops.FUSED_TOPK,
+        chunk=WL_CHUNK).compile()
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    cp = blocksparse.CutPrefix(lb_sorted=_f32(one_chip, nbr, nbc),
+                               order=i32(nbr, nbc), kept=i32(nbr),
+                               start=i32(nbr), chunks=i32())
+    blocksparse._cut_list.lower(cp, d_cut, n_chunks=256,
+                                chunk=WL_CHUNK).compile()
+
+    def sweep(x, meta, lb, live):
+        wl = FlatWorklist(meta=meta, lb=lb, n_kept=-1, n_total=nbr * nbc,
+                          live_chunks=live)
+        return ops.fused_sweep(x, x, 1000.0, interpret=False, worklist=wl)
+
+    _compile(sweep, pts, i32(256, 4, WL_CHUNK),
+             _f32(one_chip, 256, WL_CHUNK), i32())
