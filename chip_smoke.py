@@ -120,7 +120,7 @@ def fit_phase(pts, sz, spec_kw, want_backend, clock):
           f"resolved backend {eng.plan.backend_name!r}, want "
           f"{want_backend!r}")
     wl = eng.plan.telemetry()["worklists"]
-    log(f"worklists: {wl.get('cached', [])}")
+    log(f"worklists: {wl['strategy']}")
     n_cl = int(eng.clustering.num_clusters)
     log(f"fit n={pts.shape[0]}: first call {first:.3f}s (backend compile "
         f"{compile_s:.3f}s), warm call {warm:.3f}s, clusters {n_cl}")

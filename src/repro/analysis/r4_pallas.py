@@ -17,10 +17,10 @@ with ``block_shape`` / ``array_aval`` / SMEM-typed
   to live there; a worklist table accidentally routed through VMEM/HBM
   block mappings would compile on the interpreter and fail (or crawl) on
   Mosaic.
-* **static grid** — host-built worklists size the launch grid
-  (``grid = (n_kept,)``); ``num_dynamic_grid_bounds > 0`` means a traced
-  value reached the grid, i.e. a worklist was constructed under a tracer
-  (the ``_require_host`` contract, enforced here statically too).
+* **static grid** — a worklist's chunk length sizes the launch grid
+  (``grid = (C,)``); ``num_dynamic_grid_bounds > 0`` means a traced value
+  reached the grid, i.e. a worklist was sized under a tracer (the
+  ``_require_host`` contract, enforced here statically too).
 """
 from __future__ import annotations
 
@@ -52,8 +52,8 @@ def _check_pallas_eqn(target: str, site: Any) -> list[Finding]:
 
     if int(getattr(gm, "num_dynamic_grid_bounds", 0) or 0) > 0:
         finding("dynamic grid bounds: a traced value sizes the launch "
-                "grid, i.e. a host-built worklist was constructed under "
-                "a tracer (_require_host contract)")
+                "grid, i.e. a worklist was sized under a tracer "
+                "(_require_host contract)")
 
     grid = tuple(getattr(gm, "grid", ()) or ())
     for g in grid:

@@ -16,7 +16,7 @@ any drift:
    exactly where the documented validity table says (bf16 needs an
    ``mxu_dense`` backend; everything else is legal).
 3. **planner dispatch totality** — every valid plan must land on exactly
-   the documented ``worklist_strategy`` (dense / traced / host from the
+   the documented ``worklist_strategy`` (dense / traced / device from the
    backend's ``worklist_traceable`` flag) and ``grid_sort`` contract.
 4. **parity-test coverage** — every axis value literal must appear in
    ``tests/`` at least once (the parametrized parity suites), so a new
@@ -155,7 +155,7 @@ class SpecCoverageRule(Rule):
                     if not plan_ok:
                         continue
                     want = "dense" if layout != "block-sparse" else (
-                        "traced" if be.worklist_traceable else "host")
+                        "traced" if be.worklist_traceable else "device")
                     if pl.worklist_strategy != want:
                         finding(f"planner dispatch drift for {combo}: "
                                 f"worklist_strategy="

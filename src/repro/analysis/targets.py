@@ -61,16 +61,14 @@ def plan_targets(pl: Any) -> list:
     the host stitches: the fused tile sweep + the f32 direct-diff resolve
     epilogue (the R3 subject), and the masked-NN kernel — with the
     backend's worklists when the plan is block-sparse (built here, outside
-    the trace, exactly as the backend does: the fused list on the device,
-    the masked-NN list on the host).
+    the trace, on the device as the backend builds them).
     """
     import jax
     import jax.numpy as jnp
 
     from repro.core.dpc_types import density_jitter
 
-    x_np = canonical_points()
-    x = jnp.asarray(x_np)
+    x = jnp.asarray(canonical_points())
     jitter = density_jitter(N_POINTS)
     rk = jnp.arange(N_POINTS, dtype=jnp.float32)   # all-distinct NN keys
     be = pl.backend
@@ -99,9 +97,8 @@ def plan_targets(pl: Any) -> list:
         wl = blocksparse.fused_worklist(
             x, x, D_CUT, block_n=bn, block_m=ops.DENSITY_BLOCK_M,
             k=ops.FUSED_TOPK)
-        nn_wl = blocksparse.build_flat_worklist(
-            x_np, x_np, None, block_n=nn_bn, block_m=256, count=False,
-            nn="best1")
+        nn_wl = blocksparse.ring_worklist(x, x, block_n=nn_bn,
+                                          block_m=blocksparse.BS_BLOCK_M)
 
     def fused(a: Any, b: Any, jit_: Any) -> Any:
         cnt, topv, topi = ops.fused_sweep(

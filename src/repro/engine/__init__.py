@@ -8,8 +8,7 @@ The public surface of the unified execution model:
   DPC-KV ``compress_kv``).
 * :func:`plan` / :class:`DPCPlan` — the planner: resolve a spec once
   (backend instance, worklist strategy, grid sort, pad shapes) and reuse
-  the plan — with its jit traces and host-built pallas worklists — across
-  repeated calls.
+  the plan — with its jit traces — across repeated calls.
 * :class:`DPCEngine` — the facade: ``fit(points)`` (batch or distributed
   when given a mesh), ``partial_fit(batch)`` (sliding-window streaming),
   ``predict(points)`` (read-only nearest-label queries with the serve

@@ -20,8 +20,7 @@ The subsystems share one lifecycle:
 
 Execution is one :class:`ExecSpec`, resolved once per input shape by the
 planner and reused: repeated ``fit`` calls on same-shaped inputs get the
-same :class:`DPCPlan` object back (same jit traces; host-built pallas
-worklists re-served from the plan's content-addressed cache).
+same :class:`DPCPlan` object back (same jit traces).
 """
 from __future__ import annotations
 

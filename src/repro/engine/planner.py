@@ -3,25 +3,19 @@
 ``plan(points_spec, exec_spec) -> DPCPlan`` resolves the execution axes a
 single time — the :class:`~repro.kernels.backend.KernelBackend` instance,
 the layout (and with it the worklist strategy: none for dense, jit-built
-for the jnp ring worklists, host-built scalar-prefetch tables for pallas),
+for the jnp ring worklists, device-built scalar-prefetch tables for pallas),
 the grid-sort requirement, the precision, and the sweep block size — and
 hands back a plan object whose primitive wrappers inject all of that into
 every kernel call.  Drivers stop re-threading ``backend=/layout=/block=``
 kwargs; they take a plan (or an ExecSpec, via :func:`as_plan`) and call
 ``plan.rho_delta(...)``.
 
-Two caches make repeated ``fit`` / ``partial_fit`` calls cheap:
-
-* the **plan cache**: ``plan()`` memoizes on ``(PointsSpec, ExecSpec)``
-  (both frozen/hashable), so a re-fit on same-shaped input gets the *same*
-  plan object back — and with it every jit trace keyed off the plan's
-  resolved static arguments (no re-trace; asserted in
-  tests/test_engine.py).
-* the **worklist cache**: each plan owns a small LRU of host-built pallas
-  worklists (``kernels.blocksparse.FlatWorklist``), keyed by a content
-  fingerprint of the inputs.  A re-fit on the same data skips the host
-  worklist rebuild entirely (the jnp worklists are jit-built and already
-  ride the jax trace cache).
+The **plan cache** makes repeated ``fit`` / ``partial_fit`` calls cheap:
+``plan()`` memoizes on ``(PointsSpec, ExecSpec)`` (both frozen/hashable),
+so a re-fit on same-shaped input gets the *same* plan object back — and
+with it every jit trace keyed off the plan's resolved static arguments (no
+re-trace; asserted in tests/test_engine.py).  Worklists are built on the
+device at every call (``kernels.blocksparse``), so no plan caches one.
 
 Block-size resolution (the one documented default): ``spec.block`` when
 set; otherwise each backend's native tile default (jnp: 512, pallas: the
@@ -48,7 +42,6 @@ __all__ = ["PointsSpec", "DPCPlan", "plan", "as_plan", "plan_cache_info",
            "plan_cache_clear"]
 
 _PLAN = object()          # sentinel: "use the plan's resolved value"
-_WL_CACHE_MAX = 8         # host worklists kept per plan (LRU)
 _PLAN_CACHE_MAX = 64
 
 
@@ -66,12 +59,12 @@ class PointsSpec:
 
 class DPCPlan:
     """A resolved execution plan: backend + layout + precision + block,
-    with primitive wrappers that inject them (and the worklist cache) into
-    every kernel call.
+    with primitive wrappers that inject them into every kernel call.
 
     ``worklist_strategy``: ``"dense"`` (no worklists), ``"traced"``
     (jit-built jnp ring worklists — legal inside jit/shard_map), or
-    ``"host"`` (host-built pallas scalar-prefetch tables, cached per plan).
+    ``"device"`` (device-built pallas scalar-prefetch tables, count lists
+    sized on the host).
     ``grid_sort`` tells drivers the points must be laid out grid-sorted
     (block-sparse pruning quality depends on it).  ``resolved_block`` is
     the count-sweep row-tile size the wrappers actually pass.
@@ -110,8 +103,7 @@ class DPCPlan:
         elif self.backend.worklist_traceable:
             self.worklist_strategy = "traced"
         else:
-            self.worklist_strategy = "host"
-        self._wl: OrderedDict = OrderedDict()   # host-worklist LRU
+            self.worklist_strategy = "device"
         self._cost: dict | None = None          # hlo_cost estimate (lazy)
         self._memory: dict | None = None        # R9 memory block (lazy)
 
@@ -131,22 +123,18 @@ class DPCPlan:
 
     __repr__ = describe
 
-    def worklist_cache_info(self) -> dict:
-        return {"entries": len(self._wl), "max": _WL_CACHE_MAX}
-
     # --------------------------------------------------- kernel telemetry
     def telemetry(self, include_cost: bool = False) -> dict:
         """What this plan resolved to and what its kernels will touch.
 
-        Static fields (resolved axes, grid-sort, pad waste) are free.  The
-        ``worklists`` block reflects the plan's live host-worklist cache —
-        kept-pair counts and pruned-tile fractions for each cached build.
-        ``include_cost=True`` adds a ``launch/hlo_cost`` flop/byte estimate
-        from compiling the canonical fused sweep at the plan's shape; the
-        estimate is computed once per plan and cached (compiles are not
-        free), and host-worklist plans are costed on the dense formulation
-        — an upper bound — because flat worklists cannot be built during an
-        abstract trace.
+        Static fields (resolved axes, grid-sort, pad waste, the
+        ``worklists`` strategy) are free.  ``include_cost=True`` adds a
+        ``launch/hlo_cost`` flop/byte estimate from compiling the canonical
+        fused sweep at the plan's shape; the estimate is computed once per
+        plan and cached (compiles are not free), and pallas block-sparse
+        plans are costed on the dense formulation — an upper bound —
+        because a flat worklist's length cannot be read during an abstract
+        trace.
 
         The ``memory`` block carries the R9 estimates the plan was gated
         against: per-``pallas_call`` VMEM/SMEM (block shapes
@@ -166,7 +154,7 @@ class DPCPlan:
             "shape": None if self.pspec is None
             else {"n": self.pspec.n, "d": self.pspec.d},
             "pad": self._pad_telemetry(),
-            "worklists": self._worklist_telemetry(),
+            "worklists": {"strategy": self.worklist_strategy},
             "memory": self._memory_estimate(),
         }
         if include_cost:
@@ -184,17 +172,6 @@ class DPCPlan:
         padded = -(-n // row_block) * row_block
         return {"row_block": row_block, "n": n, "padded_n": padded,
                 "pad_waste_frac": round(1.0 - n / padded, 6)}
-
-    def _worklist_telemetry(self) -> dict:
-        out: dict = {"strategy": self.worklist_strategy,
-                     "cache_entries": len(self._wl),
-                     "cache_max": _WL_CACHE_MAX}
-        if self._wl:
-            out["cached"] = [
-                {"n_kept": w.n_kept, "n_total": w.n_total,
-                 "pruned_frac": round(w.pruned_frac, 6)}
-                for w in self._wl.values()]
-        return out
 
     def _memory_estimate(self) -> dict:
         if self._memory is None:
@@ -248,13 +225,6 @@ class DPCPlan:
     def _block(self, override):
         return self.block if override is _PLAN else override
 
-    def _ctx(self):
-        """Activate this plan's host-worklist cache for the wrapped call."""
-        if self.worklist_strategy == "host":
-            return blocksparse.worklist_cache(self._wl, max_entries=_WL_CACHE_MAX)
-        import contextlib
-        return contextlib.nullcontext()
-
     # -------------------------------------------------- primitive wrappers
     # Thin forms of the two DRIVER-facing primitives with the plan's
     # resolved layout / precision / block injected (each overridable per
@@ -270,10 +240,9 @@ class DPCPlan:
 
     def denser_nn(self, x, x_key, y, y_key, *, block=_PLAN, layout=_PLAN):
         faultinject.fire("kernel.dispatch")
-        with self._ctx():
-            return self.backend.denser_nn(
-                x, x_key, y, y_key, block=self._block(block),
-                layout=self._layout(layout))
+        return self.backend.denser_nn(x, x_key, y, y_key,
+                                      block=self._block(block),
+                                      layout=self._layout(layout))
 
     def rho_delta(self, x, y, d_cut, *, jitter=None, y_sel_slots=None,
                   fallback_interest=None, block=_PLAN, layout=_PLAN,
@@ -286,8 +255,6 @@ class DPCPlan:
             import jax.numpy as jnp
 
             d_cut = jnp.asarray(d_cut, jnp.float32)
-        # no worklist cache: the fused list and its fallback ring are built
-        # on the device
         return self.backend.rho_delta(
             x, y, d_cut, jitter=jitter, y_sel_slots=y_sel_slots,
             fallback_interest=fallback_interest,
@@ -342,8 +309,8 @@ def _plan_check(pl: DPCPlan) -> None:
     if res is None:
         from repro import analysis
 
-        # tracing the canonical targets may host-build throwaway worklists;
-        # suspend the worklist metrics so plan() stays neutral w.r.t. the
+        # tracing the canonical targets builds throwaway worklists; suspend
+        # the worklist metrics so plan() stays neutral w.r.t. the
         # instrumentation tests assert on (worklist_build_count & co.)
         with blocksparse.suspend_counters():
             res = tuple(analysis.analyze_plan(pl))

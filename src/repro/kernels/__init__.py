@@ -4,7 +4,7 @@ DPC hot path onto them."""
 from .backend import (KernelBackend, available_backends,
                       default_backend_name, get_backend, register_backend,
                       rho_delta_sequential)
-from .blocksparse import FlatWorklist, build_flat_worklist, worklist_stats
+from .blocksparse import FlatWorklist, worklist_stats
 from .ops import (dependent_masked, dependent_masked_gather, dependent_prefix,
                   fused_sweep, halo_density, halo_dependent, local_density,
                   local_density_delta, local_density_xy)
@@ -15,5 +15,4 @@ __all__ = ["local_density", "local_density_xy", "local_density_delta",
            "fused_sweep", "halo_density", "halo_dependent", "KernelBackend",
            "get_backend", "register_backend", "available_backends",
            "default_backend_name", "rho_delta_sequential", "SweepSpec",
-           "tile_sweep", "FlatWorklist", "build_flat_worklist",
-           "worklist_stats"]
+           "tile_sweep", "FlatWorklist", "worklist_stats"]

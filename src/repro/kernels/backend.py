@@ -44,10 +44,11 @@ that can matter are visited: count accumulators keep pairs with min
 inter-AABB distance <= d_cut, NN accumulators walk an ascending-bound ring
 with a progressively-shrinking prune radius.  The jnp worklists are
 jit-built (``worklist_traceable``: block-sparse stays legal inside
-jit/shard_map and ``rho_delta`` stays ``fused_traceable``); the pallas
-worklists are host-built, like the grid itself, and drive a scalar-
-prefetched 1-D kernel grid — or built on the device by a caller that hands
-them in (``count_kept``, ``denser_nn(worklist=)``: the distributed shards).
+jit/shard_map and ``rho_delta`` stays ``fused_traceable``).  The pallas
+worklists are built on the device and drive a scalar-prefetched 1-D kernel
+grid; a count list is sized on the host, by one 8-byte read of its length,
+like the grid itself.  A caller may also build one inside a trace and hand
+it in (``count_kept``, ``denser_nn(worklist=)``: the distributed shards).
 f32 results are bit-identical to the dense layout of the same backend — pruning bounds carry conservative slack
 covering f32 rounding of the bound arithmetic, NN tie-breaks are
 explicitly lexicographic — which is property-tested on tie-heavy lattice
@@ -113,13 +114,12 @@ def _sparse(layout: str | None) -> bool:
 
 
 def _require_host(name: str, *arrays) -> None:
-    """Pallas worklists are host-built or host-sized (like the grid index
+    """Pallas count lists are sized on the host (like the grid index
     itself)."""
     if any(isinstance(a, jax.core.Tracer) for a in arrays):
         raise ValueError(
-            f"{name}(layout='block-sparse') on a pallas backend builds or "
-            "sizes its tile worklist on the host; call it outside "
-            "jit/shard_map, or "
+            f"{name}(layout='block-sparse') on a pallas backend sizes its "
+            "tile worklist on the host; call it outside jit/shard_map, or "
             "use the jnp backend (worklist_traceable) for traced callers")
 
 
@@ -162,7 +162,7 @@ class KernelBackend:
     jit/vmap (no host-orchestrated fallback step).  ``worklist_traceable``
     marks a backend whose block-sparse worklists are jit-built — its
     ``layout="block-sparse"`` primitives stay legal inside jit/shard_map
-    (the pallas worklists are host-built, like the grid index).
+    (the pallas count lists are sized on the host, like the grid index).
     """
 
     name: str = "abstract"
@@ -620,21 +620,21 @@ def _fused_resolve(x, y, rho_key, col_key, topv, topi):
 class PallasBackend(KernelBackend):
     """MXU tiled kernels; ``interpret=True`` is the CPU-CI variant.
 
-    Block-sparse routes build a :class:`blocksparse.FlatWorklist` and
-    hand it to the scalar-prefetched 1-D sweep grid: count primitives get a
-    genuinely pruned grid (kept pairs only), NN primitives a ring-ordered
-    list whose pairs the kernel skips against its live prune radius, and
-    the fused ``rho_delta`` the union of the d_cut prefix and the static
-    k-NN ring.  The fused list is built on the device
-    (:func:`blocksparse.fused_worklist`), the others on the host; either
-    way its length is read on the host, so these routes are not
-    jit-callable (``worklist_traceable`` stays False) — the same contract
-    as the grid build itself.  The exceptions are the fused
-    ``rho_delta``'s unresolved-row fallback, whose keep-every-pair ring is
-    built on the device (:func:`blocksparse.ring_worklist`), and a
-    worklist handed to
-    ``count_kept`` or ``denser_nn`` (the distributed shard phases build
-    theirs inside ``shard_map``), which sweep it under a trace.
+    Block-sparse routes build a :class:`blocksparse.FlatWorklist` on the
+    device and hand it to the scalar-prefetched 1-D sweep grid: count
+    primitives get a genuinely pruned grid (kept pairs only), NN
+    primitives a ring-ordered list whose pairs the kernel skips against
+    its live prune radius, and the fused ``rho_delta`` the union of the
+    d_cut prefix and the static k-NN ring.  A count list
+    (:func:`blocksparse.fused_worklist`) is sized on the host, so the
+    count routes are not jit-callable (``worklist_traceable`` stays False)
+    — the same contract as the grid build itself.  A best-1 ring
+    (:func:`blocksparse.ring_worklist`) depends only on shapes, so
+    ``denser_nn`` and the fused ``rho_delta``'s unresolved-row fallback
+    build theirs under a trace too, as the distributed shard phases build
+    the lists they hand to ``count_kept`` and ``denser_nn`` inside
+    ``shard_map``.  The halo primitives sweep dense span-masked tiles:
+    the spans already prune them.
     """
 
     mxu_dense = True
@@ -648,9 +648,8 @@ class PallasBackend(KernelBackend):
         wl = None
         if _sparse(layout):
             _require_host("range_count", x, y)
-            wl = blocksparse.build_flat_worklist(
-                x, y, d_cut, block_n=bn, block_m=ops.DENSITY_BLOCK_M,
-                count=True)
+            wl = blocksparse.fused_worklist(x, y, d_cut, block_n=bn,
+                                            block_m=ops.DENSITY_BLOCK_M)
         return ops.local_density_xy(x, y, d_cut, block_n=bn,
                                     interpret=self.interpret, worklist=wl)
 
@@ -660,9 +659,8 @@ class PallasBackend(KernelBackend):
         wl = None
         if _sparse(layout):
             _require_host("range_count_delta", x, batch)
-            wl = blocksparse.build_flat_worklist(
-                x, batch, d_cut, block_n=bn, block_m=ops.DENSITY_BLOCK_M,
-                count=True)
+            wl = blocksparse.fused_worklist(x, batch, d_cut, block_n=bn,
+                                            block_m=ops.DENSITY_BLOCK_M)
         return ops.local_density_delta(x, batch, signs, d_cut, block_n=bn,
                                        interpret=self.interpret, worklist=wl)
 
@@ -670,15 +668,15 @@ class PallasBackend(KernelBackend):
                   worklist=None):
         """``worklist``: a best-1 ring built on the device at the sweep's
         tiles (``block`` rows, ``blocksparse.BS_BLOCK_M`` columns), swept
-        as given — traceable; otherwise ``layout`` decides."""
-        bn = min(block or 128, 1024)
+        as given; otherwise ``layout`` decides, and the block-sparse layout
+        builds that ring here.  Traceable either way."""
+        bn, bm = min(block or 128, 1024), blocksparse.BS_BLOCK_M
         wl = worklist
         if wl is None and _sparse(layout):
-            _require_host("denser_nn", x, y)
-            wl = blocksparse.build_flat_worklist(
-                x, y, None, block_n=bn, block_m=256, count=False, nn="best1")
+            wl = blocksparse.ring_worklist(x, y, block_n=bn, block_m=bm)
         return ops.dependent_masked(x, x_key, y, y_key, block_n=bn,
-                                    interpret=self.interpret, worklist=wl)
+                                    block_m=bm, interpret=self.interpret,
+                                    worklist=wl)
 
     def count_kept(self, x, y, d_cut, *, worklist, block=None,
                    precision=None):
@@ -794,33 +792,19 @@ class PallasBackend(KernelBackend):
 
     def range_count_halo(self, x, window, starts, ends, d_cut, *,
                          span_cap, block=None, layout=None):
-        del span_cap  # dense span-masked tiles: no gather width needed
-        bn = block or ops.DENSITY_BLOCK_N
-        wl = None
-        if _sparse(layout):
-            _require_host("range_count_halo", x, window)
-            wl = blocksparse.build_flat_worklist(
-                x, window, d_cut, block_n=bn, block_m=ops.DENSITY_BLOCK_M,
-                count=True, starts=starts, ends=ends)
-        return ops.halo_density(x, window, starts, ends, d_cut, block_n=bn,
-                                interpret=self.interpret, worklist=wl)
+        # dense span-masked tiles: no gather width needed, and the spans
+        # already prune the candidates
+        del span_cap, layout
+        return ops.halo_density(x, window, starts, ends, d_cut,
+                                block_n=block or ops.DENSITY_BLOCK_N,
+                                interpret=self.interpret)
 
     def denser_nn_halo(self, x, x_key, window, w_key, starts, ends, d_cut, *,
                        span_cap, block=None, layout=None):
-        del span_cap
-        bn = min(block or 128, 1024)
-        wl = None
-        if _sparse(layout):
-            _require_host("denser_nn_halo", x, window)
-            # halo NN is d_cut-bounded (stencil semantics), so the best-1
-            # ring prunes statically by lb <= d_cut^2 AND span reach
-            wl = blocksparse.build_flat_worklist(
-                x, window, d_cut, block_n=bn, block_m=ops.DENSITY_BLOCK_M,
-                count=False, nn="best1", nn_dcut=True,
-                starts=starts, ends=ends)
+        del span_cap, layout
         return ops.halo_dependent(x, x_key, window, w_key, starts, ends,
-                                  d_cut, block_n=bn,
-                                  interpret=self.interpret, worklist=wl)
+                                  d_cut, block_n=min(block or 128, 1024),
+                                  interpret=self.interpret)
 
     def denser_nn_update(self, points, rho_key, q_slots, *, block=None,
                          layout=None):

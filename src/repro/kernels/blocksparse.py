@@ -41,13 +41,14 @@ owns that pruning logic once, in three forms:
   (``best1``: the current best; ``topk``: the worst kept kth — statically
   pre-pruned by the k-nearest upper-bound radius, which is exact because a
   tile whose lower bound clears k strictly-closer candidates can never
-  contribute a kept entry).  The host builds the count-only, halo and
-  best-1 lists (:func:`build_flat_worklist`).  The device builds the rest
-  in the same chunked form: the fused pallas ``rho_delta``'s count + kept-k
-  list (:func:`fused_worklist`) and the distributed shards' d_cut count
-  lists (:func:`cut_prefix`, :func:`cut_worklist`: a static, bucketed
-  length of which only the live chunks run), and the keep-every-pair
-  best-1 ring of the unresolved-row fallbacks (:func:`ring_worklist`).
+  contribute a kept entry).  Every list is built on the device, in one
+  chunked form: the count and fused count + kept-k lists of the one-chip
+  sweeps (:func:`fused_worklist`, sized on the host by one 8-byte read),
+  the distributed shards' d_cut count lists (:func:`cut_prefix`,
+  :func:`cut_worklist`: a static, bucketed length of which only the live
+  chunks run), and the keep-every-pair best-1 rings
+  (:func:`ring_worklist`).  ``ref.kept_pairs_ref`` computes the same kept
+  pairs in numpy: the tests' reference for these builders.
 
 Every builder force-keeps at least one pair per row tile so output blocks
 are always initialized, and all-in-one-cell data degenerates to the dense
@@ -375,7 +376,7 @@ def _rho_delta_bs_jnp(x, y, jitter, d_cut, y_sel_slots=None,
 
 
 # =====================================================================
-# host-built flat worklists (the pallas scalar-prefetch grid)
+# device-built flat worklists (the pallas scalar-prefetch grid)
 # =====================================================================
 # Worklist entries per pallas_call.  Each call scalar-prefetches its chunk
 # into SMEM — (4, C) int32 + (C,) f32, 160 KiB at this size — so the SMEM
@@ -391,7 +392,10 @@ class FlatWorklist:
     entries sorted by (row_tile, lb) so output blocks are revisited
     consecutively (the Mosaic accumulation contract) in ring order.  The
     list is cut into chunks of whole row tiles, each padded to the same
-    length C with inert entries (flag -1), one ``pallas_call`` per chunk.
+    power-of-two length C with inert entries (flag -1, infinite bound) that
+    repeat the chunk's last (row, col) pair — no new block DMA, no
+    write-back of a block the chunk did not finish — one ``pallas_call``
+    per chunk.
     """
 
     meta: jnp.ndarray          # (chunks, 4, C) int32
@@ -413,51 +417,17 @@ def _pow2(v: int) -> int:
     return 1 << max(int(v) - 1, 0).bit_length()
 
 
-def chunk_worklist(meta: np.ndarray, lb: np.ndarray,
-                   chunk: int) -> tuple[np.ndarray, np.ndarray]:
-    """Cut a (4, W) row-sorted worklist into (chunks, 4, C) / (chunks, C).
-
-    Chunks hold whole row tiles, greedily packed up to C entries, where C
-    is a power of two (one compiled kernel serves every worklist of that
-    bucket) no smaller than the longest row tile.  Padding entries repeat
-    the chunk's last (row, col) pair — no new block DMA, no write-back of a
-    block the chunk did not finish — with flag -1 and an infinite bound, so
-    no accumulator reads them.
-    """
-    W = meta.shape[1]
-    bounds = np.flatnonzero(np.diff(meta[0])) + 1
-    row_start = np.concatenate([[0], bounds, [W]])
-    C = _pow2(max(np.diff(row_start).max(), min(W, chunk), 8))
-    cuts = [0]
-    for a, b in zip(row_start[:-1], row_start[1:]):     # row tile [a, b)
-        if b - cuts[-1] > C:
-            cuts.append(int(a))
-    cuts.append(W)
-    metas, lbs = [], []
-    for a, b in zip(cuts[:-1], cuts[1:]):
-        pad = C - (b - a)
-        fill = np.array([[meta[0, b - 1]], [meta[1, b - 1]], [0], [-1]],
-                        np.int32)
-        metas.append(np.concatenate([meta[:, a:b], np.repeat(fill, pad, 1)],
-                                    axis=1))
-        lbs.append(np.concatenate([lb[a:b], np.full(pad, np.inf,
-                                                    np.float32)]))
-    return np.stack(metas), np.stack(lbs)
-
-
 def ring_worklist(x, y, *, block_n: int, block_m: int, ny=None,
                   live_rows=None) -> FlatWorklist:
     """Device-built best-1 ring worklist: every tile pair of ``x`` rows
     against ``y`` columns, each row tile's column tiles in ascending lower
-    bound (stable: ties by tile index), chunked as :func:`chunk_worklist`
-    chunks it.
+    bound (stable: ties by tile index), in :class:`FlatWorklist` chunks.
 
-    The list ``build_flat_worklist(nn="best1")`` builds on the host (its
-    bounds equal up to f32 summation order), without the round trip: a
-    best-1 list keeps every pair, so it is a pure function of the tile
-    bounds, and its shapes depend only on the table shapes.  The kernel's
-    ``lb <= max(best)`` test then skips every pair past the row tile's
-    final radius.  Sets no worklist counter.  ``ny``: the leading rows of
+    The pairs of ``ref.kept_pairs_ref(nn="best1")`` (its bounds equal up
+    to f32 summation order): a best-1 list keeps every pair, so it is a
+    pure function of the tile bounds, and its shapes depend only on the
+    table shapes.  The kernel's ``lb <= max(best)`` test then skips every
+    pair past the row tile's final radius.  Sets no worklist counter.  ``ny``: the leading rows of
     ``y`` that are points; ``live_rows`` (traceable): the leading rows of
     ``x`` to answer, so only the chunks that hold them run.
     """
@@ -489,9 +459,8 @@ def _ring_chunks(x, y, ny=None, *, bn: int, bm: int, chunk: int):
 
     Every row tile holds nbc entries, so a chunk holds C // nbc whole row
     tiles; the entries past a chunk's last real one repeat it with flag -1
-    and an infinite bound, as the host chunker pads.  ``ny``: the leading
-    rows of ``y`` that are points (the rest bound nothing); all of them
-    when None.
+    and an infinite bound.  ``ny``: the leading rows of ``y`` that are
+    points (the rest bound nothing); all of them when None.
     """
     n, m = x.shape[0], y.shape[0] if ny is None else ny
     xp, yp = _pad_inf(x.astype(jnp.float32), bn), \
@@ -544,8 +513,7 @@ def ring_live_pairs(meta, lb, delta, live_chunks=None, *, bn: int):
 # tile's ascending-lb prefix within its threshold and packs whole row tiles
 # greedily into chunks; the host takes the chunk count (the largest over the
 # shards) up to a power of two, the list's static length; :func:`cut_worklist`
-# then lays the entries out in :class:`FlatWorklist` chunks, the host
-# builder's format.
+# then lays the entries out in :class:`FlatWorklist` chunks.
 @jax.tree_util.register_dataclass
 @dataclass(frozen=True)
 class CutPrefix:
@@ -560,10 +528,10 @@ class CutPrefix:
 
 def cut_prefix(x, nx, y, ny, d_cut, *, bn: int, bm: int,
                chunk: int = WL_CHUNK, r2=None) -> CutPrefix:
-    """The kept pairs of ``build_flat_worklist(count=True)`` for ``x`` rows
-    against ``y`` columns, on the device: per row tile the column tiles
-    whose lower bound is within d_cut, in ascending bound (stable, ties by
-    tile index), at least the nearest one (output blocks initialize).
+    """The kept pairs of a d_cut count list for ``x`` rows against ``y``
+    columns, on the device: per row tile the column tiles whose lower bound
+    is within d_cut, in ascending bound (stable, ties by tile index), at
+    least the nearest one (output blocks initialize).
     ``nx`` / ``ny``: the leading rows of each table that are points.
     ``r2`` (nbr,): a squared radius per row tile that widens its threshold
     to ``max(d_cut^2, r2)`` (the fused sweep's static k-NN ring,
@@ -597,12 +565,11 @@ def cut_prefix(x, nx, y, ny, d_cut, *, bn: int, bm: int,
 def cut_worklist(cp: CutPrefix, d_cut, *, n_chunks: int,
                  chunk: int = WL_CHUNK) -> FlatWorklist:
     """Stage two: ``n_chunks`` chunks (at least ``cp.chunks``) in
-    :func:`chunk_worklist`'s format.  Slot ``s`` belongs to the last row
+    :class:`FlatWorklist`'s format.  Slot ``s`` belongs to the last row
     tile that starts at or before it; past that tile's entries it repeats
     the tile's last entry with flag -1 and an infinite bound, so a chunk's
-    padding repeats its last pair, as the host chunker pads.  Chunks past
-    ``cp.chunks`` hold only padding and are not run
-    (``FlatWorklist.live_chunks``).  Every index here comes from the
+    padding repeats its last pair.  Chunks past ``cp.chunks`` hold only
+    padding and are not run (``FlatWorklist.live_chunks``).  Every index here comes from the
     packing, none from the sort: no sort-derived gather index (R1)."""
     nbr, nbc = cp.lb_sorted.shape
     C = _chunk_len(nbr, nbc, chunk)
@@ -641,10 +608,13 @@ def cut_worklist(cp: CutPrefix, d_cut, *, n_chunks: int,
 
 
 def knn_radius(ub, col_counts, k: int) -> jnp.ndarray:
-    """Traced :func:`_knn_radius`: per row tile the smallest upper bound
+    """Per row tile the static k-NN prune radius: the smallest upper bound
     whose tiles hold at least ``k`` candidates, +inf where no prefix does.
-    The stable int32 key-value sort of the rings, then a masked min over
-    the sorted bounds (no gather at a sort-derived index: R1)."""
+    A pair with a lower bound past it is outside every row's kept-k (k
+    strictly closer candidates exist), so pruning by it preserves
+    bit-parity.  The stable int32 key-value sort of the rings, then a
+    masked min over the sorted bounds (no gather at a sort-derived index:
+    R1)."""
     cnt = jnp.broadcast_to(col_counts.astype(jnp.int32), ub.shape)
     ub_sorted, cnt_sorted = jax.lax.sort((ub, cnt), dimension=1, num_keys=1,
                                          is_stable=True)
@@ -653,23 +623,27 @@ def knn_radius(ub, col_counts, k: int) -> jnp.ndarray:
 
 
 @partial(jax.jit, static_argnames=("bn", "bm", "k", "chunk"))
-def _fused_prefix(x, y, d_cut, sel_slots=None, *, bn: int, bm: int, k: int,
-                  chunk: int):
+def _fused_prefix(x, y, d_cut, sel_slots=None, *, bn: int, bm: int,
+                  k: int | None, chunk: int):
     """Stage one of :func:`fused_worklist`: the prefix of each row tile
-    within ``max(d_cut^2, k-NN radius)``, packed, and (chunks, kept) for
-    the host.  ``sel_slots``: the columns that may enter the kept-k (the
-    radius counts only those); every column when None."""
+    within d_cut (``k`` None) or ``max(d_cut^2, k-NN radius)``, packed, and
+    (chunks, kept) for the host.  ``sel_slots``: the columns that may enter
+    the kept-k (the radius counts only those); every column when None."""
     n, m = x.shape[0], y.shape[0]
-    xp, yp = _pad_inf(x.astype(jnp.float32), bn), \
-        _pad_inf(y.astype(jnp.float32), bm)
-    nbc = yp.shape[0] // bm
-    if sel_slots is None:
-        counts = jnp.clip(m - bm * jnp.arange(nbc, dtype=jnp.int32), 0, bm)
-    else:
-        counts = jnp.zeros((nbc,), jnp.int32).at[sel_slots // bm].add(1)
-    ub = pair_upper_bounds(*tile_bounds(xp, n, bn), *tile_bounds(yp, m, bm))
-    cp = cut_prefix(x, n, y, m, d_cut, bn=bn, bm=bm, chunk=chunk,
-                    r2=knn_radius(ub, counts, max(k, 1)))
+    r2 = None
+    if k is not None:
+        xp, yp = _pad_inf(x.astype(jnp.float32), bn), \
+            _pad_inf(y.astype(jnp.float32), bm)
+        nbc = yp.shape[0] // bm
+        if sel_slots is None:
+            counts = jnp.clip(m - bm * jnp.arange(nbc, dtype=jnp.int32), 0,
+                              bm)
+        else:
+            counts = jnp.zeros((nbc,), jnp.int32).at[sel_slots // bm].add(1)
+        ub = pair_upper_bounds(*tile_bounds(xp, n, bn),
+                               *tile_bounds(yp, m, bm))
+        r2 = knn_radius(ub, counts, max(k, 1))
+    cp = cut_prefix(x, n, y, m, d_cut, bn=bn, bm=bm, chunk=chunk, r2=r2)
     return cp, jnp.stack([cp.chunks, jnp.sum(cp.kept, dtype=jnp.int32)])
 
 
@@ -680,18 +654,17 @@ def _cut_list(cp: CutPrefix, d_cut, *, n_chunks: int, chunk: int):
     return wl.meta, wl.lb
 
 
-def fused_worklist(x, y, d_cut, *, block_n: int, block_m: int, k: int,
-                   sel_slots=None) -> FlatWorklist:
-    """The list of the fused count + kept-k sweep, built on the device:
-    the pairs, order and chunks of ``build_flat_worklist(count=True,
-    nn="topk", k=k)`` (with ``nn_col_counts`` the selected slots of each
-    column tile when ``sel_slots`` gates the kept-k).  In ascending lower
-    bound that kept set is each row tile's prefix within ``max(d_cut^2,
-    radius)``, at least one pair: :func:`cut_prefix` with the k-NN radius.
-    One host read between the stages, the 8-byte (chunks, kept); the
-    length is the chunk count up to a power of two, so a re-fit in a new
-    order reuses one compiled sweep per bucket, and only the live chunks
-    run.  Nothing is fetched, hashed or uploaded; no content cache."""
+def fused_worklist(x, y, d_cut, *, block_n: int, block_m: int,
+                   k: int | None = None, sel_slots=None) -> FlatWorklist:
+    """The list of a one-chip count sweep (``k`` None) or fused count +
+    kept-k sweep, built on the device: per row tile, in ascending lower
+    bound, the column tiles within d_cut — or within ``max(d_cut^2,
+    radius)``, the static k-NN radius (:func:`knn_radius`, counting only
+    the ``sel_slots`` columns when they gate the kept-k) — at least one:
+    :func:`cut_prefix`.  One host read between the stages, the 8-byte
+    (chunks, kept); the length is the chunk count up to a power of two, so
+    a re-fit in a new order reuses one compiled sweep per bucket, and only
+    the live chunks run.  Nothing else is fetched and nothing uploaded."""
     chunk = WL_CHUNK
     with obs.span("worklist.build") as sp:
         _M_BUILDS.inc()
@@ -710,87 +683,12 @@ def fused_worklist(x, y, d_cut, *, block_n: int, block_m: int, k: int,
                         live_chunks=cp.chunks)
 
 
-def _host_bounds(arr: np.ndarray, block: int):
-    n, d = arr.shape
-    nb = -(-n // block)
-    pad = np.full((nb * block, d), np.inf, np.float32)
-    pad[:n] = arr
-    valid = (np.arange(nb * block) < n).reshape(nb, block)[..., None]
-    xt = pad.reshape(nb, block, d)
-    lo = np.where(valid, xt, np.inf).min(axis=1)
-    hi = np.where(valid, xt, -np.inf).max(axis=1)
-    return lo, hi
-
-
-def host_pair_bounds(x: np.ndarray, y: np.ndarray, block_n: int,
-                     block_m: int):
-    """Host (numpy) mirror of the device bound math: (lb, ub) matrices."""
-    rlo, rhi = _host_bounds(np.asarray(x, np.float32), block_n)
-    clo, chi = _host_bounds(np.asarray(y, np.float32), block_m)
-    gap = np.maximum(np.maximum(clo[None] - rhi[:, None],
-                                rlo[:, None] - chi[None]), 0.0)
-    lb = (gap * gap).sum(-1) * LB_SHRINK
-    reach = np.maximum(np.maximum(chi[None] - rlo[:, None],
-                                  rhi[:, None] - clo[None]), 0.0)
-    ub = (reach * reach).sum(-1) * UB_GROW
-    empty_r = (rlo > rhi).any(-1)
-    empty_c = (clo > chi).any(-1)
-    ub[empty_r[:, None] | empty_c[None, :]] = np.inf
-    return lb.astype(np.float32), ub.astype(np.float32)
-
-
-def _knn_radius(ub: np.ndarray, col_counts: np.ndarray, k: int) -> np.ndarray:
-    """Per-row-tile static k-NN prune radius: the smallest upper bound v
-    such that tiles with ub <= v hold at least k candidate points.  A pair
-    with lb > v is provably outside every row's kept-k (k strictly closer
-    candidates exist), so pruning by it preserves bit-parity."""
-    nbr, nbc = ub.shape
-    ord_ub = np.argsort(ub, axis=1)
-    ub_sorted = np.take_along_axis(ub, ord_ub, axis=1)
-    cnt_sorted = col_counts[ord_ub]
-    cum = np.cumsum(cnt_sorted, axis=1)
-    reach = np.argmax(cum >= k, axis=1)           # first prefix with >= k
-    enough = cum[:, -1] >= k
-    radius = ub_sorted[np.arange(nbr), reach]
-    return np.where(enough, radius, np.inf).astype(np.float32)
-
-
-# --------------------------------------------------------------------------
-# Host-worklist caching.  Building a flat worklist is host work proportional
-# to the tile-pair grid; a DPCPlan (repro.engine.planner) activates a small
-# LRU here so repeated fits on the same data skip the rebuild.  Keys are
-# content fingerprints (blake2b over the input bytes + every build knob), so
-# same-shape-different-data inputs can never collide.  With no active cache
-# (direct backend calls) every build runs, exactly as before.
-_WL_CACHE_STACK: list[tuple[dict, int]] = []
-
-# Instrumentation lives on the repro.obs metrics registry (the old
-# ``_WL_BUILDS``/``_WL_CACHE_HITS`` module globals are gone); the functions
+# Instrumentation lives on the repro.obs metrics registry; the functions
 # below are the stable read surface tests and callers use.
 _M_BUILDS = _obsm.counter(
-    "worklist_builds",
-    "flat-worklist builds, host (cache misses included) and fused device")
-_M_CACHE_HITS = _obsm.counter(
-    "worklist_cache_hits", "fingerprint hits inside a worklist_cache scope")
-_M_FP_MISSES = _obsm.counter(
-    "worklist_fingerprint_misses",
-    "cache was active but the content fingerprint was absent (true rebuild)")
+    "worklist_builds", "flat-worklist builds (fused_worklist)")
 _G_WL_LEN = _obsm.gauge(
     "worklist_len", "kept tile-pair count of the most recent build")
-
-
-@contextmanager
-def worklist_cache(cache, max_entries: int = 8,
-                   max_bytes: int = 64 << 20):
-    """Activate ``cache`` (a MutableMapping, LRU-trimmed to ``max_entries``
-    AND to ``max_bytes`` of worklist table data — dense-degenerate
-    worklists can reach tens of MB, so the cap is size-aware) for
-    build_flat_worklist calls inside the context."""
-    _WL_CACHE_STACK.append((cache, max_entries, max_bytes))
-    try:
-        yield cache
-    finally:
-        _WL_CACHE_STACK.pop()
 
 
 @contextmanager
@@ -799,11 +697,10 @@ def suspend_counters():
 
     Plan-time static analysis (``engine.planner._plan_check``) builds
     throwaway worklists to probe kernel structure; those must not count as
-    real builds or cache traffic.  On exit every worklist metric family is
-    restored to its value at entry, atomically per family.
+    real builds.  On exit every worklist metric family is restored to its
+    value at entry, atomically per family.
     """
-    saved = [(m, m._state()) for m in
-             (_M_BUILDS, _M_CACHE_HITS, _M_FP_MISSES, _G_WL_LEN)]
+    saved = [(m, m._state()) for m in (_M_BUILDS, _G_WL_LEN)]
     try:
         yield
     finally:
@@ -811,20 +708,8 @@ def suspend_counters():
             m._restore(state)
 
 
-def _wl_nbytes(wl: "FlatWorklist") -> int:
-    return int(wl.meta.nbytes) + int(wl.lb.nbytes)
-
-
 def worklist_build_count() -> int:
     return int(_M_BUILDS.value())
-
-
-def worklist_cache_hits() -> int:
-    return int(_M_CACHE_HITS.value())
-
-
-def worklist_fingerprint_misses() -> int:
-    return int(_M_FP_MISSES.value())
 
 
 def d2h_nbytes(*arrays) -> int:
@@ -836,176 +721,11 @@ def d2h_nbytes(*arrays) -> int:
     return sum(int(a.nbytes) for a in dev.values())
 
 
-def _src_dtype_tag(arr) -> str:
-    """The dtype the CALLER handed us, before any canonicalizing cast.
-
-    Worklists are fingerprinted on the f32-converted coordinates, but two
-    callers passing the same coordinates at different source precisions are
-    different cache identities: the sweep kernels consume the *original*
-    arrays, so a worklist built for one must not be served to the other
-    (a f64 pad row that rounds onto a kept f32 point, say, has different
-    pruning slack).  The tag rides the fingerprint alongside the bytes.
-    """
-    if arr is None:
-        return "none"
-    dt = getattr(arr, "dtype", None)
-    return str(dt) if dt is not None else np.asarray(arr).dtype.name
-
-
-def _wl_fingerprint(x, y, d_cut, block_n, block_m, count, nn, k, nn_dcut,
-                    nn_col_counts, starts, ends,
-                    src_dtypes: tuple = ()) -> bytes:
-    import hashlib
-
-    h = hashlib.blake2b(digest_size=16)
-    for arr in (x, y, nn_col_counts, starts, ends):
-        if arr is None:
-            h.update(b"\x00none")
-        else:
-            a = np.ascontiguousarray(np.asarray(arr))
-            h.update(str(a.shape).encode())
-            h.update(str(a.dtype).encode())
-            h.update(a.tobytes())
-    h.update(repr((None if d_cut is None else float(d_cut), block_n,
-                   block_m, bool(count), nn, int(k),
-                   bool(nn_dcut), src_dtypes)).encode())
-    return h.digest()
-
-
-def build_flat_worklist(x, y, d_cut=None, *, block_n: int, block_m: int,
-                        count: bool = True, nn: str | None = None,
-                        k: int = 0, nn_dcut: bool = False,
-                        nn_col_counts=None,
-                        starts=None, ends=None) -> FlatWorklist:
-    """Host-built kept-pair worklist for one pallas sweep.
-
-    Kept pairs are the union of what each requested accumulator can touch:
-    ``count`` keeps lb <= d_cut^2; ``nn='topk'`` adds the static k-NN ring
-    (see :func:`_knn_radius`; ``nn_col_counts`` overrides the per-col-tile
-    admissible-candidate counts when a selection gate restricts the kept-k,
-    e.g. S-Approx representatives); ``nn='best1'`` keeps every pair unless
-    ``nn_dcut`` bounds the search (halo semantics) — the in-kernel runtime
-    radius does the remaining pruning.  ``starts``/``ends`` (halo spans)
-    additionally drop col tiles no row span reaches.  At least one pair per
-    row tile is force-kept so output blocks always initialize.
-
-    Inside a :func:`worklist_cache` context (a DPCPlan primitive wrapper)
-    results are memoized by content fingerprint — same data, same knobs,
-    no rebuild.
-    """
-    with obs.span("worklist.build") as sp:
-        src_dtypes = (_src_dtype_tag(x), _src_dtype_tag(y))
-        with obs.span("worklist.fetch", d2h_bytes=d2h_nbytes(x, y)):
-            x = np.asarray(x, np.float32)
-            y = np.asarray(y, np.float32)
-        key = None
-        if _WL_CACHE_STACK:
-            cache, max_entries, max_bytes = _WL_CACHE_STACK[-1]
-            with obs.span("worklist.fingerprint"):
-                key = _wl_fingerprint(x, y, d_cut, block_n, block_m, count,
-                                      nn, k, nn_dcut, nn_col_counts, starts,
-                                      ends, src_dtypes)
-            hit = cache.get(key)
-            if hit is not None:
-                _M_CACHE_HITS.inc()
-                if hasattr(cache, "move_to_end"):
-                    cache.move_to_end(key)
-                sp.set(kept=hit.n_kept, total=hit.n_total, hit=True,
-                       chunks=int(hit.meta.shape[0]))
-                return hit
-            _M_FP_MISSES.inc()
-        _M_BUILDS.inc()
-        with obs.span("worklist.bounds"):
-            meta_c, lb_c, n_kept, n_total = _kept_pairs(
-                x, y, d_cut, block_n, block_m, count, nn, k, nn_dcut,
-                nn_col_counts, starts, ends)
-        with obs.span("worklist.upload",
-                      h2d_bytes=int(meta_c.nbytes + lb_c.nbytes)):
-            out = FlatWorklist(meta=jnp.asarray(meta_c), lb=jnp.asarray(lb_c),
-                               n_kept=n_kept, n_total=n_total)
-        _G_WL_LEN.set(out.n_kept)
-        sp.set(kept=out.n_kept, total=out.n_total, hit=False,
-               chunks=int(meta_c.shape[0]))
-        if key is not None:
-            cache[key] = out
-            while len(cache) > 1 and (
-                    len(cache) > max_entries
-                    or sum(map(_wl_nbytes, cache.values())) > max_bytes):
-                cache.pop(next(iter(cache)))    # oldest entry (insertion order)
-        return out
-
-
-def _kept_pairs(x, y, d_cut, block_n, block_m, count, nn, k, nn_dcut,
-                nn_col_counts, starts, ends):
-    """The kept tile pairs of :func:`build_flat_worklist`, row-sorted and
-    chunked on the host: (meta chunks, lb chunks, kept, dense total)."""
-    n, _ = x.shape
-    m = y.shape[0]
-    nbr, nbc = -(-n // block_n), -(-m // block_m)
-    lb, ub = host_pair_bounds(x, y, block_n, block_m)
-    d2cut = None if d_cut is None else float(d_cut) ** 2
-
-    in_cut = np.zeros((nbr, nbc), bool)
-    keep = np.zeros((nbr, nbc), bool)
-    if count:
-        assert d2cut is not None
-        in_cut = lb <= d2cut
-        keep |= in_cut
-    if nn == "best1":
-        if nn_dcut:
-            assert d2cut is not None
-            keep |= lb <= d2cut
-        else:
-            keep[:] = True
-    elif nn == "topk":
-        if nn_col_counts is None:
-            col_counts = np.minimum(block_m, np.maximum(
-                0, m - np.arange(nbc) * block_m))
-        else:
-            col_counts = np.asarray(nn_col_counts)
-        radius = _knn_radius(ub, col_counts, max(k, 1))
-        keep |= lb <= radius[:, None]
-
-    if starts is not None:
-        st = np.asarray(starts)
-        en = np.asarray(ends)
-        pad_rows = nbr * block_n - n
-        if pad_rows:
-            st = np.pad(st, ((0, pad_rows), (0, 0)))
-            en = np.pad(en, ((0, pad_rows), (0, 0)))
-        live = en > st
-        smin = np.where(live, st, np.iinfo(np.int64).max) \
-            .reshape(nbr, block_n, -1).min(axis=(1, 2))
-        emax = np.where(live, en, np.iinfo(np.int64).min) \
-            .reshape(nbr, block_n, -1).max(axis=(1, 2))
-        jlo = np.arange(nbc) * block_m
-        overlap = (smin[:, None] < jlo[None, :] + block_m) & \
-                  (emax[:, None] > jlo[None, :])
-        keep &= overlap
-        in_cut &= overlap
-
-    # force-keep the min-lb pair of every row tile (output block init)
-    jmin = np.argmin(lb, axis=1)
-    keep[np.arange(nbr), jmin] = True
-
-    wi, wj = np.nonzero(keep)
-    wl = lb[wi, wj]
-    sort = np.lexsort((wl, wi))
-    wi, wj, wl = wi[sort], wj[sort], wl[sort]
-    first = np.zeros(len(wi), np.int32)
-    first[np.unique(wi, return_index=True)[1]] = 1
-    meta = np.stack([wi, wj, first,
-                     in_cut[wi, wj].astype(np.int64)]).astype(np.int32)
-    meta_c, lb_c = chunk_worklist(meta, wl.astype(np.float32), WL_CHUNK)
-    return meta_c, lb_c, len(wi), nbr * nbc
-
-
 def worklist_stats(x, y, d_cut, *, block_n: int = BS_BLOCK_N,
                    block_m: int = BS_BLOCK_M) -> dict:
     """Pruning statistics for benchmarks: how much of the dense tile grid
     the d_cut-bounded count sweep keeps (``benchmarks/scaling_dcut.py``
     records this next to runtime so the sensitivity plot shows *why*)."""
-    wl = build_flat_worklist(x, y, d_cut, block_n=block_n, block_m=block_m,
-                             count=True)
+    wl = fused_worklist(x, y, d_cut, block_n=block_n, block_m=block_m)
     return {"tiles_total": wl.n_total, "tiles_kept": wl.n_kept,
             "pruned_tile_frac": wl.pruned_frac}

@@ -64,8 +64,7 @@ def range_count_halo(x: jnp.ndarray, window: jnp.ndarray,
                      starts: jnp.ndarray, ends: jnp.ndarray, d_cut,
                      block_n: int = DEFAULT_BLOCK_N,
                      block_m: int = DEFAULT_BLOCK_M,
-                     interpret: bool = False,
-                     worklist=None) -> jnp.ndarray:
+                     interpret: bool = False) -> jnp.ndarray:
     """Range count against per-row ragged [start, end) windows (halo tiles).
 
     The distributed halo layout: each x-row counts only the window columns
@@ -76,5 +75,5 @@ def range_count_halo(x: jnp.ndarray, window: jnp.ndarray,
     spec = SweepSpec(block_n=block_n, block_m=block_m, count=True, span=True,
                      span_s=starts.shape[1])
     (cnt,) = tile_sweep(spec, x, window, d_cut, starts=starts, ends=ends,
-                        **worklist_args(worklist), interpret=interpret)
+                        interpret=interpret)
     return cnt

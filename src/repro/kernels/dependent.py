@@ -55,7 +55,7 @@ def masked_min_dist_halo(x, x_key, window, w_key, starts, ends, d_cut,
                          block_n: int = 128, block_m: int = DEFAULT_BLOCK,
                          interpret: bool = False,
                          refine_k: int = REFINE_TOPK,
-                         precision: str = "f32", worklist=None):
+                         precision: str = "f32"):
     """Strictly-denser NN within d_cut over per-row ragged halo windows.
 
     The distributed delta phase: candidates are the window columns inside the
@@ -68,6 +68,5 @@ def masked_min_dist_halo(x, x_key, window, w_key, starts, ends, d_cut,
                      span=True, span_s=starts.shape[1], nn_dcut=True,
                      refine_k=refine_k, precision=precision)
     best, arg = tile_sweep(spec, x, window, d_cut, x_key=x_key, y_key=w_key,
-                           starts=starts, ends=ends, **worklist_args(worklist),
-                           interpret=interpret)
+                           starts=starts, ends=ends, interpret=interpret)
     return jnp.sqrt(best), arg

@@ -145,7 +145,7 @@ def fused_sweep(x, y, d_cut, *, nn_sel=None, k: int = FUSED_TOPK,
 def halo_density(x, window, starts, ends, d_cut, *,
                  block_n: int = DENSITY_BLOCK_N,
                  block_m: int = DENSITY_BLOCK_M,
-                 interpret: bool | None = None, worklist=None):
+                 interpret: bool | None = None):
     """Kernel-backed halo range count: per x-row count of window columns
     inside the row's [start, end) spans and within d_cut."""
     if interpret is None:
@@ -156,14 +156,13 @@ def halo_density(x, window, starts, ends, d_cut, *,
     st = _pad_spans(starts, block_n)
     en = _pad_spans(ends, block_n)
     cnt = range_count_halo(xp, wp, st, en, d_cut, block_n=block_n,
-                           block_m=block_m, interpret=interpret,
-                           worklist=worklist)
+                           block_m=block_m, interpret=interpret)
     return cnt[:n].astype(jnp.float32)
 
 
 def halo_dependent(x, x_key, window, w_key, starts, ends, d_cut, *,
                    block_n: int = 128, block_m: int = DENSITY_BLOCK_M,
-                   interpret: bool | None = None, worklist=None):
+                   interpret: bool | None = None):
     """Kernel-backed halo strictly-denser NN within d_cut.  Returns
     (delta, parent_window_idx, found)."""
     if interpret is None:
@@ -177,8 +176,7 @@ def halo_dependent(x, x_key, window, w_key, starts, ends, d_cut, *,
     en = _pad_spans(ends, block_n)
     delta, parent = masked_min_dist_halo(xp, xk, wp, wk, st, en, d_cut,
                                          block_n=block_n, block_m=block_m,
-                                         interpret=interpret,
-                                         worklist=worklist)
+                                         interpret=interpret)
     found = jnp.isfinite(delta[:n])
     return delta[:n], parent[:n], found
 

@@ -1,6 +1,8 @@
-"""Pure-jnp oracles for the Pallas kernels (the ground truth in kernel tests)."""
+"""Oracles for the Pallas kernels (the ground truth in kernel tests): pure
+jnp for their answers, numpy for their block-sparse worklists."""
 from __future__ import annotations
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -68,3 +70,125 @@ def masked_min_dist_ref(x, x_key, y, y_key):
     arg = jnp.argmin(d2, axis=1)
     best = d2[jnp.arange(x.shape[0]), arg]
     return jnp.sqrt(best), jnp.where(jnp.isfinite(best), arg, -1).astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# The kept tile pairs of a block-sparse worklist, in numpy: the reference the
+# device builders of ``kernels.blocksparse`` are tested against.
+def _host_bounds(arr: np.ndarray, block: int):
+    n, d = arr.shape
+    nb = -(-n // block)
+    pad = np.full((nb * block, d), np.inf, np.float32)
+    pad[:n] = arr
+    valid = (np.arange(nb * block) < n).reshape(nb, block)[..., None]
+    xt = pad.reshape(nb, block, d)
+    lo = np.where(valid, xt, np.inf).min(axis=1)
+    hi = np.where(valid, xt, -np.inf).max(axis=1)
+    return lo, hi
+
+
+def host_pair_bounds(x: np.ndarray, y: np.ndarray, block_n: int,
+                     block_m: int):
+    """Numpy mirror of the device bound math: (lb, ub) matrices."""
+    from .blocksparse import LB_SHRINK, UB_GROW
+
+    rlo, rhi = _host_bounds(np.asarray(x, np.float32), block_n)
+    clo, chi = _host_bounds(np.asarray(y, np.float32), block_m)
+    gap = np.maximum(np.maximum(clo[None] - rhi[:, None],
+                                rlo[:, None] - chi[None]), 0.0)
+    lb = (gap * gap).sum(-1) * LB_SHRINK
+    reach = np.maximum(np.maximum(chi[None] - rlo[:, None],
+                                  rhi[:, None] - clo[None]), 0.0)
+    ub = (reach * reach).sum(-1) * UB_GROW
+    empty_r = (rlo > rhi).any(-1)
+    empty_c = (clo > chi).any(-1)
+    ub[empty_r[:, None] | empty_c[None, :]] = np.inf
+    return lb.astype(np.float32), ub.astype(np.float32)
+
+
+def _knn_radius(ub: np.ndarray, col_counts: np.ndarray, k: int) -> np.ndarray:
+    """Numpy ``blocksparse.knn_radius``: per row tile the smallest upper
+    bound v such that tiles with ub <= v hold at least k candidates."""
+    nbr, _ = ub.shape
+    ord_ub = np.argsort(ub, axis=1)
+    ub_sorted = np.take_along_axis(ub, ord_ub, axis=1)
+    cum = np.cumsum(col_counts[ord_ub], axis=1)
+    reach = np.argmax(cum >= k, axis=1)           # first prefix with >= k
+    radius = ub_sorted[np.arange(nbr), reach]
+    return np.where(cum[:, -1] >= k, radius, np.inf).astype(np.float32)
+
+
+def chunk_worklist(meta: np.ndarray, lb: np.ndarray,
+                   chunk: int) -> tuple[np.ndarray, np.ndarray]:
+    """Cut a (4, W) row-sorted worklist into (chunks, 4, C) / (chunks, C):
+    whole row tiles greedily packed up to C entries, C a power of two no
+    smaller than the longest row tile, each chunk padded with its last
+    (row, col) pair, flag -1 and an infinite bound
+    (``blocksparse.FlatWorklist``'s format)."""
+    from .blocksparse import _pow2
+
+    W = meta.shape[1]
+    bounds = np.flatnonzero(np.diff(meta[0])) + 1
+    row_start = np.concatenate([[0], bounds, [W]])
+    C = _pow2(max(np.diff(row_start).max(), min(W, chunk), 8))
+    cuts = [0]
+    for a, b in zip(row_start[:-1], row_start[1:]):     # row tile [a, b)
+        if b - cuts[-1] > C:
+            cuts.append(int(a))
+    cuts.append(W)
+    metas, lbs = [], []
+    for a, b in zip(cuts[:-1], cuts[1:]):
+        pad = C - (b - a)
+        fill = np.array([[meta[0, b - 1]], [meta[1, b - 1]], [0], [-1]],
+                        np.int32)
+        metas.append(np.concatenate([meta[:, a:b], np.repeat(fill, pad, 1)],
+                                    axis=1))
+        lbs.append(np.concatenate([lb[a:b], np.full(pad, np.inf,
+                                                    np.float32)]))
+    return np.stack(metas), np.stack(lbs)
+
+
+def kept_pairs_ref(x, y, d_cut=None, *, block_n: int, block_m: int,
+                   count: bool = True, nn: str | None = None, k: int = 0,
+                   nn_col_counts=None):
+    """The kept tile pairs of a pallas sweep's worklist, row-sorted and
+    chunked in numpy: (meta chunks, lb chunks, kept, dense total).
+
+    Kept pairs are the union of what each requested accumulator can touch:
+    ``count`` keeps lb <= d_cut^2; ``nn='topk'`` adds the static k-NN ring
+    (``nn_col_counts``: the admissible candidates of each column tile when
+    a selection gate restricts the kept-k, every point otherwise);
+    ``nn='best1'`` keeps every pair.  At least the min-lb pair of each row
+    tile is kept, so output blocks always initialize.
+    """
+    from . import blocksparse
+
+    x = np.asarray(x, np.float32)
+    y = np.asarray(y, np.float32)
+    n, m = x.shape[0], y.shape[0]
+    nbr, nbc = -(-n // block_n), -(-m // block_m)
+    lb, ub = host_pair_bounds(x, y, block_n, block_m)
+    in_cut = np.zeros((nbr, nbc), bool)
+    if count:
+        in_cut = lb <= float(d_cut) ** 2
+    keep = in_cut.copy()
+    if nn == "best1":
+        keep[:] = True
+    elif nn == "topk":
+        col_counts = np.minimum(block_m, np.maximum(
+            0, m - np.arange(nbc) * block_m)) if nn_col_counts is None \
+            else np.asarray(nn_col_counts)
+        keep |= lb <= _knn_radius(ub, col_counts, max(k, 1))[:, None]
+    keep[np.arange(nbr), np.argmin(lb, axis=1)] = True
+
+    wi, wj = np.nonzero(keep)
+    wl = lb[wi, wj]
+    sort = np.lexsort((wl, wi))
+    wi, wj, wl = wi[sort], wj[sort], wl[sort]
+    first = np.zeros(len(wi), np.int32)
+    first[np.unique(wi, return_index=True)[1]] = 1
+    meta = np.stack([wi, wj, first,
+                     in_cut[wi, wj].astype(np.int64)]).astype(np.int32)
+    meta_c, lb_c = chunk_worklist(meta, wl.astype(np.float32),
+                                  blocksparse.WL_CHUNK)
+    return meta_c, lb_c, len(wi), nbr * nbc

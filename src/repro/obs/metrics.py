@@ -2,7 +2,7 @@
 
 One process-global registry onto which the repo's previously ad-hoc
 instrumentation migrates (planner plan-cache hits/misses/evictions,
-blocksparse worklist builds/cache-hits/fingerprint-misses, stream tick and
+blocksparse worklist builds and kept pairs, stream tick and
 dirty-tracking counters, serve HIT/MISS_FALLBACK rates).  The old read
 surfaces (``plan_cache_info()``, ``worklist_build_count()``,
 ``StreamDPC.stats()``) remain as thin shims over these metrics.

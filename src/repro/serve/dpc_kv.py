@@ -46,7 +46,8 @@ class DPCKVConfig:
     accept as an SMEM threshold), the sweep block, and the layout
     (``"block-sparse"`` is legal on ``worklist_traceable`` backends whose
     jit-built worklists survive the jit+vmap this module runs under; the
-    host-built pallas worklists are rejected *here*, at construction).
+    pallas count lists, sized on the host, are rejected *here*, at
+    construction).
     The ``backend`` / ``block`` fields are the legacy spellings and fold
     into the spec with a ``DeprecationWarning`` (see ``repro.engine``).
     """
@@ -66,8 +67,8 @@ class DPCKVConfig:
         object.__setattr__(self, "exec_spec", ex)
         # THE plan-resolved sweep block (not a field: derived, so equal
         # configs still hash/compare equal as jit static args).  The
-        # compression itself is traced code and cannot hold the plan's
-        # host-worklist context, but its block default is the planner's.
+        # compression itself is traced code, but its block default is the
+        # planner's.
         pl = as_plan(ex)
         object.__setattr__(self, "resolved_block", pl.resolved_block)
         # fail fast on combos that cannot run under this module's jit+vmap:
@@ -76,9 +77,9 @@ class DPCKVConfig:
         if ex.sparse and not be.worklist_traceable:
             raise ValueError(
                 f"DPC-KV runs under jit; layout='block-sparse' on the "
-                f"{be.name!r} backend builds host-side worklists, which "
-                f"cannot be constructed in traced code — use the 'jnp' "
-                f"backend (jit-built worklists) or the dense layout")
+                f"{be.name!r} backend sizes its count lists on the host, "
+                f"which traced code cannot do — use the 'jnp' backend "
+                f"(jit-built worklists) or the dense layout")
         if ex.resolved_precision == "bf16" and not (be.fused_traceable
                                                     and be.mxu_dense):
             raise ValueError(

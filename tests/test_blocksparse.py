@@ -22,7 +22,8 @@ from repro.core import DPCConfig, compute_dpc
 from repro.core.dpc_types import density_jitter
 from repro.core.grid import build_grid
 from repro.kernels import get_backend
-from repro.kernels.blocksparse import build_flat_worklist, worklist_stats
+from repro.kernels.blocksparse import FlatWorklist, worklist_stats
+from repro.kernels.ref import kept_pairs_ref
 
 BACKENDS = ["jnp", "pallas-interpret"]
 SEED_MATRIX = [(17, 2, 0, 0), (96, 3, 3, 1), (64, 4, 6, 2), (2, 2, 0, 3),
@@ -105,14 +106,16 @@ class TestEngineParity:
         """Worklists longer than one chunk run as several pallas_calls
         threading their outputs through; each chunk holds whole row tiles,
         so the result equals the dense sweep bit for bit."""
-        from repro.kernels import blocksparse
+        from repro.kernels import blocksparse, ops
 
         monkeypatch.setattr(blocksparse, "WL_CHUNK", 4)
         pts, d_cut = _lattice(1500, 2, 0, 5)
         be = get_backend("pallas-interpret")
-        wl = build_flat_worklist(pts, pts, d_cut, block_n=256, block_m=512,
-                                 count=True, nn="topk", k=8)
-        assert wl.meta.shape[0] > 1, "expected several chunks"
+        wl = blocksparse.fused_worklist(
+            pts, pts, d_cut, block_n=ops.DENSITY_BLOCK_N,
+            block_m=ops.DENSITY_BLOCK_M,
+            k=None if primitive == "count" else ops.FUSED_TOPK)
+        assert int(wl.live_chunks) > 1, "expected several chunks"
         if primitive == "count":
             pairs = [(be.range_count(pts, pts, d_cut),
                       be.range_count(pts, pts, d_cut,
@@ -159,7 +162,9 @@ class TestEngineParity:
 
 
 class TestHaloParity:
-    """span-masked primitives: worklist pruning by span reach AND d_cut."""
+    """span-masked primitives: the pallas kernels' dense span-masked tiles
+    (any layout) against the jnp gather-form reference, bit for bit on
+    lattice data (exact distances, frequent ties)."""
 
     @staticmethod
     def _case(seed, W=256, m=300, S=3, d=3):
@@ -180,23 +185,23 @@ class TestHaloParity:
     @pytest.mark.parametrize("seed", [0, 1])
     def test_halo_count(self, seed):
         window, x, _, _, st, en, cap = self._case(seed)
-        be = get_backend("pallas-interpret")
         d_cut = 900.0
-        dense = be.range_count_halo(x, window, st, en, d_cut, span_cap=cap)
-        bs = be.range_count_halo(x, window, st, en, d_cut, span_cap=cap,
-                                 layout="block-sparse")
-        assert _eq(dense, bs)
+        ref = get_backend("jnp").range_count_halo(x, window, st, en, d_cut,
+                                                  span_cap=cap)
+        bs = get_backend("pallas-interpret").range_count_halo(
+            x, window, st, en, d_cut, span_cap=cap, layout="block-sparse")
+        assert _eq(ref, bs)
 
     @pytest.mark.parametrize("seed", [0, 1])
     def test_halo_nn(self, seed):
         window, x, xk, wk, st, en, cap = self._case(seed + 10)
-        be = get_backend("pallas-interpret")
         d_cut = 900.0
-        dense = be.denser_nn_halo(x, xk, window, wk, st, en, d_cut,
-                                  span_cap=cap)
-        bs = be.denser_nn_halo(x, xk, window, wk, st, en, d_cut,
-                               span_cap=cap, layout="block-sparse")
-        for a, b in zip(dense, bs):
+        ref = get_backend("jnp").denser_nn_halo(x, xk, window, wk, st, en,
+                                                d_cut, span_cap=cap)
+        bs = get_backend("pallas-interpret").denser_nn_halo(
+            x, xk, window, wk, st, en, d_cut, span_cap=cap,
+            layout="block-sparse")
+        for a, b in zip(ref, bs):
             assert _eq(a, b)
 
 
@@ -231,12 +236,11 @@ class TestFallbackRing:
         x = y[np.sort(rng.choice(m, n_rows, replace=False))]
         dev = blocksparse.ring_worklist(jnp.asarray(x), jnp.asarray(y),
                                         block_n=bn, block_m=bm)
-        host = build_flat_worklist(x, y, None, block_n=bn, block_m=bm,
-                                   count=False, nn="best1")
-        assert dev.meta.shape == host.meta.shape
-        assert (dev.n_kept, dev.n_total) == (host.n_kept, host.n_total)
-        dm, hm = np.asarray(dev.meta), np.asarray(host.meta)
-        dl, hl = np.asarray(dev.lb), np.asarray(host.lb)
+        hm, hl, hkept, htotal = kept_pairs_ref(
+            x, y, None, block_n=bn, block_m=bm, count=False, nn="best1")
+        assert dev.meta.shape == hm.shape
+        assert (dev.n_kept, dev.n_total) == (hkept, htotal)
+        dm, dl = np.asarray(dev.meta), np.asarray(dev.lb)
         # chunk padding in the same places, flagged -1 with infinite bounds
         assert np.array_equal(dm[:, 3], hm[:, 3])
         real = dm[:, 3] >= 0
@@ -362,8 +366,8 @@ class TestFallbackRing:
 
 class TestDeviceCountList:
     """The d_cut count list a distributed shard builds on the device
-    (``cut_prefix`` then ``cut_worklist``): the host count builder's pairs
-    in its chunk format, swept with only its live chunks run."""
+    (``cut_prefix`` then ``cut_worklist``): the reference count list's
+    pairs in its chunk format, swept with only its live chunks run."""
 
     @staticmethod
     def _shard(m, bn, bm, seed, lattice=False):
@@ -397,17 +401,16 @@ class TestDeviceCountList:
     def test_pairs_equal_host_count_builder(self, m, bn, bm, chunk, lattice):
         x, y, d_cut = self._shard(m, bn, bm, 5, lattice)
         cp, wl = self._device(x, y, d_cut, bn, bm, chunk, spare=2)
-        host = build_flat_worklist(x, y, d_cut, block_n=bn, block_m=bm,
-                                   count=True)
+        hm, hl, hkept, _ = kept_pairs_ref(x, y, d_cut, block_n=bn,
+                                          block_m=bm)
         dm, dl = np.asarray(wl.meta), np.asarray(wl.lb)
-        hm, hl = np.asarray(host.meta), np.asarray(host.lb)
         live = int(wl.live_chunks)
         assert live == int(cp.chunks) and dm.shape[0] == 2 * (
             1 << max(live - 1, 0).bit_length())
         real = dm[:, 3] >= 0
         hreal = hm[:, 3] >= 0
         assert not real[live:].any(), "entries past the live chunks"
-        assert int(real.sum()) == int(hreal.sum()) == host.n_kept \
+        assert int(real.sum()) == int(hreal.sum()) == hkept \
             == int(np.sum(np.asarray(cp.kept)))
         rows, cols = dm[:, 0][real], dm[:, 1][real]
         # the same (row, col, in-cut) set, row tiles in order, each row
@@ -442,9 +445,7 @@ class TestDeviceCountList:
         dm = np.asarray(wl.meta)
         real = dm[:, 3] >= 0
         rows, flags = dm[:, 0][real], dm[:, 3][real]
-        host = build_flat_worklist(x[:100], y, d_cut, block_n=16,
-                                   block_m=64, count=True)
-        hm = np.asarray(host.meta)
+        hm = kept_pairs_ref(x[:100], y, d_cut, block_n=16, block_m=64)[0]
         hreal = hm[:, 3] >= 0
         # tiles 0-6 hold the 100 points (tile 6 four of them): as the host
         # builds them from the points alone; tile 7 is all padding
@@ -455,14 +456,16 @@ class TestDeviceCountList:
     @pytest.mark.parametrize("precision", ["f32", "bf16"])
     def test_sweep_equals_host_list_sweep(self, precision):
         """The fused sweep over the device list, spare chunks left unrun,
-        equals the sweep over the host list: counts and kept-k."""
+        equals the sweep over the reference list: counts and kept-k."""
         from repro.kernels import ops
 
         x, y, d_cut = self._shard(3000, 16, 64, 9)
+        hm, hl, hkept, htotal = kept_pairs_ref(x, y, d_cut, block_n=16,
+                                               block_m=64)
+        host = FlatWorklist(meta=jnp.asarray(hm), lb=jnp.asarray(hl),
+                            n_kept=hkept, n_total=htotal)
         x, y = jnp.asarray(x), jnp.asarray(y)
         _, wl = self._device(x, y, d_cut, 16, 64, 64, spare=2)
-        host = build_flat_worklist(x, y, d_cut, block_n=16, block_m=64,
-                                   count=True)
         kw = dict(block_n=16, block_m=64, precision=precision,
                   interpret=True)
         for a, b in zip(ops.fused_sweep(x, y, d_cut, worklist=wl, **kw),
@@ -511,8 +514,8 @@ class TestDeviceCountList:
 class TestDeviceFusedList:
     """The fused pallas ``rho_delta``'s count + kept-k list, built on the
     device (``fused_worklist``: ``cut_prefix`` with the k-NN radius), equals
-    the host builder's (``_kept_pairs(count=True, nn="topk", k=8)``) entry
-    for entry up to the live chunks; the chunks past them are padding."""
+    the numpy reference's (``kept_pairs_ref(nn="topk", k=8)``) entry for
+    entry up to the live chunks; the chunks past them are padding."""
 
     BN, BM, CHUNK = 16, 64, 64
 
@@ -570,9 +573,9 @@ class TestDeviceFusedList:
             counts = np.bincount(sel // self.BM,
                                  minlength=-(-len(y) // self.BM))
         dm, dl, live, kept = self._device(case, x, y, d_cut, sel)
-        hm, hl, hkept, _ = blocksparse._kept_pairs(
-            np.asarray(x, np.float32), y, d_cut, self.BN, self.BM, True, nn,
-            8, False, counts, None, None)
+        hm, hl, hkept, _ = kept_pairs_ref(
+            x, y, d_cut, block_n=self.BN, block_m=self.BM, nn=nn, k=8,
+            nn_col_counts=counts)
         assert len(x) % self.BN and len(y) % self.BM, "pad rows expected"
         assert kept == hkept
         assert dm.shape == (blocksparse._pow2(live),) + hm.shape[1:]
@@ -643,11 +646,15 @@ class TestDegenerateWorklists:
             assert _eq(a, b)
 
     def test_single_cell_worklist_is_dense(self):
+        from repro.kernels import blocksparse
+
         rng = np.random.default_rng(2)
         pts = rng.integers(0, 4, (400, 3)).astype(np.float32)
-        wl = build_flat_worklist(pts, pts, 1e6, block_n=128, block_m=128,
-                                 count=True)
+        wl = blocksparse.fused_worklist(pts, pts, 1e6, block_n=128,
+                                        block_m=128)
         assert wl.n_kept == wl.n_total
+        assert worklist_stats(pts, pts, 1e6, block_n=128,
+                              block_m=128)["pruned_tile_frac"] == 0.0
         assert wl.pruned_frac == 0.0
 
     def test_separated_clusters_prune(self):
@@ -689,14 +696,37 @@ class TestTraceability:
                                              layout="block-sparse"))
         assert _eq(f(pts), be.range_count(pts, pts, 10.0))
 
-    def test_pallas_worklists_require_host(self):
+    @pytest.mark.parametrize("primitive", ["range_count",
+                                           "range_count_delta"])
+    def test_pallas_worklists_require_host(self, primitive):
+        """A count list is sized on the host: its routes refuse a trace."""
         import jax
         be = get_backend("pallas-interpret")
         assert not be.worklist_traceable
         pts = jnp.zeros((64, 2), jnp.float32)
+        if primitive == "range_count":
+            def f(p):
+                return be.range_count(p, p, 1.0, layout="block-sparse")
+        else:
+            def f(p):
+                return be.range_count_delta(p, p, jnp.ones(64), 1.0,
+                                            layout="block-sparse")
         with pytest.raises(ValueError, match="host"):
-            jax.jit(lambda p: be.range_count(p, p, 1.0,
-                                             layout="block-sparse"))(pts)
+            jax.jit(f)(pts)
+
+    def test_pallas_best1_denser_nn_is_jit_safe(self):
+        """The best-1 ring depends only on shapes: the block-sparse pallas
+        ``denser_nn`` runs under ``jax.jit`` and equals the eager call."""
+        import jax
+        be = get_backend("pallas-interpret")
+        pts, _ = _clusters(700, 3, 2)
+        rk = jnp.asarray(np.random.default_rng(2).permutation(700)
+                         .astype(np.float32))
+        eager = be.denser_nn(pts, rk, pts, rk, layout="block-sparse")
+        jitted = jax.jit(lambda p, k: be.denser_nn(
+            p, k, p, k, layout="block-sparse"))(pts, rk)
+        for a, b in zip(eager, jitted):
+            assert _eq(a, b)
 
     def test_unknown_layout_rejected(self):
         pts = jnp.zeros((8, 2), jnp.float32)

@@ -204,7 +204,7 @@ def test_halo_strategy_runs_kernel_backend():
 # Pallas block-sparse shard phases, every worklist built on the device
 # inside shard_map (pallas-interpret on 4 fake devices): exact against the
 # single-device algorithms, the layout decision, the shards' count lists
-# against the host builder, the fallback's power-of-two pad, and a re-fit
+# against the numpy reference, the fallback's power-of-two pad, and a re-fit
 # in a new order compiling nothing.
 _DEVICE_WL_SCRIPT = r"""
 import warnings, json
@@ -220,7 +220,7 @@ from repro.data.points import gaussian_mixture, with_noise
 from repro.engine import ExecSpec
 from repro.engine.planner import plan
 from repro.kernels import ops
-from repro.kernels.blocksparse import build_flat_worklist
+from repro.kernels.ref import kept_pairs_ref
 
 mesh = jax.make_mesh((4,), ("data",))
 spec = ExecSpec(backend="pallas-interpret", layout="block-sparse")
@@ -263,7 +263,7 @@ for name, (pts, d_cut) in cases.items():
             "parent_or_tie": float(((par == rpar) | tie).mean())}
     out[name] = r
 
-# every shard's device-built count list holds the host builder's pairs
+# every shard's device-built count list holds the reference's pairs
 pts, d_cut = cases["mixture_0_2"]
 grid = build_grid(jnp.asarray(pts), d_cut)
 n = grid.points.shape[0]
@@ -274,15 +274,15 @@ meta = np.asarray(meta).reshape(4, n_chunks, 4, -1)
 same = []
 for s in range(4):
     x = np.asarray(grid.points)[s * (n // 4):(s + 1) * (n // 4)]
-    host = build_flat_worklist(x, np.asarray(grid.points), d_cut,
-                               block_n=256, block_m=ops.DENSITY_BLOCK_M,
-                               count=True)
-    m, hm = meta[s], np.asarray(host.meta)
+    hm, _, hkept, _ = kept_pairs_ref(x, np.asarray(grid.points), d_cut,
+                                     block_n=256,
+                                     block_m=ops.DENSITY_BLOCK_M)
+    m = meta[s]
     pairs = sorted(map(tuple, m[:, [0, 1, 3]].transpose(0, 2, 1).reshape(
         -1, 3)[m[:, 3].reshape(-1) >= 0].tolist()))
     hpairs = sorted(map(tuple, hm[:, [0, 1, 3]].transpose(0, 2, 1).reshape(
         -1, 3)[hm[:, 3].reshape(-1) >= 0].tolist()))
-    same.append(pairs == hpairs and len(pairs) == host.n_kept)
+    same.append(pairs == hpairs and len(pairs) == hkept)
 out["count_lists_equal"] = same
 
 # two orders of one draw: the second fit compiles nothing

@@ -7,8 +7,8 @@ The ISSUE 5 contract, in four parts:
   ``ExecSpec`` / ``DPCEngine`` paths produce bit-identical results per
   backend (including ``pallas-interpret``) and per layout.
 * **Plan reuse** — a re-``fit`` on a same-shaped input returns the *same*
-  plan object, adds no new jit trace entries, and (block-sparse pallas)
-  skips the host worklist rebuild entirely.
+  plan object and adds no new jit trace entries; block-sparse pallas
+  builds its worklists on the device, fetching no point table.
 * **Deprecation** — constructing any of the four shims through its legacy
   exec kwargs emits a ``DeprecationWarning`` pointing at ``repro.engine``.
 * **Fail-fast validation** — unknown backend/layout/precision names and
@@ -182,7 +182,7 @@ class TestLegacyShimParity:
 
 
 class TestPlanReuse:
-    """Re-fit on same-shaped input: cached plan, no retrace, no rebuild."""
+    """Re-fit on same-shaped input: cached plan, no retrace."""
 
     def test_plan_object_identity_and_no_retrace(self):
         from repro.kernels.backend import _rho_delta_jnp
@@ -209,29 +209,10 @@ class TestPlanReuse:
         assert plan(ps, spec) is not plan(PointsSpec(n=129, d=3), spec)
         assert as_plan(spec).spec == spec
 
-    def test_host_worklist_reuse(self):
-        """pallas block-sparse: a plan primitive that builds its worklist
-        on the host (the best-1 ``denser_nn``) serves a same-data call
-        from the plan's content-addressed cache."""
-        pts = jnp.asarray(_mix(160, seed=13))
-        key = jnp.arange(160, dtype=jnp.float32)
-        pl = as_plan(ExecSpec(backend="pallas-interpret",
-                              layout="block-sparse"), pts)
-        pl.denser_nn(pts, key, pts, key)
-        builds_after_first = blocksparse.worklist_build_count()
-        assert builds_after_first > 0
-        pl.denser_nn(pts, key, pts, key)            # same data
-        assert blocksparse.worklist_build_count() == builds_after_first, \
-            "same-data call rebuilt a host worklist"
-        # different data with the same shape must rebuild (fingerprinted)
-        other = jnp.asarray(_mix(160, seed=14))
-        pl.denser_nn(other, key, other, key)
-        assert blocksparse.worklist_build_count() > builds_after_first
-
-    def test_fused_worklist_fetches_nothing(self, monkeypatch):
+    def test_fused_worklist_fetches_nothing(self):
         """pallas block-sparse: the fused rho/delta list is built on the
-        device at every fit, same data included.  No host builder runs, and
-        its one host copy is the 8-byte (chunks, kept) read."""
+        device at every fit, same data included.  Its one host copy is the
+        8-byte (chunks, kept) read."""
         from repro import obs
 
         pts = _mix(160, seed=13)
@@ -239,10 +220,6 @@ class TestPlanReuse:
                         exec_spec=ExecSpec(backend="pallas-interpret",
                                            layout="block-sparse"))
         eng.fit(pts)                    # plans (and analyzes) the spec
-
-        def host_build(*a, **kw):
-            raise AssertionError("the fused path built a host worklist")
-        monkeypatch.setattr(blocksparse, "build_flat_worklist", host_build)
         obs.configure(level="metrics")
         try:
             for _ in range(2):          # the same data twice
@@ -260,52 +237,42 @@ class TestPlanReuse:
             obs.configure(level="off")
             obs.reset_spans()
 
-    def test_worklist_fingerprint_source_dtype_miss(self):
-        """Cache identity is the caller's data, not its f32 shadow: the
-        same coordinates handed in at a different source dtype must MISS
-        (the sweep kernels consume the original arrays; only the worklist
-        builder canonicalizes to f32, so the converted bytes collide)."""
-        pts32 = np.asarray(_mix(96, seed=16), np.float32)
-        pts64 = pts32.astype(np.float64)
-        kw = dict(block_n=64, block_m=64)
-        with blocksparse.worklist_cache({}):
-            before = blocksparse.worklist_build_count()
-            hits0 = blocksparse.worklist_cache_hits()
-            blocksparse.build_flat_worklist(pts32, pts32, 500.0, **kw)
-            assert blocksparse.worklist_build_count() == before + 1
-            blocksparse.build_flat_worklist(pts32, pts32, 500.0, **kw)
-            assert blocksparse.worklist_build_count() == before + 1, \
-                "identical call must be served from the cache"
-            assert blocksparse.worklist_cache_hits() == hits0 + 1
-            blocksparse.build_flat_worklist(pts64, pts64, 500.0, **kw)
-            assert blocksparse.worklist_build_count() == before + 2, \
-                "same coords at f64 hit the f32-coord fingerprint"
-            blocksparse.build_flat_worklist(pts64, pts32, 500.0, **kw)
-            assert blocksparse.worklist_build_count() == before + 3, \
-                "per-argument dtype tags: (f64, f32) != (f64, f64)"
+    @pytest.mark.parametrize("primitive", ["range_count",
+                                           "range_count_delta",
+                                           "denser_nn"])
+    def test_pallas_sparse_routes_fetch_no_table(self, primitive):
+        """pallas block-sparse primitives build their lists on the device:
+        a call's spans copy at most the count list's 8-byte read down and
+        no list up, and its answer equals the dense layout's."""
+        from repro import obs
+        from repro.kernels import get_backend
 
-    def test_worklist_fingerprint_perturbation_miss(self):
-        """One moved point is a different identity — content-addressed
-        keys, not shape-addressed."""
-        pts = np.asarray(_mix(96, seed=17), np.float32)
-        bumped = pts.copy()
-        bumped[17, 0] += 1.0
-        kw = dict(block_n=64, block_m=64)
-        with blocksparse.worklist_cache({}):
-            before = blocksparse.worklist_build_count()
-            blocksparse.build_flat_worklist(pts, pts, 500.0, **kw)
-            blocksparse.build_flat_worklist(bumped, bumped, 500.0, **kw)
-            assert blocksparse.worklist_build_count() == before + 2
-
-    def test_direct_backend_calls_never_cache(self):
-        """Without an active plan context the builder is stateless."""
-        pts, = (np.asarray(_mix(96, seed=15)),)
-        before = blocksparse.worklist_build_count()
-        blocksparse.build_flat_worklist(pts, pts, 500.0, block_n=64,
-                                        block_m=64)
-        blocksparse.build_flat_worklist(pts, pts, 500.0, block_n=64,
-                                        block_m=64)
-        assert blocksparse.worklist_build_count() == before + 2
+        pts = jnp.asarray(_mix(160, seed=13))
+        key = jnp.asarray(np.random.default_rng(13).permutation(160)
+                          .astype(np.float32))
+        signs = jnp.asarray(np.random.default_rng(14)
+                            .choice([-1.0, 0.0, 1.0], 160).astype(np.float32))
+        be = get_backend("pallas-interpret")
+        call = {
+            "range_count": lambda **kw: be.range_count(pts, pts, 900.0,
+                                                       **kw),
+            "range_count_delta": lambda **kw: be.range_count_delta(
+                pts, pts, signs, 900.0, **kw),
+            "denser_nn": lambda **kw: be.denser_nn(pts, key, pts, key, **kw),
+        }[primitive]
+        dense = call()
+        obs.configure(level="metrics")
+        try:
+            obs.reset_spans()
+            sparse = call(layout="block-sparse")
+            attrs = [r.get("attrs", {}) for r in obs.spans()]
+        finally:
+            obs.configure(level="off")
+            obs.reset_spans()
+        assert sum(a.get("d2h_bytes", 0) for a in attrs) <= 8
+        assert not any(a.get("h2d_bytes", 0) for a in attrs)
+        for a, b in zip(jax.tree.leaves(dense), jax.tree.leaves(sparse)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 class TestDeprecationShims:

@@ -14,7 +14,7 @@ The ISSUE 7 contract:
   the legacy read surfaces (``plan_cache_info``, ``worklist_build_count``,
   ``StreamDPC.stats``) keep their exact semantics.
 * **Plan telemetry** — ``DPCPlan.telemetry()`` reports the resolved axes,
-  pad waste and worklist cache; ``include_cost=True`` adds the hlo_cost
+  pad waste and worklist strategy; ``include_cost=True`` adds the hlo_cost
   flop/byte estimate.
 """
 import json
